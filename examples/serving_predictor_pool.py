@@ -47,9 +47,6 @@ def main():
     model, X, y = train_model(rng)
 
     with tempfile.TemporaryDirectory(prefix="serve_") as tmp:
-        # keep the persistent bucket-executable cache inside the demo dir
-        os.environ.setdefault("PADDLE_TPU_COMPILE_CACHE",
-                              os.path.join(tmp, "compile-cache"))
         path = os.path.join(tmp, "infer")
         _serve(model, X, y, path)
         _serve_resilient(X, y, path)

@@ -25,7 +25,8 @@ properties no source lint or runtime probe can see:
   no layout changes smuggled into the conv stack.
 * **GC004 host-transfer** — a device-to-host transfer compiled INTO the
   graph: callback primitives (`pure_callback`/`io_callback`/
-  `debug_callback`) in the jaxpr, or infeed/outfeed in the HLO.
+  `debug_callback`/`debug_print`) in the jaxpr, or infeed/outfeed in
+  the HLO.
 * **GC005 donation-unaliased** — an argument declared donated whose
   buffers do NOT appear in the executable's input-output aliasing table:
   the donation silently bought nothing (the static complement of
@@ -113,12 +114,12 @@ _PASSTHROUGH = {
     "tanh", "logistic", "rsqrt", "sqrt", "sign", "integer_pow", "pow",
     "select_n", "convert_element_type", "broadcast_in_dim", "reshape",
     "squeeze", "expand_dims", "custom_jvp_call", "custom_vjp_call",
-    "custom_vjp_call_jaxpr", "pjit", "clamp", "ge", "gt", "le", "lt",
+    "custom_vjp_call_jaxpr", "jit", "clamp", "ge", "gt", "le", "lt",
 }
 
 #: jaxpr primitives that ARE host transfers (GC004)
-_HOST_PRIMS = {"pure_callback", "io_callback", "debug_callback", "infeed",
-               "outfeed"}
+_HOST_PRIMS = {"pure_callback", "io_callback", "debug_callback",
+               "debug_print", "infeed", "outfeed"}
 
 #: GC003 def-use proximity (hops through _PASSTHROUGH prims)
 _CONV_HOPS = 3
@@ -339,7 +340,7 @@ def _prim_name(eqn):
 #: registry's per-op jit boundaries (every framework op traces as its
 #: own pjit eqn — without inlining, a transpose and the conv it feeds
 #: never share a jaxpr)
-_CALL_PRIMS = {"pjit", "custom_jvp_call", "custom_vjp_call",
+_CALL_PRIMS = {"jit", "custom_jvp_call", "custom_vjp_call",
                "custom_vjp_call_jaxpr", "remat", "checkpoint",
                "closed_call", "core_call"}
 

@@ -390,11 +390,8 @@ def _apply(name, impl, tensor_args, statics=None, out_wrapper=None):
             # memory on use — XLA cannot mix memory spaces in one op
             mk = getattr(getattr(v, "sharding", None), "memory_kind", None)
             if mk in ("pinned_host", "unpinned_host"):
-                from ..compat import has_device_memory_kind
-
-                if has_device_memory_kind():
-                    v = jax.device_put(
-                        v, v.sharding.with_memory_kind("device"))
+                v = jax.device_put(
+                    v, v.sharding.with_memory_kind("device"))
             if cast_to is not None and v.dtype != cast_to and jnp.issubdtype(v.dtype, jnp.floating):
                 v = v.astype(cast_to)
             arrays.append(v)

@@ -4,7 +4,7 @@ Reference analog: SOT's partial-graph compilation — the reference's
 opcode translator executes *compiled subgraphs between graph breaks* and
 resumes tracing after them
 (python/paddle/jit/sot/opcode_translator/executor/opcode_executor.py:1473,
-break taxonomy jit/sot/utils/exceptions.py:38). Our to_static traces
+break classes jit/sot/utils/exceptions.py:38). Our to_static traces
 whole functions; when a function contains an unconvertible construct the
 round-3 contract dropped the WHOLE call to per-op eager execution.
 

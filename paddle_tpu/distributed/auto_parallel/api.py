@@ -16,7 +16,7 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from ...compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding  # isinstance checks only
 
 from ... import sharding as _shardlib

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 # only — construction goes through the paddle_tpu.sharding factories
 # (the ONE placement authority, tracelint TL011)
 from jax.sharding import Mesh, NamedSharding
-from ..compat import shard_map
+from jax import shard_map
 
 from ..core.tensor import Tensor
 from ..sharding import named_sharding as _named_sharding, spec as _spec
@@ -405,17 +405,15 @@ def barrier(group=None):
 
     Multi-process job: a REAL cross-process barrier over the native
     coordination store (native/coord_store.cc) — `block_until_ready` says
-    nothing about other processes (and can return at enqueue time through a
-    PJRT relay). Single controller: a host readback fences locally-issued
-    work."""
+    nothing about other processes. Single controller: wait for the
+    locally-issued device work (`device.synchronize`)."""
     from .env import get_store, get_world_size, get_rank
     store = get_store()
     if store is not None and get_world_size() > 1:
         store.barrier(name="dist_barrier", world_size=get_world_size())
         return
-    # fence via host readback, not block_until_ready (see bench discipline)
-    import numpy as _np
-    _np.asarray(jnp.zeros(()))
+    from ..device import synchronize
+    synchronize()
 
 
 def get_group(axis="dp"):

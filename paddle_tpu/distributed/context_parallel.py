@@ -289,6 +289,30 @@ def _cp_spec(mesh, seq_axis, batch_axes, head_axes):
     return _shardlib.spec(batch if batch else None, seq_axis, head, None)
 
 
+def batch_head_shard_map(fn, mesh, q_shape,
+                         batch_axes=("dp", "sharding", "fsdp"),
+                         head_axis=("mp", "tp")):
+    """`fn(q, k, v)` over [batch, seq, heads, head_dim] arrays, run per
+    shard with batch split over the mesh's data axes and heads over its
+    tensor axis — exact, since attention is independent per batch row and
+    per head. This is how a Pallas kernel runs on a multi-device mesh:
+    GSPMD refuses to partition a Mosaic call ("wrap the call in a
+    shard_map"). Returns None when the shape does not divide the mesh."""
+    from jax import shard_map
+
+    spec = _cp_spec(mesh, None, batch_axes, head_axis)
+
+    def ways(entry):
+        names = () if entry is None else \
+            (entry,) if isinstance(entry, str) else entry
+        return math.prod(mesh.shape[a] for a in names)
+
+    if q_shape[0] % ways(spec[0]) or q_shape[2] % ways(spec[2]):
+        return None
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)
+
+
 def _ring_flash_shapes_ok(s_loc, d, balanced):
     """Whether the Pallas pos-kernels handle this per-shard problem (same
     VMEM envelope as flash_attention_supported, on the LOCAL length)."""
@@ -322,7 +346,7 @@ def context_parallel_attention(q, k, v, mesh, *, mode="ring", seq_axis="sep",
         mode, impl = "ring", "flash"
     head_axes = (head_axis,) if isinstance(head_axis, str) else head_axis
     spec = _cp_spec(mesh, seq_axis, batch_axes, head_axes)
-    from ..compat import shard_map
+    from jax import shard_map
 
     if mode == "ring":
         n = int(mesh.shape[seq_axis])
@@ -399,7 +423,9 @@ def ulysses_attention(q, k, v, mesh, *, seq_axis="sep", causal=True,
 
 class _CPState(threading.local):
     def __init__(self):
-        self.config = None  # (mesh, mode, seq_axis)
+        # (mesh, mode, seq_axis); seq_axis None = no sequence axis in use,
+        # the mesh is published for the per-shard flash kernel alone
+        self.config = None
 
 
 _cp_state = _CPState()

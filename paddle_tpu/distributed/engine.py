@@ -259,23 +259,25 @@ class ShardedTrainStep:
 
     # ------------------------------------------------------------------
     def _cp_guard(self):
-        """Context manager enabling context-parallel attention during trace
-        (no-op when the mesh has no sequence axis > 1 or
-        context_parallel=None). The sequence axis is resolved through the
-        AxisRules "seq" entries, so "sep" (hybrid topology) and "cp"
-        (MeshConfig) meshes both route without engine-side special
-        cases."""
+        """Context manager publishing the mesh to attention during trace
+        (no-op on a one-device mesh). Model-level sdpa calls then become
+        context-parallel when the mesh has a sequence axis > 1 (unless
+        context_parallel=None) — resolved through the AxisRules "seq"
+        entries, so "sep" (hybrid topology) and "cp" (MeshConfig) meshes
+        both route without engine-side special cases — and on every
+        multi-device mesh the Pallas flash kernel runs per shard, which
+        is the only way a Mosaic kernel runs under a partitioned step."""
         import contextlib
 
         from ..sharding import resolve_axis
-        if not self.context_parallel:
+        if self.mesh.devices.size == 1:
             return contextlib.nullcontext()
-        seq_axis = resolve_axis("seq", mesh=self.mesh)
-        if not isinstance(seq_axis, str):
-            return contextlib.nullcontext()
+        seq_axis = resolve_axis("seq", mesh=self.mesh) \
+            if self.context_parallel else None
         from .context_parallel import context_parallel_guard
-        return context_parallel_guard(self.mesh, mode=self.context_parallel,
-                                      seq_axis=seq_axis)
+        return context_parallel_guard(
+            self.mesh, mode=self.context_parallel or "ring",
+            seq_axis=seq_axis if isinstance(seq_axis, str) else None)
 
     # ---- cached placement helpers (shared by train/eval/prefetch) -----
     def _batch_sharding(self, ndim):
@@ -528,6 +530,18 @@ class ShardedTrainStep:
         _cc.check_entrypoint(site, jit_obj=fn, args=args)
 
     # ---- public step APIs ----------------------------------------------
+    def lower_step(self, *batch):
+        """The single-step program `train_batch(*batch)` dispatches, as a
+        `jax.stages.Lowered` over this engine's mesh. Its `.as_text()` and
+        `.compile().as_text()` say what the step really contains — Pallas
+        custom calls, the partitioner's collectives — where a platform
+        test can only say what was asked for. Nothing is dispatched."""
+        placed = self._place_batch(batch)
+        fn = self._step_fn or self._build_step(placed)
+        return fn.lower(self.param_vals, self.opt_state, self.buffer_vals,
+                        placed, self._lr_scalar(), self._key_scalar(),
+                        self._step_scalar())
+
     def train_batch(self, *batch):
         """Run one optimizer step; returns the (device) loss Tensor."""
         if self.optimizer is None:

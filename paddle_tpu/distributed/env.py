@@ -67,13 +67,6 @@ def init_parallel_env():
     # initialize it and make multi-host bootstrap impossible).
     coord = os.environ.get("PADDLE_TPU_COORDINATOR")
     if coord:
-        if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-            try:  # older jax CPU backends need the collectives impl named
-                # explicitly for cross-process computations
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # tpu-lint: disable=TL007 — option absent on
-                pass           # this jax version: collectives just default
         jax.distributed.initialize(
             coordinator_address=coord,
             num_processes=int(os.environ["PADDLE_TPU_NUM_PROCESSES"]),

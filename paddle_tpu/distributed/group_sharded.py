@@ -50,10 +50,7 @@ def group_sharded_parallel(model, optimizer, level, scaler=None, group=None,
                               sharding_stage=stage, mesh=mesh)
         sh = _named_sharding(mesh, spec)
         if offload:
-            from ..compat import supports_memory_kind
-
-            if supports_memory_kind("pinned_host"):
-                sh = sh.with_memory_kind("pinned_host")
+            sh = sh.with_memory_kind("pinned_host")
         p._value = jax.device_put(p._value, sh)
         p.dist_spec = tuple(spec)
 

@@ -16,7 +16,10 @@ def parse_args(argv=None):
                    help="number of nodes (hosts) in the job")
     p.add_argument("--nproc_per_node", type=int,
                    default=int(os.environ.get("PADDLE_NPROC_PER_NODE", "1")),
-                   help="processes per node (TPU: one controller per host)")
+                   help="processes per node. Keep 1 on a TPU host: one "
+                        "controller process owns all local chips; more than "
+                        "one runs the ranks on CPU and is refused when "
+                        "JAX_PLATFORMS names an accelerator")
     p.add_argument("--master", default=os.environ.get("PADDLE_MASTER"),
                    help="host:port of the rendezvous store "
                         "(auto-hosted locally when omitted)")
@@ -70,3 +73,12 @@ class Context:
         self.args = args
         self.node_ip = os.environ.get("POD_IP", "127.0.0.1")
         self.world_size = args.nnodes * args.nproc_per_node
+        plat = (os.environ.get("JAX_PLATFORMS") or "cpu").lower()
+        if args.nproc_per_node > 1 and "cpu" not in plat:
+            # every local rank would get the same TPU_VISIBLE_DEVICES and
+            # fight for chips that belong to ONE process
+            raise SystemExit(
+                f"launch: --nproc_per_node={args.nproc_per_node} with "
+                f"JAX_PLATFORMS={plat!r}: one process owns a host's "
+                f"accelerator chips; use --nproc_per_node 1 (one controller "
+                f"per host drives all local chips)")

@@ -164,6 +164,10 @@ class Controller:
         if args.devices:
             env["CUDA_VISIBLE_DEVICES"] = args.devices
             env["TPU_VISIBLE_DEVICES"] = args.devices
+        if args.nproc_per_node > 1:
+            # several ranks per node are CPU ranks (Context refused an
+            # accelerator platform): one process owns a host's chips
+            env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS") or "cpu"
         return env
 
     def spawn(self, restart_epoch=0):

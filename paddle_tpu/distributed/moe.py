@@ -153,7 +153,7 @@ def _moe_ffn_alltoall_impl(x, gate_w, w1, b1, w2, b2, *, top_k, capacity,
     tok = _shardlib.spec(all_axes, None)
     ew = _shardlib.spec(axis, *([None] * (w1.ndim - 1)))
     eb = _shardlib.spec(axis, None)
-    from ..compat import shard_map
+    from jax import shard_map
     y, aux = shard_map(
         body, mesh=mesh,
         in_specs=(tok, _shardlib.spec(None, None), ew, eb,
@@ -363,7 +363,7 @@ def global_scatter(x, axis="mp", *, split_axis=0, concat_axis=0):
     mesh axis (XLA collective on ICI). Inside compiled MoE layers this
     collective is inserted automatically by GSPMD; this eager form exists
     for API parity and custom shard_map blocks."""
-    from ..compat import shard_map
+    from jax import shard_map
     from . import functional as dist_f
 
     mesh = topo_mod.get_mesh()

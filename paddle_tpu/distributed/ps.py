@@ -210,28 +210,20 @@ class HostOffloadedEmbedding(Layer):
 
     def _shardings(self, axes):
         from . import topology as topo_mod
-        from ..compat import supports_memory_kind
-
-        # backends without distinct host/device memory spaces (older jax
-        # CPU) degrade gracefully: the table stays in default memory,
-        # which IS host memory there
-        def _kind(sh, kind):
-            return sh.with_memory_kind(kind) \
-                if supports_memory_kind(kind) else sh
 
         hcg = topo_mod.get_hybrid_communicate_group()
         if axes and hcg is not None:
             mesh = hcg.mesh
-            host = _kind(_named_sharding(mesh, (tuple(axes), None)),
-                         "pinned_host")
-            dev = _kind(_named_sharding(mesh, ()), "device")
-            self._acc_host_sharding = _kind(
-                _named_sharding(mesh, (tuple(axes),)), "pinned_host")
+            host = _named_sharding(mesh, (tuple(axes), None)) \
+                .with_memory_kind("pinned_host")
+            dev = _named_sharding(mesh, ()).with_memory_kind("device")
+            self._acc_host_sharding = _named_sharding(
+                mesh, (tuple(axes),)).with_memory_kind("pinned_host")
         else:
             d = jax.devices()[0]
-            host = _kind(jax.sharding.SingleDeviceSharding(d),
-                         "pinned_host")
-            dev = _kind(jax.sharding.SingleDeviceSharding(d), "device")
+            host = jax.sharding.SingleDeviceSharding(
+                d, memory_kind="pinned_host")
+            dev = jax.sharding.SingleDeviceSharding(d, memory_kind="device")
             self._acc_host_sharding = host
         return host, dev
 

@@ -73,7 +73,7 @@ class BatchConfig:
             a DeadlineExceeded).
         cache: optional `jit.aot.CompileCache` override for the
             persistent executable cache (default: the process-wide cache
-            honoring ``$PADDLE_TPU_COMPILE_CACHE``).
+            honoring ``$JAX_COMPILATION_CACHE_DIR``).
     """
 
     def __init__(self, buckets=(1, 2, 4, 8, 16), max_wait_ms=2.0,

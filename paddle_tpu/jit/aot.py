@@ -23,16 +23,24 @@ batching layer wants to run. Both costs are removable:
   recompiling. Writes are crash-atomic (shared `_atomic_io` protocol)
   and the directory is size-bounded (keep-last-K by LRU mtime).
 
-Cache location: `$PADDLE_TPU_COMPILE_CACHE` if set, else
-`~/.cache/paddle_tpu/compile`. Capacity: `$PADDLE_TPU_COMPILE_CACHE_KEEP`
-entries (default 64). A corrupt or version-skewed entry is never fatal —
-deserialization failure falls back to a fresh compile and overwrites it.
+Cache location: ONE root for every compiled program — jax's own
+persistent compilation cache and these serialized executables (in its
+`paddle_tpu_aot/` subdirectory). The root is `$JAX_COMPILATION_CACHE_DIR`
+when set (jax reads that variable itself; nothing here sets another
+directory), else the fixed `<checkout>/.jax_cache` — see
+`compile_cache_root` / `enable_compile_cache`. Capacity:
+`$PADDLE_TPU_COMPILE_CACHE_KEEP` entries (default 64). A corrupt or
+version-skewed entry is never fatal — deserialization failure falls back
+to a fresh compile and overwrites it.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import pickle
+import tempfile
+import warnings
 
 from ..analysis import commcheck as _cc
 from ..analysis import graphcheck as _gc
@@ -40,21 +48,63 @@ from ..analysis import locks as _locks
 from ..analysis import runtime_san as _san
 
 __all__ = ["CompileCache", "compile_batched", "compile_jit", "default_cache",
-           "cache_dir"]
+           "cache_dir", "compile_cache_root", "enable_compile_cache",
+           "hermetic_cache"]
 
-_ENV_DIR = "PADDLE_TPU_COMPILE_CACHE"
+_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 _ENV_KEEP = "PADDLE_TPU_COMPILE_CACHE_KEEP"
 _SUFFIX = ".aotexec"
+# Fixed, inside the checkout, never $HOME / a temp name / a pid / a time:
+# a directory that moves between runs never hits.
+_CHECKOUT_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def compile_cache_root():
+    """The one compile-cache root: `$JAX_COMPILATION_CACHE_DIR`, else
+    `<checkout>/.jax_cache`."""
+    return os.environ.get(_ENV_DIR) or _CHECKOUT_ROOT
 
 
 def cache_dir():
-    """Resolve the persistent cache directory (env override first, so
-    tests and hermetic CI never pollute $HOME)."""
-    d = os.environ.get(_ENV_DIR)
-    if d:
-        return d
-    return os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                        "compile")
+    """Where serialized AOT executables live: a subdirectory of the root
+    (read per call, so tests repointing the variable get a fresh dir)."""
+    return os.path.join(compile_cache_root(), "paddle_tpu_aot")
+
+
+def enable_compile_cache():
+    """Turn on jax's persistent compilation cache at `compile_cache_root()`
+    and return the root. Entry points (bench.py, chip_smoke.py) call this
+    once before their first compile. With `$JAX_COMPILATION_CACHE_DIR` set
+    jax has already pointed itself there and nothing is set in code."""
+    root = compile_cache_root()
+    if not os.environ.get(_ENV_DIR):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", root)
+    return root
+
+
+@contextlib.contextmanager
+def hermetic_cache(prefix="aot-"):
+    """Point the AOT executable cache at a fresh temporary root for the
+    duration, whatever `$JAX_COMPILATION_CACHE_DIR` says, and put the
+    caller's back afterwards; yields the temporary directory. For the
+    audit CLIs and fault harnesses, which measure compiles: every one
+    must be real (a disk hit skips the audit hooks) and every run must
+    start as cold as the last. Not for the main path, where a cache
+    that moves never hits."""
+    outer = os.environ.get(_ENV_DIR)
+    with tempfile.TemporaryDirectory(prefix=prefix) as tmp:
+        os.environ[_ENV_DIR] = os.path.join(tmp, "compile-cache")
+        try:
+            yield tmp
+        finally:
+            if outer is None:
+                os.environ.pop(_ENV_DIR, None)
+            else:
+                os.environ[_ENV_DIR] = outer
 
 
 class CompileCache:
@@ -170,7 +220,7 @@ _default_lock = _locks.new_lock("aot.default_cache")
 
 def default_cache():
     """Process-wide CompileCache over the resolved cache dir. Rebuilt if
-    the env override changed (tests repoint it per tmpdir)."""
+    the resolved directory changed (tests repoint it per tmpdir)."""
     global _default_cache
     with _default_lock:
         if _default_cache is None or _default_cache.root != cache_dir():
@@ -202,14 +252,17 @@ def _sharding_sig(in_shardings):
 
     leaves, treedef = jax.tree_util.tree_flatten(
         in_shardings, is_leaf=lambda x: hasattr(x, "spec"))
-    mesh_sig = None
-    for sh in leaves:
-        m = getattr(sh, "mesh", None)
-        if m is not None:
-            mesh_sig = tuple((str(a), int(s)) for a, s in dict(m.shape).items())
-            break
+    m = _first_mesh(leaves)
+    mesh_sig = None if m is None else tuple(
+        (str(a), int(s)) for a, s in dict(m.shape).items())
     return (str(treedef), mesh_sig,
             [str(getattr(sh, "spec", sh)) for sh in leaves])
+
+
+def _first_mesh(shardings):
+    """The mesh the first mesh-carrying sharding names, else None."""
+    return next((sh.mesh for sh in shardings
+                 if getattr(sh, "mesh", None) is not None), None)
 
 
 def executable_key(fingerprint, bucket, input_spec, holder_shapes,
@@ -234,6 +287,46 @@ def _aval_signature(avals):
     leaves, treedef = jax.tree_util.tree_flatten(avals)
     return (str(treedef),
             [(list(a.shape), str(a.dtype)) for a in leaves])
+
+
+def _load_executable(cache, key, in_shardings):
+    """The executable persisted under `key`, or None (absent, or a stale /
+    corrupt entry — warned about, then recompiled and overwritten). It is
+    loaded onto the devices it was compiled for — the mesh `in_shardings`
+    names, else the default device: left to itself jax 0.9 spreads it
+    over EVERY local device, and a one-device program then refuses its
+    arguments at call time on a multi-device host."""
+    import jax
+    from jax.experimental import serialize_executable as _se
+
+    blob = cache.get(key)
+    if blob is None:
+        return None
+    mesh = _first_mesh(jax.tree_util.tree_leaves(
+        in_shardings, is_leaf=lambda x: hasattr(x, "spec")))
+    devices = jax.devices()[:1] if mesh is None \
+        else list(mesh.devices.flat)
+    try:
+        payload, in_tree, out_tree = pickle.loads(blob)
+        return _se.deserialize_and_load(payload, in_tree, out_tree,
+                                        execution_devices=devices)
+    except Exception as e:  # tpu-lint: disable=TL007 — never fatal
+        warnings.warn(f"aot: cached executable {key[:12]} did not load "
+                      f"({type(e).__name__}: {e}); recompiling")
+        return None
+
+
+def _store_executable(cache, key, compiled):
+    """Persist `compiled` under `key`; a backend that cannot serialize
+    still serves from memory, but says so."""
+    from jax.experimental import serialize_executable as _se
+
+    try:
+        cache.put(key, pickle.dumps(_se.serialize(compiled), protocol=4))
+    except Exception as e:  # tpu-lint: disable=TL007 — never fatal
+        warnings.warn(f"aot: executable {key[:12]} was not persisted "
+                      f"({type(e).__name__}: {e}); every process will "
+                      f"recompile it")
 
 
 def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
@@ -264,7 +357,6 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
     process start loads every bucket from disk instead of recompiling.
     """
     import jax
-    from jax.experimental import serialize_executable as _se
 
     key = None
     if fingerprint is not None:
@@ -276,14 +368,9 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
                                  else ()),
                                *(("extra", extra_key)
                                  if extra_key is not None else ()))
-        blob = cache.get(key)
-        if blob is not None:
-            try:
-                payload, in_tree, out_tree = pickle.loads(blob)
-                loaded = _se.deserialize_and_load(payload, in_tree, out_tree)
-                return loaded, "disk"
-            except Exception:  # tpu-lint: disable=TL007 — stale/corrupt
-                pass  # cache entry: recompile and overwrite below
+        loaded = _load_executable(cache, key, in_shardings)
+        if loaded is not None:
+            return loaded, "disk"
 
     if _san.enabled():
         # retrace sentinel (tpu-san): this is a REAL XLA compile — a
@@ -334,10 +421,7 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
         _cc.check_entrypoint(f"aot.{tag}", fn=fn, args=avals,
                              lowered=lowered, compiled=compiled)
     if key is not None:
-        try:
-            cache.put(key, pickle.dumps(_se.serialize(compiled), protocol=4))
-        except Exception:  # tpu-lint: disable=TL007 — an unserializable
-            pass           # backend still serves from memory
+        _store_executable(cache, key, compiled)
     return compiled, "compiled"
 
 
@@ -361,7 +445,6 @@ def compile_batched(exported, holder_avals, input_spec, bucket, *,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import serialize_executable as _se
 
     if bucket < 1:
         raise ValueError(f"bucket size must be >= 1, got {bucket}")
@@ -378,15 +461,10 @@ def compile_batched(exported, holder_avals, input_spec, bucket, *,
         cache = cache or default_cache()
         key = executable_key(fingerprint, bucket, input_spec, holder_shapes,
                              sharding_sig=_sharding_sig(in_shardings))
-        blob = cache.get(key)
-        if blob is not None:
-            try:
-                payload, in_tree, out_tree = pickle.loads(blob)
-                loaded = _se.deserialize_and_load(payload, in_tree, out_tree)
-                return (lambda holders, *stacked:
-                        loaded(list(holders), *stacked)), "disk"
-            except Exception:  # tpu-lint: disable=TL007 — stale/corrupt
-                pass  # cache entry: recompile and overwrite below
+        loaded = _load_executable(cache, key, in_shardings)
+        if loaded is not None:
+            return (lambda holders, *stacked:
+                    loaded(list(holders), *stacked)), "disk"
 
     if _san.enabled():
         _san.note_trace(
@@ -424,9 +502,6 @@ def compile_batched(exported, holder_avals, input_spec, bucket, *,
                              args=(list(holder_avals), *stacked_avals),
                              lowered=lowered, compiled=compiled)
     if key is not None:
-        try:
-            cache.put(key, pickle.dumps(_se.serialize(compiled), protocol=4))
-        except Exception:  # tpu-lint: disable=TL007 — an unserializable
-            pass           # backend still serves from memory
+        _store_executable(cache, key, compiled)
     return (lambda holders, *stacked:
             compiled(list(holders), *stacked)), "compiled"

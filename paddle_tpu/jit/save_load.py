@@ -20,12 +20,6 @@ from ..analysis import runtime_san as _san
 
 import numpy as np
 import jax
-
-try:  # jax.export is lazily exposed on some versions: bind it eagerly so
-    # `jax.export.export(...)` attribute access below always resolves
-    import jax.export  # noqa: F401
-except ImportError:
-    pass
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor

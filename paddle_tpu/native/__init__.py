@@ -43,6 +43,11 @@ def build_sources(name: str, sources, extra_flags=(),
             try:
                 subprocess.run(cmd, check=True, capture_output=True,
                                text=True)
+            except FileNotFoundError as e:
+                # _build/ is git-ignored: a fresh checkout builds here
+                raise RuntimeError(
+                    f"native build of {name} needs g++ on PATH, and it "
+                    f"is missing ({e})") from e
             except subprocess.CalledProcessError as e:
                 raise RuntimeError(
                     f"native build of {name} failed:\n{e.stderr}") from e

@@ -9,19 +9,21 @@ paddle_tpu.distributed.
 """
 from __future__ import annotations
 
+import functools
+import os
+
 import jax
 import jax.numpy as jnp
 
 from ...ops._helpers import apply, wrap, Tensor
 
 
-import os
-
 _PALLAS_FLASH = os.environ.get("PADDLE_TPU_FLASH", "1") != "0"
 
 
-def _sdpa_impl(q, k, v, *, causal, scale):
-    # inputs [B, S, H, D] (reference flash_attention layout)
+def _sdpa_impl(q, k, v, *, causal, scale, mesh=None):
+    # inputs [B, S, H, D] (reference flash_attention layout); `mesh` is the
+    # engine's multi-device mesh when one is tracing this call
     if _PALLAS_FLASH and jax.default_backend() == "tpu":
         from ...ops.pallas import flash_attention as pallas_flash
         from ...ops.pallas import flash_attention_supported
@@ -30,8 +32,14 @@ def _sdpa_impl(q, k, v, *, causal, scale):
         if (q.shape == k.shape == v.shape
                 and flash_attention_supported(q.shape, causal)):
             # tuned v5e kernel: ~6-14x over XLA fused attention forward
-            return pallas_flash(q, k, v, causal=causal, scale=scale,
-                                interpret=False)
+            flash = functools.partial(pallas_flash, causal=causal,
+                                      scale=scale, interpret=False)
+            if mesh is not None:
+                from ...distributed.context_parallel import (
+                    batch_head_shard_map)
+                flash = batch_head_shard_map(flash, mesh, q.shape)
+            if flash is not None:
+                return flash(q, k, v)
     return jax.nn.dot_product_attention(
         q, k, v, is_causal=causal, scale=scale)
 
@@ -93,7 +101,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         return apply("sdpa_mask", _sdpa_mask_impl, (q, k, v, wrap(attn_mask)),
                      {"causal": bool(is_causal), "scale": None})
     return apply("sdpa", _sdpa_impl, (q, k, v),
-                 {"causal": bool(is_causal), "scale": None})
+                 {"causal": bool(is_causal), "scale": None,
+                  "mesh": cp[0] if cp is not None else None})
 
 
 _SHORT_ATTN = os.environ.get("PADDLE_TPU_SHORT_ATTENTION", "0") != "0"

@@ -275,13 +275,7 @@ def _cummax_indices(xx, ax, op):
 
 
 def _logcumsumexp_impl(x, *, axis):
-    return jax.lax.cumlogsumexp(x, axis=axis) if hasattr(jax.lax, "cumlogsumexp") else _lcse(x, axis)
-
-
-def _lcse(x, axis):
-    def comb(a, b):
-        return jnp.logaddexp(a, b)
-    return jax.lax.associative_scan(comb, x, axis=axis)
+    return jax.lax.cumlogsumexp(x, axis=axis)
 
 
 def logcumsumexp(x, axis=None, dtype=None, name=None):

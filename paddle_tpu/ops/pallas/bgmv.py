@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params as _compiler_params
 
 _VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -108,6 +107,7 @@ def lora_delta(x, A, B, ids, *, use_kernel=None, interpret=None):
         functools.partial(_kernel),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, s, d_out), jnp.float32),
-        compiler_params=_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(ids, x, A, B)

@@ -20,15 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu is import-safe on CPU; guards match flash_attention.py
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # tpu-lint: disable=TL007 — capability probe:
-    # version-skewed jax raises AttributeError/RuntimeError here, not
-    # just ImportError; any failure degrades to the interpret path
-    pltpu = None  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -135,7 +127,7 @@ def _bs_pallas(q, k, v, block_idx, block_cnt, *, scale, block_size,
     bh, s, d = q.shape
     nq = s // block_size
     kwargs = {}
-    if _HAS_PLTPU and not interpret:
+    if not interpret:
         smem = pltpu.SMEM
         vmem = pltpu.VMEM
         kwargs["in_specs"] = [

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params as _compiler_params
 
 _VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -103,7 +102,8 @@ def decode_attention(q, kq, ks, vq, vs, pos, interpret=None):
                   q_spec, kv_spec, sc_spec, kv_spec, sc_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(pos_arr, qh, kq, ks, vq, vs)
     return jnp.transpose(out, (0, 2, 1, 3))
@@ -262,7 +262,8 @@ def paged_decode_attention(q, kq, ks, vq, vs, tables, pos, *,
                           nblocks=NB),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
       qh, kq, ks, vq, vs)

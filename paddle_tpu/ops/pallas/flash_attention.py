@@ -45,8 +45,7 @@ def _compiler_params(interpret):
     diverge); the interpret backend takes no compiler params."""
     if interpret:
         return None
-    from ...compat import tpu_compiler_params
-    return tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _ceil_to(x, m):

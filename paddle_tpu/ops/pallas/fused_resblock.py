@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params as _compiler_params
 
 
 def _default_interpret():
@@ -323,7 +322,8 @@ def _call(kernel, grid, in_arrays, in_specs, out_shapes, out_specs,
     return pl.pallas_call(
         kernel, grid=(grid,), in_specs=in_specs,
         out_shape=out_shapes, out_specs=out_specs,
-        compiler_params=_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret)(*in_arrays)
 
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params as _compiler_params
 
 
 # v5e scoped-VMEM default is 16MB; the 8MB double-buffered weight blocks
@@ -117,7 +116,8 @@ def weight_only_matmul(x, qweight, scale, out_dtype=None, interpret=None,
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x, qweight, scale.reshape(1, n))
 

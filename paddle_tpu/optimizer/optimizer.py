@@ -125,10 +125,7 @@ class Optimizer:
             sh = getattr(x, "sharding", None)
             if getattr(sh, "memory_kind", None) in ("pinned_host",
                                                     "unpinned_host"):
-                from ..compat import has_device_memory_kind
-
-                if has_device_memory_kind():
-                    return sh
+                return sh
             return None
 
         def _to_device(x):

@@ -61,11 +61,9 @@ class CppExtensionModule:
             return self._ops[key]
         target = f"{self.name}.{symbol}"
         if target not in self._registered:
-            from ..compat import ffi as _ffi
-
             fn_ptr = getattr(self._lib, symbol)
-            _ffi().register_ffi_target(
-                target, _ffi().pycapsule(fn_ptr), platform=platform)
+            jax.ffi.register_ffi_target(
+                target, jax.ffi.pycapsule(fn_ptr), platform=platform)
             self._registered.add(target)
 
         def impl(*arrays, **attrs):
@@ -74,9 +72,7 @@ class CppExtensionModule:
             else:
                 ref = arrays[out_like]
                 out = jax.ShapeDtypeStruct(ref.shape, ref.dtype)
-            from ..compat import ffi as _ffi
-
-            return _ffi().ffi_call(target, out)(*arrays, **attrs)
+            return jax.ffi.ffi_call(target, out)(*arrays, **attrs)
 
         if vjp is not None:
             from .custom_op import wrap_custom_vjp

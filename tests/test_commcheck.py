@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.analysis import commcheck as cc
-from paddle_tpu.compat import shard_map
+from jax import shard_map
 from paddle_tpu.sharding import cpu_mesh, named_sharding, replicated, spec
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -74,12 +74,10 @@ def test_cpp_op_under_jit(ext):
     op_raw = ext.get_op("MySoftShrink")
     x = np.linspace(-1, 1, 9).astype(np.float32)
 
-    from paddle_tpu.compat import ffi
-
     # the ffi target also composes into larger jitted programs
     def f(v):
         return jax.numpy.sum(
-            ffi().ffi_call("my_ops.MySoftShrink",
+            jax.ffi.ffi_call("my_ops.MySoftShrink",
                            jax.ShapeDtypeStruct(v.shape, v.dtype))(v) ** 2)
 
     got = jax.jit(f)(x)

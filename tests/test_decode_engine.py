@@ -40,13 +40,13 @@ def _shared_compile_cache(tmp_path_factory):
     compiles each bucket once, every later engine disk-loads it — the
     suite stays cheap AND the persistence path gets exercised."""
     d = str(tmp_path_factory.mktemp("decode-compile-cache"))
-    old = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    os.environ["PADDLE_TPU_COMPILE_CACHE"] = d
+    old = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = d
     yield d
     if old is None:
-        os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     else:
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = old
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = old
 
 
 @pytest.fixture(scope="module")
@@ -410,7 +410,7 @@ def test_warm_start_compiles_zero_decode_executables(
     decode-step/prefill executables (all disk loads) and produce the
     same tokens."""
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_COMPILE_CACHE=str(tmp_path / "cc"))
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
     outs = []
     for _ in range(2):
         r = subprocess.run([sys.executable, "-c", _WARM_SNIPPET], env=env,

@@ -43,13 +43,13 @@ K = 3
 @pytest.fixture(scope="module", autouse=True)
 def _shared_compile_cache(tmp_path_factory):
     d = str(tmp_path_factory.mktemp("decode-spec-compile-cache"))
-    old = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    os.environ["PADDLE_TPU_COMPILE_CACHE"] = d
+    old = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = d
     yield d
     if old is None:
-        os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     else:
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = old
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = old
 
 
 @pytest.fixture(scope="module")

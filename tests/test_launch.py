@@ -76,6 +76,24 @@ def test_launch_exports_canonical_mesh_env(tmp_path):
     assert "PADDLE_TPU_MESH" not in plain._env_for(0)
 
 
+def test_launch_refuses_several_accelerator_ranks_per_node(monkeypatch):
+    """A host's chips belong to one process: --nproc_per_node > 1 is
+    refused at launch when JAX_PLATFORMS names an accelerator, and the
+    ranks are pinned to CPU otherwise."""
+    from paddle_tpu.distributed.launch.context import Context, parse_args
+    from paddle_tpu.distributed.launch.controller import Controller
+
+    args = parse_args(["--nproc_per_node", "2", "t.py"])
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(SystemExit, match="one process owns"):
+        Context(args)
+    Context(parse_args(["--nproc_per_node", "1", "t.py"]))  # 1 is fine
+    monkeypatch.delenv("JAX_PLATFORMS")
+    c = Controller(Context(args))
+    c.master, c.node_rank = "127.0.0.1:1", 0
+    assert c._env_for(1)["JAX_PLATFORMS"] == "cpu"
+
+
 def test_launch_fail_fast_propagates_exit_code(tmp_path):
     r = _run_launch("""
         import os, sys, time

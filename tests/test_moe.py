@@ -171,10 +171,8 @@ def test_moe_alltoall_per_device_flops_scale():
         f_dense = jax.jit(functools.partial(
             _moe_ffn_impl, top_k=1, capacity=cap_dense, act="relu",
             disp_sharding=None))
-        from paddle_tpu.compat import cost_analysis
-
-        fl_a2a = cost_analysis(f_a2a.lower(*args).compile())["flops"]
-        fl_dense = cost_analysis(f_dense.lower(*args).compile())["flops"]
+        fl_a2a = f_a2a.lower(*args).compile().cost_analysis()["flops"]
+        fl_dense = f_dense.lower(*args).compile().cost_analysis()["flops"]
         assert fl_a2a < 0.5 * fl_dense, (fl_a2a, fl_dense)
     finally:
         dist.set_hybrid_communicate_group(None)

@@ -222,9 +222,5 @@ def test_group_sharded_offload_trains():
     loss = model(paddle.ones([4, 16])).sum()
     loss.backward()
     opt.step()
-    from paddle_tpu.compat import supports_memory_kind
-
-    want = "pinned_host" if supports_memory_kind("pinned_host") \
-        else "unpinned_host"  # backends without a pinned space degrade
-    assert model.weight._value.sharding.memory_kind == want
+    assert model.weight._value.sharding.memory_kind == "pinned_host"
     assert not np.allclose(w0, model.weight.numpy())

@@ -145,3 +145,34 @@ def test_flash_attention_bf16_path():
     np.testing.assert_allclose(np.asarray(out2, np.float32),
                                np.asarray(out, np.float32), rtol=0.05,
                                atol=0.05)
+
+
+def test_flash_per_shard_on_a_mesh_matches_unsharded():
+    """GSPMD refuses to partition a Mosaic kernel, so on a multi-device
+    mesh the engine runs flash per shard (batch over the data axes, heads
+    over tp). Attention is independent per row and head: the sharded
+    value and grads equal the unsharded ones; a shape the mesh does not
+    divide gets no wrapper (the caller falls back to XLA attention)."""
+    import functools
+
+    from paddle_tpu.distributed.context_parallel import batch_head_shard_map
+    from paddle_tpu.sharding import MeshConfig
+
+    mesh = MeshConfig(fsdp=4, tp=2).build()
+    q, k, v = _qkv(b=4, s=128, h=2, d=16, seed=3)
+    flash = functools.partial(flash_attention, causal=True, interpret=True)
+    sharded = batch_head_shard_map(flash, mesh, q.shape)
+
+    def loss(f, q, k, v):
+        return (f(q, k, v) ** 2).sum()
+
+    got = jax.jit(jax.value_and_grad(functools.partial(loss, sharded),
+                                     argnums=(0, 1, 2)))(q, k, v)
+    want = jax.value_and_grad(functools.partial(loss, flash),
+                              argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+    assert batch_head_shard_map(flash, mesh, (3, 128, 2, 16)) is None
+    assert batch_head_shard_map(flash, mesh, (4, 128, 3, 16)) is None

@@ -9,19 +9,9 @@ import paddle_tpu as paddle
 from paddle_tpu.distributed import HostOffloadedEmbedding
 
 
-def _host_kind():
-    """The host memory space the table should live in: pinned_host where
-    the backend has one, else the backend's sole host space (older jax
-    CPU)."""
-    from paddle_tpu.compat import supports_memory_kind
-
-    return "pinned_host" if supports_memory_kind("pinned_host") \
-        else "unpinned_host"
-
-
 def test_table_lives_in_host_memory():
     tab = HostOffloadedEmbedding(1000, 16, optimizer="sgd")
-    assert tab.memory_kind == _host_kind()
+    assert tab.memory_kind == "pinned_host"
 
 
 def test_lookup_matches_table_rows():
@@ -50,7 +40,7 @@ def test_sparse_push_updates_only_touched_rows():
     np.testing.assert_array_equal(after[untouched], before[untouched])
     # no dense gradient ever materializes for the table
     assert tab.weight.grad is None
-    assert tab.memory_kind == _host_kind()
+    assert tab.memory_kind == "pinned_host"
 
 
 def test_adagrad_accumulates():
@@ -76,7 +66,7 @@ def test_larger_than_device_memory_trains():
     N, D = 200_000, 64
     tab = HostOffloadedEmbedding(N, D, optimizer="sgd", learning_rate=0.1)
     tab.train()
-    assert tab.memory_kind == _host_kind()
+    assert tab.memory_kind == "pinned_host"
     rng = np.random.RandomState(0)
     ids_np = rng.randint(0, N, size=(64,)).astype(np.int32)
     before = np.asarray(tab.weight._value)[ids_np[0]].copy()

@@ -519,7 +519,7 @@ def test_framework_runs_clean_via_cli(tmp_path):
     and finite."""
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               PADDLE_TPU_COMPILE_CACHE=str(tmp_path / "cc"))
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
     env.pop("PADDLE_TPU_SAN", None)        # the CLI enables it itself
     r = subprocess.run([sys.executable, CLI], capture_output=True,
                        text=True, env=env, timeout=180)

@@ -494,7 +494,7 @@ class TestExportedArtifact:
         from paddle_tpu.inference.serving import ServingPool
         from paddle_tpu.jit import save_load
 
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = str(tmp_path / "cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
         try:
             paddle.seed(3)
             topo.set_hybrid_communicate_group(None)   # trace without mesh
@@ -541,7 +541,7 @@ class TestExportedArtifact:
             assert np.allclose(stacked[0], ref, atol=1e-5)
             assert np.allclose(stacked[1], ref, atol=1e-5)
         finally:
-            os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
 # ---------------------------------------------------------------------------
@@ -553,7 +553,7 @@ class TestDecodeEngineTP:
         from paddle_tpu.models.gpt import gpt
         from paddle_tpu.inference.decode import DecodeEngine
 
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = str(tmp_path / "cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
         try:
             cfg = dict(vocab_size=97, hidden_size=48, num_heads=4,
                        num_kv_heads=2, num_layers=2, rope=True,
@@ -595,7 +595,7 @@ class TestDecodeEngineTP:
             finally:
                 eng.shutdown()
         finally:
-            os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
 # ---------------------------------------------------------------------------
@@ -615,7 +615,7 @@ class TestDecodeEngineCP:
         from paddle_tpu.inference.decode import DecodeEngine
         from paddle_tpu.analysis import runtime_san
 
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = str(tmp_path / "cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
         try:
             cfg = dict(vocab_size=97, hidden_size=48, num_heads=4,
                        num_kv_heads=2, num_layers=2, rope=True,
@@ -667,7 +667,7 @@ class TestDecodeEngineCP:
             finally:
                 eng.shutdown()
         finally:
-            os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
     def test_cp_indivisible_bucket_falls_back_replicated(self):
         """A prefill bucket the cp group can't split evenly keeps

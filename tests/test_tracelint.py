@@ -679,8 +679,8 @@ def test_cli_dotted_package_resolves_without_importing(tmp_path):
         "p = m._resolve_package('paddle_tpu.jit')\n"
         "assert p and p.replace('\\\\', '/').endswith("
         "'paddle_tpu/jit'), p\n"
-        "assert m._resolve_package('paddle_tpu.compat').endswith("
-        "'compat.py')\n"
+        "assert m._resolve_package('paddle_tpu.device').endswith("
+        "'device.py')\n"
         "assert m._resolve_package('paddle_tpu.no_such_mod') is None\n"
         "assert 'paddle_tpu' not in sys.modules, 'parent was imported'\n"
         "assert 'jax' not in sys.modules, 'jax was imported'\n")

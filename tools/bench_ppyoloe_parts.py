@@ -22,7 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BATCH = int(os.environ.get("BENCH_BATCH", "16"))
 SIZE = int(os.environ.get("BENCH_SIZE", "640"))
 STEPS = int(os.environ.get("BENCH_STEPS", "20"))
-PEAK_TFLOPS = float(os.environ.get("BENCH_PEAK_TFLOPS", "197"))
 
 
 def main():
@@ -30,6 +29,10 @@ def main():
     import jax.numpy as jnp
 
     import paddle_tpu as paddle
+    from paddle_tpu.device import chip_peaks
+
+    # the one sourced table; a device_kind without a row is an error
+    peak = chip_peaks(jax.devices()[0].device_kind)["bf16_flops"]
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.distributed.functional import functionalize
     from paddle_tpu.vision.models import ppyoloe_s
@@ -89,13 +92,8 @@ def main():
     results = {}
     for name, (f, batch) in progs.items():
         jf = jax.jit(f)
-        try:
-            from paddle_tpu.compat import cost_analysis
-
-            flops = cost_analysis(jf.lower(pv, *batch).compile())
-            flops = float(flops.get("flops", 0.0)) if flops else 0.0
-        except Exception:
-            flops = 0.0
+        flops = float(
+            jf.lower(pv, *batch).compile().cost_analysis()["flops"])
         out = jf(pv, *batch)
         _fence(out)
         best = float("inf")
@@ -106,7 +104,7 @@ def main():
                 o = jf(pv, *batch)
             _fence(o)
             best = min(best, (time.perf_counter() - t0) / STEPS)
-        mfu = flops / best / (PEAK_TFLOPS * 1e12)
+        mfu = flops / best / peak
         results[name] = (best, flops, mfu)
         print(f"{name:18s} {best * 1e3:8.2f} ms  {flops / 1e9:9.1f} GF  "
               f"MFU {mfu * 100:5.1f}%")
@@ -119,7 +117,7 @@ def main():
                        "forward_fwdbwd")):
         dt = results[a][0] - results[b][0]
         df = results[a][1] - results[b][1]
-        mfu = df / dt / (PEAK_TFLOPS * 1e12) if dt > 0 else float("nan")
+        mfu = df / dt / peak if dt > 0 else float("nan")
         print(f"{tag:22s} {dt * 1e3:8.2f} ms  {df / 1e9:9.1f} GF  "
               f"differential MFU {mfu * 100:5.1f}%")
 

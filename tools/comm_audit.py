@@ -47,7 +47,6 @@ import argparse
 import json
 import os
 import sys
-import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -308,18 +307,12 @@ def main(argv=None):
                   file=sys.stderr)
             return USAGE_ERROR
 
-    # hermetic compile cache unless pinned (same contract as graph_audit):
-    # every smoke then COMPILES — disk hits would skip the record hooks
-    pinned = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    with tempfile.TemporaryDirectory(prefix="comm-audit-") as tmp:
-        if pinned is None:
-            os.environ["PADDLE_TPU_COMPILE_CACHE"] = \
-                os.path.join(tmp, "compile-cache")
-        try:
-            schedules, errors, report = run_smokes(smokes)
-        finally:
-            if pinned is None:
-                os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+    # hermetic AOT cache (same contract as graph_audit): every smoke then
+    # COMPILES — disk hits would skip the record hooks
+    from paddle_tpu.jit.aot import hermetic_cache
+
+    with hermetic_cache(prefix="comm-audit-"):
+        schedules, errors, report = run_smokes(smokes)
 
     from paddle_tpu.analysis import commcheck
 

@@ -43,7 +43,6 @@ import argparse
 import json
 import os
 import sys
-import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -308,18 +307,12 @@ def main(argv=None):
                   file=sys.stderr)
             return USAGE_ERROR
 
-    # hermetic compile cache unless pinned (same contract as tpu_san):
-    # every smoke then COMPILES — disk hits would skip the audit hooks
-    pinned = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    with tempfile.TemporaryDirectory(prefix="graph-audit-") as tmp:
-        if pinned is None:
-            os.environ["PADDLE_TPU_COMPILE_CACHE"] = \
-                os.path.join(tmp, "compile-cache")
-        try:
-            counts, wm, report = run_smokes(smokes, tmp)
-        finally:
-            if pinned is None:
-                os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+    # hermetic AOT cache (same contract as tpu_san): every smoke then
+    # COMPILES — disk hits would skip the audit hooks
+    from paddle_tpu.jit.aot import hermetic_cache
+
+    with hermetic_cache(prefix="graph-audit-") as tmp:
+        counts, wm, report = run_smokes(smokes, tmp)
 
     from paddle_tpu.analysis import graphcheck
 

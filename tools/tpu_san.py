@@ -40,7 +40,6 @@ import argparse
 import json
 import os
 import sys
-import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -270,20 +269,11 @@ def main(argv=None):
                   file=sys.stderr)
             return USAGE_ERROR
 
-    # hermetic compile cache unless the caller pinned one (repeat runs in
-    # CI must not grow $HOME; a pinned cache proves warm-start behavior).
-    # The env var is RESTORED afterwards: in-process callers (tests) must
-    # not be left pointing at a deleted tmp dir.
-    pinned = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    with tempfile.TemporaryDirectory(prefix="tpu-san-") as tmp:
-        if pinned is None:
-            os.environ["PADDLE_TPU_COMPILE_CACHE"] = \
-                os.path.join(tmp, "compile-cache")
-        try:
-            counts, report = run_smokes(smokes)
-        finally:
-            if pinned is None:
-                os.environ.pop("PADDLE_TPU_COMPILE_CACHE", None)
+    # hermetic AOT cache: every run sees the same cold start
+    from paddle_tpu.jit.aot import hermetic_cache
+
+    with hermetic_cache(prefix="tpu-san-"):
+        counts, report = run_smokes(smokes)
 
     from paddle_tpu.analysis import runtime_san
 
