@@ -1,0 +1,19 @@
+"""The served work's share of the chip's bf16 peak over the traced stretch:
+one forward of every prompt whose first token arrived in it and of every
+decoded token delivered in it (2 x matmul weights + attention over the
+tokens present, logits only where a token is chosen)."""
+from benchmarks import flops
+
+
+def read(ctx):
+    s = ctx["scope"]
+    if not s or not ctx["peaks"]:
+        return None
+    work = flops.serve_flops(ctx["model"], s["decode_positions"],
+                             len(s["decode_positions"]))
+    work += sum(flops.serve_flops(ctx["model"], range(p), 1)
+                for p in s["prompts_finished"])
+    if not work:
+        return None
+    return 100.0 * work / (s["window_s"] * ctx["peaks"]["bf16_flops"]
+                           * ctx["cell"]["chips"])
