@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from ..analysis import commcheck as _cc
 from ..analysis import graphcheck as _gc
 from ..analysis import runtime_san as _san
+from ..obs import trace as _otrace
 from ..core import lazy as _lazy
 from ..core.tensor import Tensor
 from ..nn.clip import ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue
@@ -69,11 +70,10 @@ _ENGINE_OBS_SEQ = itertools.count()
 
 
 def _span(name, histogram=None):
-    """`profiler.profiled_span` indirection: a RecordEvent span when a
-    host profiler is actively recording, else a no-op — keeps the native
-    tracer (and its first-use build) entirely off the un-profiled hot
-    path. With `histogram=` the span ALSO feeds that obs latency
-    histogram on every pass, recording or not."""
+    """`profiler.profiled_span` indirection (imported on first use): a
+    child of the call's `engine.dispatch` root span in the flight
+    recorder, the `histogram=` observation, and a RecordEvent while a
+    host profiler records."""
     global _prof_mod
     if _prof_mod is None:
         from .. import profiler as _p
@@ -542,16 +542,31 @@ class ShardedTrainStep:
                         placed, self._lr_scalar(), self._key_scalar(),
                         self._step_scalar())
 
+    def _dispatch_root(self, steps):
+        """The root span of one `train_batch` / `train_batches` call
+        (`engine.dispatch`, attrs `steps` and `cold`): its children are
+        the `engine::device_put` / `engine::dispatch` /
+        `engine::write_back` spans, its own time the host code between
+        them. The calling thread keeps a window of them (`flight`)."""
+        _otrace.reserve_ring()
+        return _otrace.root_span("engine.dispatch",
+                                 attrs={"steps": steps}, profile=True)
+
     def train_batch(self, *batch):
         """Run one optimizer step; returns the (device) loss Tensor."""
         if self.optimizer is None:
             raise RuntimeError(
                 "this engine was built without an optimizer; use eval_batch")
+        with self._dispatch_root(1) as root:
+            return self._train_batch(root, batch)
+
+    def _train_batch(self, root, batch):
         self._adopt_external_writes()
         with _span("engine::device_put"):
             placed = self._place_batch(batch)
         san = _san.enabled()
         cold = self._step_fn is None
+        root.set_attr("cold", cold)
         if san:
             # per-call sentinel: the step jit retraces INTERNALLY on any
             # new batch signature — a cache-keyed build hook would miss
@@ -635,6 +650,10 @@ class ShardedTrainStep:
             batches = batches[:n]
         if not batches:
             return Tensor(jnp.zeros((0,), jnp.float32))
+        with self._dispatch_root(len(batches)) as root:
+            return self._train_batches(root, batches)
+
+    def _train_batches(self, root, batches):
         n = len(batches)
         static = all(b is batches[0] for b in batches[1:])
         norm = [tuple(b) if isinstance(b, (list, tuple)) else (b,)
@@ -687,6 +706,7 @@ class ShardedTrainStep:
                             (sig, self._san_mesh_sig), per_call=True)
         fn = self._multi_fns.get(sig)
         cold = fn is None
+        root.set_attr("cold", cold)
         if cold:
             fn = self._build_multi(placed, static)
             self._multi_fns[sig] = fn

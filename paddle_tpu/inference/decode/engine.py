@@ -103,10 +103,13 @@ or through a `ServingPool(..., decode_engine=engine)` via
 """
 from __future__ import annotations
 
+import collections
 import hashlib
 import itertools
+import logging
 import math
 import queue
+import statistics
 import threading
 import time
 
@@ -115,6 +118,7 @@ import numpy as np
 from ...analysis import locks as _locks
 from ...analysis import graphcheck as _gc
 from ...analysis import runtime_san as _san
+from ...obs import flight as _flight
 from ...obs import trace as _otrace
 from ..serving import (AdapterNotLoaded, Deadline, DeadlineExceeded,
                        Overloaded, PoolClosed, RequestFailed, RetryPolicy,
@@ -131,6 +135,32 @@ _WAITING, _PREFILL, _ACTIVE, _DONE = "waiting", "prefill", "active", "done"
 _CACHE_OWNER = "prefix-cache"
 
 _END = object()   # stream sentinel
+
+_log = logging.getLogger(__name__)
+
+# scheduler-phase spans (docs/observability.md, "Reading an idle gap"):
+# every loop iteration that finds work is one `decode.round` root and
+# these phases tile it. `.enqueue` and `.fetch` run on the step-pool
+# worker as children of the scheduler's `.handoff`, whose own time is
+# therefore the hand-off itself.
+_ROUND = "decode.round"
+_IDLE = "decode.idle_wait"
+_ADMIT = _ROUND + ".admit"
+
+
+def _phase_names(kind):
+    base = f"{_ROUND}.{kind}"
+    return {"round": base, **{p: f"{base}.{p}" for p in (
+        "grow", "pack", "handoff", "enqueue", "fetch", "deliver")}}
+
+
+_PREFILL, _DECODE = _phase_names("prefill"), _phase_names("decode")
+
+#: a round (or an idle wait with work queued) is SLOW past this many
+#: seconds AND this many times the median of the last rounds
+_SLOW_ROUND_S = 1.0
+_SLOW_ROUND_X = 4.0
+_SLOW_ROUND_HISTORY = 64
 
 
 class SequenceStream:
@@ -246,7 +276,8 @@ class _Seq:
                  "cancelled", "submitted_at", "span", "draft_blocks",
                  "draft_pos", "draft_outstanding", "spec_proposed",
                  "spec_accepted", "sampling", "adapter", "adapter_slot",
-                 "adapter_sig", "sample_base", "out_tokens", "held")
+                 "adapter_sig", "sample_base", "out_tokens", "held",
+                 "t_submit", "t_admit", "t_first", "round_admit", "chunks")
 
     def __init__(self, sid, prompt, max_new, deadline):
         self.id = sid
@@ -281,6 +312,12 @@ class _Seq:
         self.out_tokens = []           # every committed token (incl. held)
         self.held = []                 # committed, not yet streamed (stop
         #                                hold-back: a possible stop prefix)
+        # perf_counter stamps of the sequence span's decomposition
+        self.t_submit = time.perf_counter()
+        self.t_admit = None            # admission (_begin_sequence)
+        self.t_first = None            # first token committed
+        self.round_admit = None        # scheduler round it was admitted in
+        self.chunks = 0                # prefill dispatches it took
 
 
 #: registry collector keys need a distinct name per engine instance
@@ -612,6 +649,12 @@ class DecodeEngine:
         self._spec_draft_dispatches = 0
         self._spec_catchup_chunks = 0
         self._spec_fallbacks = 0
+        # scheduler rounds: written by the scheduler thread alone
+        # (_slow_rounds under _lock: stats() reads it)
+        self._round_no = 0
+        self._slow_rounds = 0
+        self._round_times = collections.deque(maxlen=_SLOW_ROUND_HISTORY)
+        self._last_step = None    # (bucket, member ids) of the round's step
 
         # telemetry (paddle_tpu.obs): TTFT observed at first-token
         # delivery plus stats() as a registry collector. TWO histograms
@@ -628,12 +671,16 @@ class DecodeEngine:
         if metrics is False:
             self._metrics = None
             self._h_ttft_shared = None
+            self._h_queue_wait = None
         else:
             self._metrics = metrics if metrics is not None \
                 else _obs_registry()
             self._h_ttft_shared = self._metrics.histogram(
                 "decode.ttft_seconds",
                 help="time to first token: admission -> first delivery")
+            self._h_queue_wait = self._metrics.histogram(
+                "decode.queue_wait_seconds",
+                help="submit -> admission into the resident batch")
 
         self._thread = threading.Thread(target=self._loop,
                                         name="DecodeEngine-scheduler",
@@ -1524,57 +1571,160 @@ class DecodeEngine:
         table[: len(seq.blocks)] = seq.blocks
         return table
 
-    def _submit_step(self, run):
+    def _submit_step(self, run, names):
         """Dispatch a step closure on the supervised step pool. A wedged
         dispatch (pool hang detection fired: worker retired, capacity
         restored) is re-submitted — the closure is a pure function of the
         last COMMITTED state, so a re-run is safe and batchmates lose
         nothing. `RequestFailed` / `PoolClosed` propagate to the caller
-        for classification."""
+        for classification.
+
+        Each attempt is one `.handoff` span of the round's phase
+        (`names`): `run(member, ctx)` opens its `.enqueue` / `.fetch`
+        under `ctx` on the worker, so the span's self time is the way to
+        the worker and back. The step pool itself is entered detached:
+        its admission must not add spans of its own to the round."""
         last = None
         for _ in range(self._step_retries + 1):
-            req = self._steps.submit(run, timeout=self.step_timeout)
-            try:
-                return req.result()
-            except DeadlineExceeded as e:
-                with self._lock:
-                    self._wedged_steps += 1
-                last = e
+            with _otrace.span(names["handoff"], profile=True) as hand:
+                def call(member, ctx=hand.ctx):
+                    _otrace.reserve_ring()   # the worker keeps a window
+                    return run(member, ctx)
+
+                with _otrace.detached():
+                    req = self._steps.submit(call,
+                                             timeout=self.step_timeout)
+                try:
+                    return req.result()
+                except DeadlineExceeded as e:
+                    with self._lock:
+                        self._wedged_steps += 1
+                    last = e
         raise RequestFailed(
             f"decode step wedged {self._step_retries + 1} time(s) — "
             f"giving up", cause=last,
             attempts=self._step_retries + 1)
 
     def _loop(self):
+        """The scheduler thread. Its time is tiled by `decode.round`
+        roots (an iteration that found work) and `decode.idle_wait`
+        roots (the stretch it slept with nothing to do); a round starts
+        at the loop top, so the wait for the engine's lock is inside
+        its `.admit` phase and nothing between two rounds is unnamed."""
+        if _otrace.enabled():
+            _otrace.reserve_ring()
+            _otrace.watch_gc()
+        idle = None
         while True:
+            t_top = time.perf_counter()
             with self._cv:
                 if self._stopping:
-                    return
-                if self._closed and not self._waiting and not self._active \
-                        and not self._prefill_q:
-                    return
+                    break
                 if not self._waiting and not self._active \
                         and not self._prefill_q:
+                    if self._closed:
+                        break
+                    if idle is None:
+                        idle = (_otrace.root_span(_IDLE, profile=True,
+                                                  t0=t_top), t_top)
                     self._cv.wait(0.05)
                     continue
-            try:
-                self._sweep_waiting()
-                self._admit_waiting()
-                self._sweep_prefilling()
+                counts = (len(self._active), len(self._prefill_q),
+                          len(self._waiting))
+                oldest = self._waiting[0].t_submit if self._waiting \
+                    else None
+            if idle is not None:
+                t_top = self._end_idle(idle, oldest)
+                idle = None
+            self._round(t_top, counts)
+        if idle is not None:
+            idle[0].end()
+
+    def _end_idle(self, idle, oldest):
+        """Close a `decode.idle_wait`. Work that sat in the queue while
+        the loop slept (`work_waited_s`: normally the wake-up latency of
+        one notify) is judged like a round: an idle wait is slow only by
+        the part of it that had work."""
+        sp, t0 = idle
+        now = time.perf_counter()
+        waited = 0.0 if oldest is None else \
+            max(0.0, now - max(oldest, t0))
+        sp.set_attr("work_waited_s", waited)
+        sp.end()
+        if waited > _SLOW_ROUND_S:
+            self._judge_round(sp, waited, f"idle wait before round "
+                                          f"{self._round_no + 1}")
+        return now
+
+    def _round(self, t0, counts):
+        self._round_no += 1
+        self._last_step = None
+        root = _otrace.root_span(
+            _ROUND, attrs={"round": self._round_no, "active": counts[0],
+                           "prefilling": counts[1], "waiting": counts[2]}
+            if _otrace.enabled() else None, profile=True, t0=t0)
+        try:
+            with root:
+                with _otrace.span(_ADMIT, profile=True, t0=t0):
+                    self._sweep_waiting()
+                    self._admit_waiting()
+                    self._sweep_prefilling()
                 # ONE prefill chunk per round, interleaved with the
                 # decode step below: a long prompt advances chunk by
                 # chunk while the running batch keeps streaming tokens
-                self._prefill_round()
+                if self._prefill_q:
+                    with _otrace.span(_PREFILL["round"],
+                                      profile=True):
+                        self._prefill_round()
                 if self._active:
-                    self._decode_round()
-            except Exception as exc:  # noqa: BLE001 — scheduler must
-                # survive anything: fail the implicated sequences with a
-                # typed error instead of silently dying with them stuck
-                err = RequestFailed(
-                    f"decode scheduler error: {type(exc).__name__}: {exc}",
-                    cause=exc)
-                for seq in list(self._active) + list(self._prefill_q):
-                    self._finish(seq, "failed", err)
+                    with _otrace.span(_DECODE["round"],
+                                      profile=True):
+                        self._decode_round()
+        except Exception as exc:  # noqa: BLE001 — scheduler must
+            # survive anything: fail the implicated sequences with a
+            # typed error instead of silently dying with them stuck
+            err = RequestFailed(
+                f"decode scheduler error: {type(exc).__name__}: {exc}",
+                cause=exc)
+            for seq in list(self._active) + list(self._prefill_q):
+                self._finish(seq, "failed", err)
+        took = time.perf_counter() - t0
+        if took > _SLOW_ROUND_S:
+            self._judge_round(root, took, f"round {self._round_no}")
+        self._round_times.append(took)
+
+    def _judge_round(self, span, took, what):
+        """Rare path (a round or an in-work idle wait past
+        `_SLOW_ROUND_S`): past `_SLOW_ROUND_X` times the median of the
+        last rounds it is pinned in the flight recorder (`slow_round`),
+        counted, and logged once with its phases — so the run that meets
+        a stall names the phase in its own log, traced or not."""
+        if not self._round_times:
+            return
+        usual = statistics.median(self._round_times)
+        if took <= _SLOW_ROUND_X * usual:
+            return
+        with self._lock:
+            self._slow_rounds += 1
+        phases = {}
+        if span.ctx is not None:
+            rec = _flight.recorder().pin(span.ctx.trace_id,
+                                         reason="slow_round")
+            for sp in rec["spans"]:
+                phases[sp.name] = round(
+                    phases.get(sp.name, 0.0) + sp.t1 - sp.t0, 4)
+            t1 = time.perf_counter()
+            for sp in _flight.recorder().spans_between(
+                    t1 - took, t1, prefix="host.gc")[0]:
+                phases["host.gc"] = round(
+                    phases.get("host.gc", 0.0) + sp.t1 - sp.t0, 4)
+        bucket, members = self._last_step or (None, [])
+        _log.warning(
+            "decode engine %s: slow %s: %.3f s against a median of %.3f s "
+            "over the last %d rounds; phases (s) %s; bucket %s; members "
+            "%s; trace %s", self.name, what, took, usual,
+            len(self._round_times), phases, bucket, members,
+            span.trace_id_hex)
 
     def _sweep_waiting(self):
         with self._cv:
@@ -1653,6 +1803,11 @@ class DecodeEngine:
         it: a full-prompt hit joins the running batch immediately — zero
         prompt compute — anything else enters the chunked-prefill queue."""
         plen = len(seq.prompt)
+        seq.t_admit = time.perf_counter()
+        seq.round_admit = self._round_no
+        if self._h_queue_wait is not None and seq.submitted_at is not None:
+            self._h_queue_wait.observe(self._clock() - seq.submitted_at,
+                                       ctx=seq.span.ctx)
         if entry is not None:
             self.pool.incref(entry["blocks"], owner=seq.id)
             seq.blocks = list(entry["blocks"])
@@ -1722,6 +1877,7 @@ class DecodeEngine:
         when chunking is off or the prompt fits one chunk). On the final
         chunk the sequence publishes its prefix-cache entries and joins
         the running batch."""
+        names = _PREFILL
         plen = len(seq.prompt)
         start = seq.prefill_pos
         remaining = plen - start
@@ -1729,62 +1885,81 @@ class DecodeEngine:
                                    and remaining > self._chunk) \
             else remaining
         pbucket = next(p for p in self.prefill_buckets if p >= this_len)
-        # fresh blocks to hold positions [len(blocks)*bs, start+this_len)
-        need = self.pool.blocks_for(start + this_len) - len(seq.blocks)
-        if need > 0:
-            try:
-                seq.blocks += self.pool.alloc(need, owner=seq.id)
-                seq.outstanding -= need
-            except OutOfBlocks as e:  # admission gate guarantees this
-                raise RequestFailed(   # can't — an over-admission bug
-                    f"sequence {seq.id}: block pool exhausted at prefill",
-                    cause=e) from e
-        fn = self._prefill_fn(pbucket)
-        pv, bv = self._weights()
-        ats = self._adapter_stacks()
-        aid = np.asarray(seq.adapter_slot, np.int32)
-        hist = self._hist_row(seq)
-        samp = self._samp_row(seq)
-        tokens = np.full((1, pbucket), self.pad_token_id, np.int32)
-        tokens[0, :this_len] = seq.prompt[start:start + this_len]
-        table = self._padded_table(seq, self._nb + self._prefill_tail)
-        pool_ts = self.pool.tensors
+        with _otrace.span(names["pack"], profile=True):
+            # fresh blocks to hold positions
+            # [len(blocks)*bs, start+this_len)
+            need = self.pool.blocks_for(start + this_len) - len(seq.blocks)
+            if need > 0:
+                try:
+                    seq.blocks += self.pool.alloc(need, owner=seq.id)
+                    seq.outstanding -= need
+                except OutOfBlocks as e:
+                    # the admission gate guarantees this can't happen —
+                    # an over-admission bug
+                    raise RequestFailed(
+                        f"sequence {seq.id}: block pool exhausted at "
+                        f"prefill", cause=e) from e
+            fn = self._prefill_fn(pbucket)
+            pv, bv = self._weights()
+            ats = self._adapter_stacks()
+            aid = np.asarray(seq.adapter_slot, np.int32)
+            hist = self._hist_row(seq)
+            samp = self._samp_row(seq)
+            tokens = np.full((1, pbucket), self.pad_token_id, np.int32)
+            tokens[0, :this_len] = seq.prompt[start:start + this_len]
+            table = self._padded_table(seq, self._nb + self._prefill_tail)
+            pool_ts = self.pool.tensors
         hook = self._fault_hook
         sctx = seq.span.ctx
         chunked = this_len < remaining or start > 0
+        rnd = self._round_no
 
-        def run(_member):
+        def run(_member, hctx):
             if hook is not None:
                 hook("prefill", [seq.id], {"bucket": pbucket,
                                            "start": start,
                                            "tokens": this_len})
             # chunk span in the SEQUENCE's trace (the step-pool worker
             # thread re-enters the sequence context explicitly), so a
-            # chunked TTFT decomposes chunk by chunk in /traces/<id>
+            # chunked TTFT decomposes chunk by chunk in /traces/<id>;
+            # `round` joins it to the scheduler round that submitted it
             with _otrace.span_in(
                     "decode.prefill_chunk" if chunked
                     else "decode.prefill", sctx,
                     attrs=None if sctx is None else
                     {"seq": seq.id, "bucket": pbucket, "start": start,
-                     "tokens": this_len, "prompt_len": plen}), \
+                     "tokens": this_len, "prompt_len": plen,
+                     "round": rnd}, profile=True), \
                     _locks.blocking_region("decode.step_dispatch"):
                 # the hot-sync probe covers the dispatch only; the token
                 # readback below is the step's deliverable (streaming
                 # needs the committed value on the host) and is
                 # sanctioned inside the step pool's serving.execute
                 # region
-                with _san.hot_region("decode.step_dispatch"):
+                with _san.hot_region("decode.step_dispatch"), \
+                        _otrace.span_in(names["enqueue"], hctx,
+                                        profile=True):
                     new_pool, nxt = fn(pv, bv, ats, pool_ts, tokens,
                                        np.asarray(start, np.int32),
                                        np.asarray(this_len, np.int32),
                                        table, aid, hist, samp)
                 self._san_sweep(new_pool)
-                with _san.allow_host_sync("decode.token_fetch"):
+                with _san.allow_host_sync("decode.token_fetch"), \
+                        _otrace.span_in(names["fetch"], hctx,
+                                        profile=True):
                     return new_pool, int(np.asarray(nxt))
 
-        new_pool, tok = self._submit_step(run)
+        new_pool, tok = self._submit_step(run, names)
+        with _otrace.span(names["deliver"], profile=True):
+            self._prefill_done(seq, new_pool, tok, start + this_len)
+
+    def _prefill_done(self, seq, new_pool, tok, done):
+        """Commit one prompt chunk: the pool, the prefix-cache entries it
+        completes and, after the last chunk, the first token."""
+        plen = len(seq.prompt)
         self.pool.tensors = new_pool
-        seq.prefill_pos = done = start + this_len
+        seq.prefill_pos = done
+        seq.chunks += 1
         with self._lock:
             self._prefill_chunks += 1
         if self._prefix_on and self._chunk and done % self._chunk == 0 \
@@ -1947,6 +2122,8 @@ class DecodeEngine:
         seq.last_token = tok
         seq.generated += 1
         seq.out_tokens.append(int(tok))
+        if seq.generated == 1:
+            seq.t_first = time.perf_counter()
         if seq.generated == 1 and seq.submitted_at is not None:
             ttft = self._clock() - seq.submitted_at
             self._h_ttft.observe(ttft, ctx=seq.span.ctx)
@@ -2054,20 +2231,22 @@ class DecodeEngine:
         # (the prefix cache, or a prefix-sharing batchmate) also
         # references must not be visible to them, so the sequence copies
         # that one block first and drops its shared reference.
-        for seq in list(active):
-            try:
-                if seq.pos >= len(seq.blocks) * self.block_size:
-                    seq.blocks += self.pool.alloc(1, owner=seq.id)
-                    seq.outstanding -= 1
-                else:
-                    bi = seq.pos // self.block_size
-                    if self.pool.refcount(seq.blocks[bi]) > 1:
-                        self._cow_block(seq, bi)
-            except OutOfBlocks as e:
-                active.remove(seq)
-                self._finish(seq, "failed", RequestFailed(
-                    f"sequence {seq.id}: block pool exhausted "
-                    f"mid-decode (admission reserve bug)", cause=e))
+        names = _DECODE
+        with _otrace.span(names["grow"], profile=True):
+            for seq in list(active):
+                try:
+                    if seq.pos >= len(seq.blocks) * self.block_size:
+                        seq.blocks += self.pool.alloc(1, owner=seq.id)
+                        seq.outstanding -= 1
+                    else:
+                        bi = seq.pos // self.block_size
+                        if self.pool.refcount(seq.blocks[bi]) > 1:
+                            self._cow_block(seq, bi)
+                except OutOfBlocks as e:
+                    active.remove(seq)
+                    self._finish(seq, "failed", RequestFailed(
+                        f"sequence {seq.id}: block pool exhausted "
+                        f"mid-decode (admission reserve bug)", cause=e))
         if not active:
             return
         try:
@@ -2084,8 +2263,9 @@ class DecodeEngine:
                 self._isolations += 1
             self._run_isolated(active)
             return
-        for seq, tok in zip(active, nxt):
-            self._deliver(seq, int(tok))
+        with _otrace.span(names["deliver"], profile=True):
+            for seq, tok in zip(active, nxt):
+                self._deliver(seq, int(tok))
 
     def _run_linked_step(self, name, event_name, seqs, hook_tag, info,
                          dispatch, sweep=False):
@@ -2105,24 +2285,33 @@ class DecodeEngine:
                    if s.span.ctx is not None and s.span.ctx.sampled]
                   if _otrace.enabled() else [])
         member_extra = {k: v for k, v in info.items() if k != "bucket"}
+        names = _DECODE
+        rnd = self._round_no
+        self._last_step = (info.get("bucket"), ids)
 
-        def run(_member):
+        def run(_member, hctx):
             if hook is not None:
                 hook(hook_tag, ids, info)
+            # `round` joins the step's own trace to the scheduler round
+            # that submitted it
             step_span = _otrace.null_span() if not traced else \
                 _otrace.root_span(
                     name,
-                    attrs={**info, "n": len(seqs),
+                    attrs={**info, "n": len(seqs), "round": rnd,
                            "links": [s.span.trace_id_hex
                                      for s in traced]},
-                    sampled=True)  # inherit the members' sampling: a
-            #                        dangling back-link helps nobody
+                    sampled=True,  # inherit the members' sampling: a
+                    profile=True)  # dangling back-link helps nobody
             with step_span, _locks.blocking_region("decode.step_dispatch"):
-                with _san.hot_region("decode.step_dispatch"):
+                with _san.hot_region("decode.step_dispatch"), \
+                        _otrace.span_in(names["enqueue"], hctx,
+                                        profile=True):
                     new_pool, host = dispatch()
                 if sweep:
                     self._san_sweep(new_pool)
-                with _san.allow_host_sync("decode.token_fetch"):
+                with _san.allow_host_sync("decode.token_fetch"), \
+                        _otrace.span_in(names["fetch"], hctx,
+                                        profile=True):
                     out = new_pool, np.asarray(host)
             for s in traced:
                 _otrace.event_in(
@@ -2131,26 +2320,27 @@ class DecodeEngine:
                            "step_trace": step_span.trace_id_hex})
             return out
 
-        return self._submit_step(run)
+        return self._submit_step(run, names)
 
     def _dispatch_decode(self, active):
         n = len(active)
         bucket = next(b for b in self.decode_buckets if b >= n)
-        fn = self._decode_fn(bucket)
-        pv, bv = self._weights()
-        ats = self._adapter_stacks()
-        tokens = np.zeros(bucket, np.int32)
-        positions = np.zeros(bucket, np.int32)
-        tables = np.zeros((bucket, self._nb), np.int32)  # pad rows -> 0
-        aids = np.zeros(bucket, np.int32)  # pad rows -> slot 0 (no-op)
-        for i, seq in enumerate(active):
-            tokens[i] = seq.last_token
-            positions[i] = seq.pos
-            tables[i] = self._padded_table(seq)
-            aids[i] = seq.adapter_slot
-        hist = self._hist_pack(active, bucket)
-        samp = self._samp_pack(active, bucket)
-        pool_ts = self.pool.tensors
+        with _otrace.span(_DECODE["pack"], profile=True):
+            fn = self._decode_fn(bucket)
+            pv, bv = self._weights()
+            ats = self._adapter_stacks()
+            tokens = np.zeros(bucket, np.int32)
+            positions = np.zeros(bucket, np.int32)
+            tables = np.zeros((bucket, self._nb), np.int32)  # pad -> 0
+            aids = np.zeros(bucket, np.int32)  # pad rows -> slot 0 (no-op)
+            for i, seq in enumerate(active):
+                tokens[i] = seq.last_token
+                positions[i] = seq.pos
+                tables[i] = self._padded_table(seq)
+                aids[i] = seq.adapter_slot
+            hist = self._hist_pack(active, bucket)
+            samp = self._samp_pack(active, bucket)
+            pool_ts = self.pool.tensors
         new_pool, nxt = self._run_linked_step(
             "decode.step", "decode.step_join", active, "decode",
             {"bucket": bucket},
@@ -2272,7 +2462,9 @@ class DecodeEngine:
         hook = self._fault_hook
         sctx = seq.span.ctx
 
-        def run(_member):
+        names = _DECODE
+
+        def run(_member, hctx):
             if hook is not None:
                 hook("draft_prefill", [seq.id],
                      {"bucket": pbucket, "start": start,
@@ -2283,7 +2475,9 @@ class DecodeEngine:
                     {"seq": seq.id, "bucket": pbucket,
                      "start": start, "tokens": this_len}), \
                     _locks.blocking_region("decode.step_dispatch"):
-                with _san.hot_region("decode.step_dispatch"):
+                with _san.hot_region("decode.step_dispatch"), \
+                        _otrace.span_in(names["enqueue"], hctx,
+                                        profile=True):
                     new_pool, nxt = fn(pv, bv, pool_ts, tokens,
                                        np.asarray(start, np.int32),
                                        np.asarray(this_len, np.int32),
@@ -2291,11 +2485,13 @@ class DecodeEngine:
                 # the argmax is discarded (the propose dispatch
                 # starts from last_token) — fetched only to fence
                 # the dispatch for the pool's hang detection
-                with _san.allow_host_sync("decode.token_fetch"):
+                with _san.allow_host_sync("decode.token_fetch"), \
+                        _otrace.span_in(names["fetch"], hctx,
+                                        profile=True):
                     int(np.asarray(nxt))
                 return new_pool
 
-        self.draft_pool.tensors = self._submit_step(run)
+        self.draft_pool.tensors = self._submit_step(run, names)
         seq.draft_pos = start + this_len
         with self._lock:
             self._spec_catchup_chunks += 1
@@ -2531,7 +2727,20 @@ class DecodeEngine:
         else:
             self._cancelled += 1
         # close the sequence's root span with its terminal status; a
-        # typed failure additionally pins the trace as a postmortem
+        # typed failure additionally pins the trace as a postmortem.
+        # Its duration decomposes into the three stretches below (a
+        # stretch the sequence never reached is 0)
+        if seq.span.ctx is not None:
+            now = time.perf_counter()
+            admit = seq.t_admit if seq.t_admit is not None else now
+            first = seq.t_first if seq.t_first is not None else now
+            for key, value in (("queue_wait_s", admit - seq.t_submit),
+                               ("prefill_s", first - admit),
+                               ("decode_s", now - first),
+                               ("chunks", seq.chunks),
+                               ("round_admitted", seq.round_admit),
+                               ("round_finished", self._round_no)):
+                seq.span.set_attr(key, value)
         if error is not None:
             _otrace.pin_failure(seq.span.ctx, error)
         seq.span.end(error=error if status != "completed" else None,
@@ -2622,6 +2831,12 @@ class DecodeEngine:
                 "isolation_rounds": self._isolations,
                 "occupancy": (self._step_active / self._step_slots)
                 if self._step_slots else 0.0,
+                # the two cumulative counters behind `occupancy`: a
+                # reader differences them over its own window
+                "step_active": self._step_active,
+                "step_slots": self._step_slots,
+                "rounds": self._round_no,
+                "slow_rounds": self._slow_rounds,
                 "internal_fragmentation": (1.0 - used_tokens / alloc_slots)
                 if alloc_slots else 0.0,
                 "prefix_hit_rate": (self._prefix_hits / lookups)

@@ -16,8 +16,10 @@ as a collector — single source of truth, no duplicated bookkeeping):
   (``metrics=False`` disables; ``pool.serve_metrics(port=0)`` exports);
 * `inference.ServingRouter` — per-replica health, failovers, swap
   generations (``router.serve_metrics(...)``);
-* `inference.DecodeEngine` — occupancy, fragmentation, TTFT histogram;
-* `distributed` Engine — dispatch/device_put/step counts;
+* `inference.DecodeEngine` — occupancy, fragmentation, TTFT and
+  queue-wait histograms, scheduler-phase spans (`decode.round*`);
+* `distributed` Engine — dispatch/device_put/step counts, an
+  `engine.dispatch` root span a train call;
 * `profiler` — `Profiler.summary()` publishes steps/sec;
   `profiled_span(name, histogram=...)` feeds any span into a latency
   histogram even when no native tracer is recording.
@@ -27,8 +29,10 @@ Dapper-style spans with cross-thread/process context propagation, an
 always-on bounded per-thread flight recorder, postmortem retention of
 typed-failure traces, per-bucket histogram exemplars (last trace id —
 scrape → p99 bucket → trace id → ``/traces/<id>``), and the
-``/traces`` endpoints on `MetricsServer`. ``PADDLE_TPU_TRACE=0``
-reduces every probe to a flag check.
+``/traces`` endpoints on `MetricsServer`. The engines' threads keep a
+whole window of spans (`flight.reserve` / `spans_between`) and put
+them on the profiler's clock as ``pt::<name>`` annotations.
+``PADDLE_TPU_TRACE=0`` reduces every probe to a flag check.
 
 See docs/observability.md for the full API, knobs, and the SLO ratchet
 workflow; tools/metrics_dump.py and tools/trace_dump.py scrape/dump

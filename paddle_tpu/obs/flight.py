@@ -8,11 +8,22 @@ production:
 
 * **Per-thread ring buffers** — a finished span is appended to the
   RECORDING thread's own bounded ring (`PADDLE_TPU_TRACE_RING` spans,
-  default 512): owner-thread-only writes, no lock, no allocation beyond
-  the span itself. Memory is bounded in SPANS, not bytes — sizing is
-  ``threads x ring x ~200B``. The ``obs.flight`` named lock guards only
+  default 512): owner-thread-only writes, no lock; the ring's list grows
+  by appends until it holds its capacity and overwrites in place from
+  then on. Memory is bounded in SPANS, not bytes — sizing is
+  ``threads x ring x ~0.4KB``. The ``obs.flight`` named lock guards only
   the ring REGISTRY (first record per thread) and the postmortem table
   below — never an append.
+
+* **Reserved rings** — a long-lived thread whose spans a reader wants
+  for a whole WINDOW (the decode engine's scheduler and step-pool
+  worker, a trainer's dispatch thread) calls `reserve()` once and gets
+  a ring of `ENGINE_RING_SPANS` instead of the default. A reserved ring
+  outlives its thread (the newest `RESERVED_RINGS_KEPT` of them are
+  kept until the process ends), and `spans_between(t0, t1)` reads an
+  interval of `perf_counter` time out of every ring and says whether
+  any ring overwrote a span of that interval — a reader told of a wrap
+  must not sum what it got.
 
 * **Postmortem retention** — a typed serving failure on a traced
   request *pins* its trace (`pin()`): the trace's spans are copied out
@@ -43,10 +54,19 @@ import time
 from ..analysis import locks as _locks
 
 __all__ = ["Span", "FlightRecorder", "recorder", "DEFAULT_RING_SPANS",
-           "DEFAULT_POSTMORTEM_TRACES"]
+           "DEFAULT_POSTMORTEM_TRACES", "ENGINE_RING_SPANS",
+           "RESERVED_RINGS_KEPT", "wall_of", "perf_of"]
 
 DEFAULT_RING_SPANS = 512
 DEFAULT_POSTMORTEM_TRACES = 64
+#: ordinary rings of dead threads kept for the next scrape
+RETIRED_RINGS_KEPT = 16
+#: a reserved ring (`reserve()`): a 51 s window of the serving cell is
+#: ~5 rounds/s x ~15 spans today and ten times that with a decode step ten
+#: times faster
+ENGINE_RING_SPANS = 65536
+#: reserved rings of dead threads kept for later readers (newest first out)
+RESERVED_RINGS_KEPT = 8
 
 # perf_counter -> wall-clock anchor: spans time themselves with the
 # monotonic perf counter and are STAMPED into the epoch domain when
@@ -71,6 +91,12 @@ if hasattr(os, "register_at_fork"):
 def wall_of(perf_t):
     """Epoch seconds for a perf_counter reading (this process)."""
     return _ANCHOR_WALL + (perf_t - _ANCHOR_PERF)
+
+
+def perf_of(wall_t):
+    """The perf_counter reading of a span's epoch stamp (this process):
+    `wall_of`'s inverse, for readers that work on `perf_counter` time."""
+    return _ANCHOR_PERF + (wall_t - _ANCHOR_WALL)
 
 
 class Span:
@@ -131,19 +157,21 @@ class Span:
 
 
 class _Ring:
-    """Fixed-capacity span ring owned by ONE writer thread. `slots` is
-    preallocated; the writer only ever assigns one slot and bumps `n` —
-    no lock, no resize, no allocation. `owner` weakly references the
-    writer thread so the registry can retire rings of dead threads."""
+    """Bounded span ring owned by ONE writer thread: `slots` grows by
+    appends up to `cap`, then the writer overwrites one slot a span and
+    notes when the span it dropped had ended (`dropped_t1`) — no lock.
+    `owner` weakly references the writer thread so the registry can
+    retire rings of dead threads."""
 
-    __slots__ = ("slots", "cap", "n", "thread_name", "owner")
+    __slots__ = ("slots", "cap", "n", "thread_name", "owner", "dropped_t1")
 
     def __init__(self, cap, thread_name, owner=None):
         self.cap = cap
-        self.slots = [None] * cap
+        self.slots = []
         self.n = 0
         self.thread_name = thread_name
         self.owner = owner
+        self.dropped_t1 = None      # epoch end of the newest dropped span
 
     def owner_dead(self):
         if self.owner is None:
@@ -152,17 +180,30 @@ class _Ring:
         return t is None or not t.is_alive()
 
     def append(self, span):
-        self.slots[self.n % self.cap] = span
+        if self.n < self.cap:
+            self.slots.append(span)
+        else:
+            i = self.n % self.cap
+            self.dropped_t1 = self.slots[i].t1
+            self.slots[i] = span
         self.n += 1
+
+    def grow(self, cap):
+        """Owner thread only: hold `cap` spans from now on (what is there
+        is kept, oldest first)."""
+        if cap > self.cap:
+            self.slots = self.snapshot()
+            self.n = len(self.slots)
+            self.cap = cap
 
     def snapshot(self):
         """Best-effort copy, oldest first (see module docstring)."""
         n = self.n
         items = list(self.slots)    # one pass under the GIL
         if n <= self.cap:
-            return [s for s in items[:n] if s is not None]
+            return items
         cut = n % self.cap
-        return [s for s in items[cut:] + items[:cut] if s is not None]
+        return items[cut:] + items[:cut]
 
 
 class FlightRecorder:
@@ -189,9 +230,10 @@ class FlightRecorder:
         # for a while (a retired pool worker's last spans must survive
         # to the next scrape) but are BOUNDED: short-lived request
         # threads on a long-running server must not grow memory forever
-        self._retired = collections.deque(
-            maxlen=int(os.environ.get("PADDLE_TPU_TRACE_RETIRED_RINGS",
-                                      "16")))
+        self._retired = collections.deque(maxlen=RETIRED_RINGS_KEPT)
+        # reserved rings outlive their thread: a window's reader runs
+        # after the engine that wrote them is shut down
+        self._kept = collections.deque(maxlen=RESERVED_RINGS_KEPT)
         self._foreign = []          # ingested cross-process spans
         self._pinned = {}           # trace_id -> postmortem record
         self._pin_order = collections.deque()
@@ -199,26 +241,42 @@ class FlightRecorder:
         self.dropped_wraps = 0
 
     # -- hot path ----------------------------------------------------------
-    def record(self, span):
-        """Append one finished span to the calling thread's ring. Lock
-        free except the once-per-thread ring registration; the pinned
-        lookup is one dict membership test."""
+    def _ring(self, cap=None):
+        """The calling thread's ring, registered on first use. The
+        once-per-thread registration doubles as the sweep point: dead
+        threads' rings move to the bounded retired deques (FIFO) instead
+        of accumulating."""
         ring = getattr(self._tls, "ring", None)
         if ring is None:
             import weakref
 
             t = threading.current_thread()
-            ring = _Ring(self.ring_spans, t.name, owner=weakref.ref(t))
+            ring = _Ring(max(cap or 0, self.ring_spans), t.name,
+                         owner=weakref.ref(t))
             self._tls.ring = ring
             with self._lock:
-                # once-per-thread registration doubles as the sweep
-                # point: dead threads' rings move to the bounded
-                # retired deque (FIFO) instead of accumulating
                 dead = [r for r in self._rings if r.owner_dead()]
                 for r in dead:
                     self._rings.remove(r)
-                    self._retired.append(r)
+                    (self._kept if r.cap > self.ring_spans
+                     else self._retired).append(r)
                 self._rings.append(ring)
+        elif cap:
+            ring.grow(cap)
+        return ring
+
+    def reserve(self, spans=ENGINE_RING_SPANS):
+        """Give the CALLING thread a ring of at least `spans` spans (what
+        it recorded so far is kept). For threads that live as long as
+        their engine and whose spans are read a window at a time
+        (`spans_between`); such a ring is kept after its thread died."""
+        self._ring(spans)
+
+    def record(self, span):
+        """Append one finished span to the calling thread's ring. Lock
+        free except the once-per-thread ring registration; the pinned
+        lookup is one dict membership test."""
+        ring = self._ring()
         if ring.n >= ring.cap:
             self.dropped_wraps += 1     # a slot is being overwritten
         ring.append(span)
@@ -326,7 +384,25 @@ class FlightRecorder:
     def _all_rings(self):
         with self._lock:
             return (list(self._rings) + list(self._retired)
-                    + list(self._foreign))
+                    + list(self._kept) + list(self._foreign))
+
+    def spans_between(self, t0, t1, prefix=None):
+        """``(spans, wrapped)``: every recorded span of this process
+        that overlaps the `perf_counter` interval [t0, t1) and whose
+        name starts with `prefix` (a string or a tuple of them), oldest
+        first — and whether a ring overwrote a span that ended after
+        `t0`, in which case the list is NOT the whole interval and a
+        reader must not sum it. Dead threads' rings are read too."""
+        w0, w1 = wall_of(t0), wall_of(t1)
+        out, wrapped = [], False
+        for ring in self._all_rings():
+            if ring.dropped_t1 is not None and ring.dropped_t1 > w0:
+                wrapped = True
+            out += [s for s in ring.snapshot()
+                    if s.t1 >= w0 and s.t0 < w1 and s.pid == _PID
+                    and (prefix is None or s.name.startswith(prefix))]
+        out.sort(key=lambda s: (s.t0, s.t1))
+        return out, wrapped
 
     def spans_for(self, trace_id, pinned=True):
         """Every recorded span of one trace (rings + postmortem when
@@ -419,10 +495,11 @@ class FlightRecorder:
     def stats(self):
         with self._lock:
             rings = len(self._rings) + len(self._foreign)
-            retired = len(self._retired)
+            retired = len(self._retired) + len(self._kept)
             pinned = len(self._pinned)
+        held = sum(len(r.slots) for r in self._all_rings())
         return {"recorded": self.recorded, "rings": rings,
-                "retired_rings": retired,
+                "retired_rings": retired, "spans_held": held,
                 "ring_spans": self.ring_spans, "pinned_traces": pinned,
                 "dropped_wraps": self.dropped_wraps,
                 "max_postmortems": self.max_postmortems}
@@ -432,6 +509,7 @@ class FlightRecorder:
         with self._lock:
             self._rings = []
             self._retired.clear()
+            self._kept.clear()
             self._foreign = []
             self._pinned = {}
             self._pin_order.clear()

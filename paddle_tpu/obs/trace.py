@@ -39,14 +39,24 @@ Design points:
   holding the exception can fetch the causal record
   (``/traces/<id>`` or ``tools/trace_dump.py``).
 
+* **On the profiler's clock** — a span opened with ``profile=True``
+  (the decode engine's rounds and phases, the train engine's dispatch
+  spans) is also a `jax.profiler.TraceAnnotation` named
+  ``pt::<span name>``: a profile captured from a live server shows the
+  rounds and their phases on the host plane, above the device's
+  operations. With no profiler session the annotation is a flag check
+  in C++ (~0.4 us); it is kept off events and per-token sites.
+
 The ``obs.trace`` named lock guards only the shared id generator;
 span creation otherwise touches per-thread state. See
 docs/observability.md ("Distributed tracing") for the workflow.
 """
 from __future__ import annotations
 
+import gc
 import os
 import random
+import sys
 import threading
 import time
 
@@ -56,9 +66,13 @@ from . import flight as _flight
 __all__ = [
     "TraceContext", "enabled", "enable", "disable", "sample_rate",
     "set_sample_rate", "current", "current_wire", "span", "root_span",
-    "span_in", "attach", "event", "event_in", "open_span", "null_span",
-    "note_failure", "pin_failure",
+    "span_in", "attach", "detached", "event", "event_in", "open_span",
+    "null_span", "note_failure", "pin_failure", "reserve_ring",
+    "watch_gc", "PROFILE_PREFIX",
 ]
+
+#: a `profile=True` span is the profiler annotation `pt::<span name>`
+PROFILE_PREFIX = "pt::"
 
 
 def _env_flag(name, default="1"):
@@ -176,6 +190,24 @@ def current_wire():
     return None if ctx is None else ctx.to_wire()
 
 
+_annotation_cls = None
+
+
+def _annotate(name):
+    """Enter `pt::<name>` on the profiler's timeline (None while jax is
+    not loaded: no profiler session can be running then)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    ann = _annotation_cls(PROFILE_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
 class _NullSpan:
     """Shared no-op for every untraced probe: ``with span(...)`` costs a
     flag check and two trivial method calls."""
@@ -186,6 +218,7 @@ class _NullSpan:
     trace_id_hex = None
     span_id_hex = None
     recorded = False
+    duration = None
 
     def __enter__(self):
         return self
@@ -215,18 +248,23 @@ class _OpenSpan:
     the ``with`` body stamp the span's status with the error type."""
 
     __slots__ = ("name", "ctx", "parent_id", "attrs", "_t0", "_thread",
-                 "_pushed", "_extra_pop", "recorded")
+                 "_pushed", "_extra_pop", "recorded", "duration", "_ann")
 
-    def __init__(self, name, ctx, parent_id, attrs, extra_pop=False):
+    def __init__(self, name, ctx, parent_id, attrs, extra_pop=False,
+                 profile=False, t0=None):
         self.name = name
         self.ctx = ctx
         self.parent_id = parent_id
         self.attrs = dict(attrs) if attrs else None
-        self._t0 = time.perf_counter()
+        # `t0` backdates the span to a perf_counter reading its opener
+        # took before it knew a span was due (the scheduler's loop top)
+        self._t0 = time.perf_counter() if t0 is None else t0
         self._thread = threading.current_thread().name
         self._pushed = True
         self._extra_pop = extra_pop  # attach-style: a foreign parent ctx
         self.recorded = False        # was pushed under this span
+        self.duration = None         # seconds, once ended
+        self._ann = _annotate(name) if profile else None
 
     # -- identity ----------------------------------------------------------
     @property
@@ -270,9 +308,12 @@ class _OpenSpan:
         if self.recorded:
             return
         self.recorded = True
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        t1 = time.perf_counter()
+        self.duration = t1 - self._t0
         if not self.ctx.sampled:
             return
-        t1 = time.perf_counter()
         if status is None:
             status = "ok" if error is None else (
                 type(error).__name__ if isinstance(error, BaseException)
@@ -287,10 +328,10 @@ class _OpenSpan:
             thread=self._thread))
 
 
-def span(name, attrs=None):
+def span(name, attrs=None, profile=False, t0=None):
     """Child span of the CURRENT context; the shared no-op when tracing
     is off or no trace is active (instrumentation call sites stay free
-    outside a traced request)."""
+    outside a traced request). `profile` / `t0`: see `_OpenSpan`."""
     if not _enabled:
         return _NULL
     parent = current()
@@ -298,10 +339,11 @@ def span(name, attrs=None):
         return _NULL
     ctx = TraceContext(parent.trace_id, _new_id(), parent.sampled)
     _stack().append(ctx)
-    return _OpenSpan(name, ctx, parent.span_id, attrs)
+    return _OpenSpan(name, ctx, parent.span_id, attrs, profile=profile,
+                     t0=t0)
 
 
-def root_span(name, attrs=None, sampled=None):
+def root_span(name, attrs=None, sampled=None, profile=False, t0=None):
     """Mint a trace (new trace id, deterministic sampling decision) —
     or a child span when a context is already active, so a traced
     caller's hop nests instead of forking a second trace.
@@ -323,7 +365,7 @@ def root_span(name, attrs=None, sampled=None):
                            else bool(sampled))
         pid = None
     _stack().append(ctx)
-    return _OpenSpan(name, ctx, pid, attrs)
+    return _OpenSpan(name, ctx, pid, attrs, profile=profile, t0=t0)
 
 
 def open_span(name, attrs=None, parent=None):
@@ -347,7 +389,7 @@ def open_span(name, attrs=None, parent=None):
     return sp
 
 
-def span_in(name, ctx, attrs=None):
+def span_in(name, ctx, attrs=None, profile=False):
     """Child span under an EXPLICIT context (cross-thread handoff): the
     executing thread both attaches `ctx` and opens the child in one
     push, popping both at exit."""
@@ -357,7 +399,8 @@ def span_in(name, ctx, attrs=None):
     s.append(ctx)
     child = TraceContext(ctx.trace_id, _new_id(), ctx.sampled)
     s.append(child)
-    return _OpenSpan(name, child, ctx.span_id, attrs, extra_pop=True)
+    return _OpenSpan(name, child, ctx.span_id, attrs, extra_pop=True,
+                     profile=profile)
 
 
 class _Attach:
@@ -385,6 +428,17 @@ def attach(ctx):
     return _Attach(ctx)
 
 
+_DETACHED = _Attach(None)
+
+
+def detached():
+    """Run a block with NO current context on this thread: a callee that
+    captures `current()` for a hand-off (the serving pool's admission)
+    gets None, so an internal executor's own spans stay out of the
+    caller's trace."""
+    return _DETACHED if _enabled else _NULL
+
+
 def event(name, attrs=None):
     """Zero-duration child span of the current context ("something
     happened here"): admission stamps, first-token marks, batch links."""
@@ -398,6 +452,45 @@ def event_in(name, ctx, attrs=None):
     sp = span_in(name, ctx, attrs)
     sp.end()
     return sp
+
+
+# ---------------------------------------------------------------------------
+# long-lived threads and the collector
+# ---------------------------------------------------------------------------
+
+def reserve_ring():
+    """Called by a long-lived engine thread (a scheduler, a step-pool
+    worker, a trainer's dispatch thread): its spans go to a ring that
+    holds a whole window and outlives the thread (`flight.reserve`)."""
+    if _enabled:
+        _flight.recorder().reserve()
+
+
+_gc_t0 = None
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` hook: a generation-2 collection is one `host.gc`
+    span — a child of whatever span the collecting thread is in, else a
+    root — so host self-time with no child has its next suspect on
+    record. Younger generations are not recorded."""
+    global _gc_t0
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is not None:
+        root_span("host.gc", attrs={"collected": info.get("collected", 0)},
+                  t0=t0).end()
+
+
+def watch_gc():
+    """Record generation-2 collections as `host.gc` spans from now on
+    (idempotent; the decode engine turns it on with its scheduler)."""
+    if _enabled and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 # ---------------------------------------------------------------------------

@@ -45,31 +45,29 @@ def host_recording():
     return _cpu_recording
 
 
-def profiled_span(name, histogram=None, attrs=None):
-    """RecordEvent span when a host profiler is actively recording, else
-    a zero-cost no-op context. The shared gate for hot-path
-    instrumentation (the distributed engine's dispatch spans, the serving
-    batcher's form/pad/dispatch/scatter spans): outside a record window
-    the native tracer is never touched, so unprofiled runs pay nothing
-    — not even the tracer's first-use build.
+def profiled_span(name, histogram=None):
+    """The shared gate for hot-path instrumentation (the distributed
+    engine's `engine::device_put` / `engine::dispatch` /
+    `engine::write_back`, the serving batcher's form/pad/dispatch/
+    scatter stages). What one call site records, and where:
 
-    `histogram=` (a `paddle_tpu.obs` Histogram) additionally times the
-    span with `time.perf_counter` and observes the duration on EVERY
-    pass, whether or not a tracer is recording — one span site feeds
-    both the chrome trace (profiling sessions) and the always-on latency
-    histogram (production telemetry).
+    * inside an active trace context (`obs.trace`; the train engine
+      opens an `engine.dispatch` root a `train_batch[es]` call, the
+      serving pool one a batch) — a child trace span in the flight
+      recorder, which is also the profiler annotation `pt::<name>`;
+    * with `histogram=` (a `paddle_tpu.obs` Histogram) — the duration,
+      observed on EVERY pass;
+    * while a `Profiler` with the CPU target records — a native
+      `RecordEvent` for the `paddle.profiler` chrome trace.
 
-    **Tracing** (obs.trace): when the calling thread is inside an
-    active trace context, the same call site ALSO opens a child trace
-    span recorded into the flight recorder — the per-thread context
-    stack gives every profiled_span a parent link, so nested and
-    concurrent spans export properly nested instead of interleaving
-    flat. One instrumentation point, three consumers (native chrome
-    trace, latency histogram, distributed trace); with
-    ``PADDLE_TPU_TRACE=0`` the tracing path is one flag check."""
+    The interval is timed once: the histogram takes the trace span's
+    own duration where there is one. Outside all three the call returns
+    a no-op context and never touches the native tracer (not even its
+    first-use build); with ``PADDLE_TPU_TRACE=0`` the tracing path is
+    one flag check."""
     traced = _obs_trace.enabled() and _obs_trace.current() is not None
     if histogram is not None or traced:
-        return _TimedSpan(name, histogram, traced, attrs)
+        return _TimedSpan(name, histogram, traced)
     if _cpu_recording:
         return RecordEvent(name)
     from contextlib import nullcontext
@@ -78,17 +76,15 @@ def profiled_span(name, histogram=None, attrs=None):
 
 
 class _TimedSpan:
-    """profiled_span(..., histogram=... / under a trace): always-on
-    timing feeding an obs histogram and/or a child trace span, plus the
+    """`profiled_span` with a histogram and/or under a trace: one timed
+    interval feeding the child trace span and the histogram, plus the
     native RecordEvent while a profiler records."""
 
-    __slots__ = ("name", "histogram", "attrs", "_traced", "_ev", "_t0",
-                 "_tspan")
+    __slots__ = ("name", "histogram", "_traced", "_ev", "_t0", "_tspan")
 
-    def __init__(self, name, histogram, traced=False, attrs=None):
+    def __init__(self, name, histogram, traced=False):
         self.name = name
         self.histogram = histogram
-        self.attrs = attrs
         self._traced = traced
         self._tspan = None
 
@@ -97,17 +93,20 @@ class _TimedSpan:
         if self._ev is not None:
             self._ev.begin()
         if self._traced:
-            self._tspan = _obs_trace.span(self.name, attrs=self.attrs)
-        self._t0 = time.perf_counter()
+            self._tspan = _obs_trace.span(self.name, profile=True)
+        else:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self.histogram is not None:
-            # observed with the trace span still current, so the bucket
-            # exemplar carries this request's trace id
-            self.histogram.observe(time.perf_counter() - self._t0)
         if self._tspan is not None:
             self._tspan.end(error=exc)
+            took, ctx = self._tspan.duration, self._tspan.ctx
+        else:
+            took, ctx = time.perf_counter() - self._t0, None
+        if self.histogram is not None:
+            # the bucket exemplar carries the span's own trace id
+            self.histogram.observe(took, ctx=ctx)
         if self._ev is not None:
             self._ev.end()
         return False

@@ -363,49 +363,79 @@ def test_metrics_false_strips_instrumentation():
         pool.shutdown(drain_timeout=5.0)
 
 
-def test_overhead_guard_instrumented_vs_disabled():
-    """The always-on hot path must be in the noise of the pool
-    machinery itself. Two guards:
+def test_overhead_guard_instrumented_vs_disabled(monkeypatch):
+    """The always-on hot path must be in the noise of the machinery
+    itself. Guarded by COUNTS, not by the wall clock (a timing ratio
+    fails under a loaded suite and passes alone):
 
-    1. the observe path is a bisect + unlocked int adds — measured
-       directly, it must stay in the low-microsecond range (a lock,
-       snapshot, or allocation slipping onto it blows past the bound);
-    2. instrumented pool throughput on a stub predictor within 2.5x of
-       a registry-disabled pool, min-of-5 with the two modes
-       INTERLEAVED so 2-core CI scheduling drift hits both equally
-       (in practice the ratio is ~1.0)."""
-    h = Histogram("ovh.direct")
-    m = 20_000
-    t0 = time.perf_counter()
-    for _ in range(m):
-        h.observe(0.01)
-    per_observe = (time.perf_counter() - t0) / m
-    assert per_observe < 5e-6, f"{per_observe * 1e6:.2f} us/observe"
+    1. metrics: an instrumented pool makes exactly one observation per
+       histogram per request; a registry-disabled pool has no histogram
+       to observe at all;
+    2. tracing off: every probe is the shared no-op and the recorder's
+       `record` is never called, through a whole decode engine run;
+    3. tracing on: the same run makes a fixed number of spans a request,
+       a prefill, a round and a step — nothing per token beyond the one
+       `decode.step_join` a member a step."""
+    import collections
 
-    n = 300
+    from paddle_tpu.inference.decode import demo
+    from paddle_tpu.obs import flight, trace
 
-    def drive(pool):
-        t0 = time.perf_counter()
-        reqs = [pool.submit(lambda p: 0, timeout=30.0) for _ in range(n)]
-        for r in reqs:
-            r.result(timeout=30.0)
-        return time.perf_counter() - t0
-
-    pools = {"on": make_pool(MetricsRegistry(), name="ovh-on",
-                             max_queue_depth=n + 8),
-             "off": make_pool(None, metrics=False, name="ovh-off",
-                              max_queue_depth=n + 8)}
-    best = {"on": float("inf"), "off": float("inf")}
+    n = 40
+    on = make_pool(MetricsRegistry(), name="ovh-on", max_queue_depth=n + 8)
+    off = make_pool(None, metrics=False, name="ovh-off",
+                    max_queue_depth=n + 8)
     try:
-        for pool in pools.values():
-            drive(pool)  # warm the workers
-        for _ in range(5):
-            for mode, pool in pools.items():
-                best[mode] = min(best[mode], drive(pool))
+        for pool in (on, off):
+            for r in [pool.submit(lambda p: 0, timeout=30.0)
+                      for _ in range(n)]:
+                r.result(timeout=30.0)
+        assert [h.count for h in (on._h_queue_wait, on._h_execute,
+                                  on._h_latency)] == [n, n, n]
+        assert off._h_queue_wait is off._h_execute is off._h_latency is None
     finally:
-        for pool in pools.values():
-            pool.shutdown(drain_timeout=10.0)
-    assert best["on"] <= best["off"] * 2.5, best
+        on.shutdown(drain_timeout=10.0)
+        off.shutdown(drain_timeout=10.0)
+
+    calls = []
+    real = flight.FlightRecorder.record
+    monkeypatch.setattr(flight.FlightRecorder, "record",
+                        lambda self, span: (calls.append(span.name),
+                                            real(self, span))[1])
+
+    def run():
+        eng = demo.tiny_engine(1)
+        try:
+            assert len(eng.generate(demo.demo_prompt(5, 8), 4)) == 4
+            return eng.stats()
+        finally:
+            eng.shutdown()
+
+    was = trace.enabled()
+    try:
+        trace.disable()
+        assert trace.span("x") is trace.root_span("y") is trace.null_span()
+        assert trace.detached() is trace.null_span()
+        run()
+        assert calls == []
+        trace.enable()
+        st = run()
+    finally:
+        (trace.enable if was else trace.disable)()
+    got = collections.Counter(calls)
+    rounds, steps, chunks = st["rounds"], st["steps"], st["prefill_chunks"]
+    assert (rounds, steps, chunks) == (3, 3, 1)
+    phase = ("", ".pack", ".handoff", ".enqueue", ".fetch", ".deliver")
+    want = {"decode.sequence": 1, "decode.first_token": 1,
+            "decode.round": rounds, "decode.round.admit": rounds,
+            "decode.prefill": chunks, "decode.step": steps,
+            "decode.step_join": steps, "decode.round.decode.grow": steps}
+    want.update({"decode.round.prefill" + p: chunks for p in phase})
+    want.update({"decode.round.decode" + p: steps for p in phase})
+    idle = got.pop("decode.idle_wait", 0)
+    got.pop("host.gc", None)             # the collector's own schedule
+    assert dict(got) == want
+    assert idle <= 2                     # before the request, after it
 
 
 # ---------------------------------------------------------------------------
