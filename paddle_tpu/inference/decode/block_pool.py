@@ -1,18 +1,22 @@
 """paddle_tpu.inference.decode.block_pool — paged KV-cache allocator.
 
 The dense KV cache (`GPTForCausalLM.init_cache`) allocates one
-``[B, max_len, Hkv, D]`` buffer per layer per *batch slot*: every sequence
+``[B, max_len, Hkv*D]`` buffer per layer per *batch slot*: every sequence
 pays for its worst-case length up front, and a serving batch of mixed
 lengths wastes most of that memory. The paged layout (vLLM/PagedAttention,
 SOSP '23) instead keeps ONE device-resident pool of fixed-size blocks per
 layer —
 
-    k_pool: [num_blocks, block_size, Hkv, D]      (bf16 cache)
+    k_pool: [num_blocks, block_size, Hkv*D]       (bf16 cache)
     kq/ks/vq/vs pools for the int8 layout           (int8 values +
                                                     [num_blocks, block_size,
                                                     Hkv] f32 scales)
 
-— and gives each sequence a *block table*: the ordered list of pool block
+(a cache row is stored flat, heads x head_dim side by side: the minor
+dimension is then a multiple of the chip's 128 lanes and the device keeps
+the pool row-major as it is, where ``[.., Hkv, 64]`` made every step
+relayout the whole pool twice — PERF.md section 5) — and gives each
+sequence a *block table*: the ordered list of pool block
 ids that hold its tokens (token position ``p`` lives at
 ``(table[p // block_size], p % block_size)``). Sequences allocate blocks
 as they grow and return them the moment they finish, so the pool's
@@ -76,13 +80,16 @@ class BlockKVCache:
     Args:
         num_blocks: total pool blocks (>= RESERVED_BLOCKS + 1).
         block_size: tokens per block.
-        entry_specs: per-layer tuple of ``(suffix_shape, dtype)`` pairs —
-            one pair per cache tensor in the layer's cache-entry order
-            (``(k, v)`` for bf16, ``(kq, ks, vq, vs)`` for int8). Each
-            pool tensor is allocated as ``[num_blocks, block_size,
-            *suffix_shape]`` of the given dtype. Models build this via
-            ``init_block_pool`` so the geometry always matches their
-            ``decode_step`` cache layout.
+        entry_specs: per-layer tuple of ``(suffix_shape, dtype,
+            kv_heads)`` triples — one per cache tensor in the layer's
+            cache-entry order (``(k, v)`` for bf16, ``(kq, ks, vq, vs)``
+            for int8). Each pool tensor is allocated as ``[num_blocks,
+            block_size, *suffix_shape]`` of the given dtype (the model's
+            K/V suffix is one flat row, ``(Hkv*D,)``); ``kv_heads`` is
+            how many heads lie side by side along suffix dimension 0,
+            which is all `shard_` needs to know of them. Models build
+            this via ``init_block_pool`` so the geometry always matches
+            their ``decode_step`` cache layout.
         quant: informational layout tag (None or "int8") carried for
             engine fingerprinting and stats.
         name: informational pool tag carried in stats()/repr — the
@@ -110,8 +117,10 @@ class BlockKVCache:
         self.tensors = [
             tuple(jnp.zeros((self.num_blocks, self.block_size, *suffix),
                             dtype)
-                  for suffix, dtype in layer)
+                  for suffix, dtype, _ in layer)
             for layer in entry_specs]
+        self._kv_heads = [tuple(h for _, _, h in layer)
+                          for layer in entry_specs]
         self.mesh = None        # set by shard_() for tensor-parallel pools
         self.shardings = None
         self._lock = _locks.new_lock("decode.block_pool")
@@ -127,11 +136,13 @@ class BlockKVCache:
 
     # -- tensor-parallel placement (paddle_tpu.sharding) -------------------
     def shard_(self, mesh, rules=None):
-        """Shard every pool tensor along the KV-head dimension (logical
-        axis "kv", suffix dim 0 — pool layout [N, bs, Hkv, ...]) over
-        `mesh` via the axis-rule table. Head counts an axis does not
-        divide replicate instead of erroring. Returns the per-tensor
-        NamedShardings (per layer, matching `tensors` structure)."""
+        """Shard every pool tensor along the KV heads (logical axis
+        "kv", suffix dim 0 — pool layout [N, bs, Hkv*D] or [N, bs, Hkv])
+        over `mesh` via the axis-rule table: contiguous groups of whole
+        heads, so what divides is the entry's head count, not the flat
+        row's width. Head counts an axis does not divide replicate
+        instead of erroring. Returns the per-tensor NamedShardings (per
+        layer, matching `tensors` structure)."""
         import jax
         from ... import sharding as _shardlib
 
@@ -139,9 +150,9 @@ class BlockKVCache:
         self.shardings = [
             tuple(_shardlib.logical_to_sharding(
                 (None, None, "kv") + (None,) * (t.ndim - 3),
-                mesh, rules=rules, shape=tuple(t.shape))
-                for t in layer)
-            for layer in self.tensors]
+                mesh, rules=rules, shape=(*t.shape[:2], heads))
+                for t, heads in zip(layer, layer_heads))
+            for layer, layer_heads in zip(self.tensors, self._kv_heads)]
         self.tensors = [
             tuple(jax.device_put(t, sh) for t, sh in zip(layer, shs))
             for layer, shs in zip(self.tensors, self.shardings)]

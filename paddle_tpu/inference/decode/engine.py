@@ -14,7 +14,10 @@ that already exist in-tree:
   pool of fixed-size blocks per layer; each sequence holds a block table
   and grows block-by-block, returning blocks the moment it finishes.
   Supports the bf16 and int8 (`cache_quant="int8"`) layouts of
-  `GPTForCausalLM.init_cache` via `init_block_pool`.
+  `GPTForCausalLM.init_cache` via `init_block_pool`. A pool tensor is
+  `[N, block_size, Hkv*D]` — flat rows, which the chip stores as they
+  are; the engine gathers, scatters and copies whole rows and knows
+  nothing of heads (the model views a row as `[Hkv, D]`).
 
 * **Prefill/decode separation with chunked prefill** (Sarathi-Serve,
   OSDI '24): a new sequence's prompt is prefilled in block-aligned
@@ -534,7 +537,7 @@ class DecodeEngine:
 
         # tensor-parallel placement (paddle_tpu.sharding): weights shard
         # per their logical-axis annotations / the name-pattern rules,
-        # paged KV blocks shard along the kv-head dim, and every step
+        # paged KV blocks shard along their rows' kv heads, and every step
         # executable compiles partitioned over the mesh (docs/sharding.md)
         self.mesh = mesh
         self._sharding_rules = sharding_rules
@@ -704,7 +707,7 @@ class DecodeEngine:
         for n in sorted(self._buffers):
             b = self._buffers[n]
             h.update(f"{n}:{tuple(b.shape)}:{b.dtype}".encode())
-        h.update(f"paged-scan-mt-v3:{self.pool.quant}:"
+        h.update(f"paged-scan-mt-v4:{self.pool.quant}:"
                  f"{self.block_size}:{self._nb}:{self._prefill_tail}:"
                  f"{self.max_length}".encode())
         if self._adapters is not None:
@@ -732,7 +735,7 @@ class DecodeEngine:
         for n in sorted(self._d_buffers):
             b = self._d_buffers[n]
             h.update(f"{n}:{tuple(b.shape)}:{b.dtype}".encode())
-        h.update(f"spec-draft-v2:{self.draft_pool.quant}:"
+        h.update(f"spec-draft-v3:{self.draft_pool.quant}:"
                  f"{self.block_size}:{self._nb}:{self._prefill_tail}"
                  .encode())
         if self.mesh is not None:

@@ -204,16 +204,29 @@ def _cached_attn_core(q, kk, vv, pos, num_heads, k_scale=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
 
 
-def _cached_attn_impl(q, k_new, v_new, k_cache, v_cache, pos, *, num_heads):
-    """q [B,s,H,D]; k/v_new [B,s,Hkv,D]; caches [B,T,Hkv,D]; pos scalar
-    global offset of this chunk. Returns (out, new_k_cache, new_v_cache)."""
+def _write_rows(cache, new, pos):
+    """Write this chunk's [B,s,Hkv,D] values at `pos` into a cache of flat
+    rows [B,T,Hkv*D] (the stored layout, see `init_cache`)."""
     import jax
 
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k_new.astype(k_cache.dtype), pos, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v_new.astype(v_cache.dtype), pos, axis=1)
-    out = _cached_attn_core(q, k_cache, v_cache, pos, num_heads)
+    rows = new.reshape(new.shape[:2] + (-1,)).astype(cache.dtype)
+    return jax.lax.dynamic_update_slice_in_dim(cache, rows, pos, axis=1)
+
+
+def _heads(cache, like):
+    """View flat cache rows [B,T,Hkv*D] as [B,T,Hkv,D], the head geometry
+    taken from this chunk's own K/V `like` [B,s,Hkv,D]."""
+    return cache.reshape(cache.shape[:2] + like.shape[2:])
+
+
+def _cached_attn_impl(q, k_new, v_new, k_cache, v_cache, pos, *, num_heads):
+    """q [B,s,H,D]; k/v_new [B,s,Hkv,D]; caches [B,T,Hkv*D] flat rows; pos
+    scalar global offset of this chunk. Returns (out, new_k_cache,
+    new_v_cache), the caches flat as they came."""
+    k_cache = _write_rows(k_cache, k_new, pos)
+    v_cache = _write_rows(v_cache, v_new, pos)
+    out = _cached_attn_core(q, _heads(k_cache, k_new), _heads(v_cache, v_new),
+                            pos, num_heads)
     return out, k_cache, v_cache
 
 
@@ -230,25 +243,22 @@ def _quant_kv(x):
 def _cached_attn_int8_impl(q, k_new, v_new, kq_c, ks_c, vq_c, vs_c, pos, *,
                            num_heads):
     """int8 KV cache decode: caches store int8 values + f32 per-position
-    scales ([B,T,Hkv,D] int8 + [B,T,Hkv] f32 — half the decode-loop HBM
-    read of a bf16 cache). New K/V are quantized at write; the dequant
-    multiply fuses into the attention matmul's operand read."""
-    import jax
-
+    scales ([B,T,Hkv*D] int8 flat rows + [B,T,Hkv] f32 — half the
+    decode-loop HBM read of a bf16 cache). New K/V are quantized at write;
+    the dequant multiply fuses into the attention matmul's operand read."""
     knq, kns = _quant_kv(k_new)
     vnq, vns = _quant_kv(v_new)
-    kq_c = jax.lax.dynamic_update_slice_in_dim(kq_c, knq, pos, axis=1)
-    ks_c = jax.lax.dynamic_update_slice_in_dim(
-        ks_c, kns.astype(ks_c.dtype), pos, axis=1)
-    vq_c = jax.lax.dynamic_update_slice_in_dim(vq_c, vnq, pos, axis=1)
-    vs_c = jax.lax.dynamic_update_slice_in_dim(
-        vs_c, vns.astype(vs_c.dtype), pos, axis=1)
+    kq_c = _write_rows(kq_c, knq, pos)
+    ks_c = _write_rows(ks_c, kns, pos)
+    vq_c = _write_rows(vq_c, vnq, pos)
+    vs_c = _write_rows(vs_c, vns, pos)
 
     # Scales fold into SCORE space ([B,H,q,T] — tiny at decode q=1) rather
     # than dequantizing the cache: a broadcast-multiply dequant would
     # materialize a full bf16 cache copy every step (measured SLOWER than
     # a bf16 cache, docs/decode_perf.md round-4 addendum).
-    out = _cached_attn_core(q, kq_c.astype(q.dtype), vq_c.astype(q.dtype),
+    out = _cached_attn_core(q, _heads(kq_c, k_new).astype(q.dtype),
+                            _heads(vq_c, v_new).astype(q.dtype),
                             pos, num_heads, k_scale=ks_c, v_scale=vs_c)
     return out, kq_c, ks_c, vq_c, vs_c
 
@@ -337,8 +347,8 @@ class GPTModel(nn.Layer):
 
     def forward_step(self, input_ids, caches, pos):
         """Cached decode: input_ids [B, s] at global positions
-        [pos, pos+s); caches = [(k, v)] per layer, [B, T, Hkv, D].
-        Returns (hidden, new_caches)."""
+        [pos, pos+s); caches = [(k, v)] per layer, flat rows
+        [B, T, Hkv*D] (`init_cache`). Returns (hidden, new_caches)."""
         b, s = input_ids.shape
         position_ids = ops.unsqueeze(
             ops.arange(s, dtype="int32"), 0) + pos
@@ -402,9 +412,14 @@ class GPTForCausalLM(nn.Layer):
             f"'bf16'/None for the unquantized layout)")
 
     def init_cache(self, batch_size, max_length, dtype=None, quant=None):
-        """Zeroed per-layer KV caches [B, T, Hkv, D] for cached decode.
-        Cache dtype follows the parameters (bf16 params -> bf16 cache:
-        the KV read is the decode bandwidth bill).
+        """Zeroed per-layer KV caches [B, T, Hkv*D] for cached decode:
+        one flat row a position, heads x head_dim side by side, so the
+        minor dimension is a multiple of the chip's 128 lanes at any
+        head size and the array keeps its plain row-major layout on the
+        device (a [.., Hkv, 64] minor pair does not: PERF.md section 5).
+        The cached attention views a row as [Hkv, D] where it needs
+        heads. Cache dtype follows the parameters (bf16 params -> bf16
+        cache: the KV read is the decode bandwidth bill).
 
         quant="int8" stores int8 values plus f32 per-position scales —
         half the per-token cache read (docs/decode_perf.md names the KV
@@ -419,11 +434,12 @@ class GPTForCausalLM(nn.Layer):
         quant = self._resolve_cache_quant(quant)
         if dtype is None:
             dtype = self.transformer.wte.weight.dtype
-        shape = (batch_size, int(max_length), cfg.num_kv_heads, cfg.head_dim)
+        shape = (batch_size, int(max_length),
+                 cfg.num_kv_heads * cfg.head_dim)
         from ..core.tensor import Tensor
 
         if quant == "int8":
-            sshape = shape[:-1]
+            sshape = shape[:2] + (cfg.num_kv_heads,)
             return [(Tensor(jnp.zeros(shape, jnp.int8)),
                      Tensor(jnp.zeros(sshape, jnp.float32)),
                      Tensor(jnp.zeros(shape, jnp.int8)),
@@ -438,9 +454,16 @@ class GPTForCausalLM(nn.Layer):
         """Paged twin of `init_cache`: a `BlockKVCache` whose per-layer
         pool tensors use exactly this model's cache-entry order and
         dtypes — `(k, v)` blocks of the parameter dtype, or int8
-        `(kq, ks, vq, vs)` quads ([N, bs, Hkv, D] int8 values +
-        [N, bs, Hkv] f32 scales). Quant precedence and error semantics
-        are shared with `init_cache` (`_resolve_cache_quant`). The
+        `(kq, ks, vq, vs)` quads ([N, bs, Hkv*D] int8 values +
+        [N, bs, Hkv] f32 scales). A block holds the same flat rows as
+        `init_cache`, so a pool tensor is [N, bs, Hkv*D] and the chip
+        stores it as it is: with [N, bs, Hkv, D] at D = 64 the runtime
+        puts N on the lanes, and every step and every prompt chunk
+        relayouts the whole pool on its way in and again on its way out
+        (PERF.md section 5). Each entry spec also names its head count,
+        which is what `BlockKVCache.shard_` splits over. Quant precedence
+        and error semantics are shared with `init_cache`
+        (`_resolve_cache_quant`). The
         continuous-batching `DecodeEngine` calls this so cache geometry
         is owned by the model, not the scheduler; with speculative
         decoding on, the engine calls it on BOTH the target and the
@@ -452,12 +475,13 @@ class GPTForCausalLM(nn.Layer):
         quant = self._resolve_cache_quant(quant)
         if dtype is None:
             dtype = self.transformer.wte.weight.dtype
-        suffix = (cfg.num_kv_heads, cfg.head_dim)
+        hkv = cfg.num_kv_heads
+        rows = (hkv * cfg.head_dim,)
         if quant == "int8":
-            layer = ((suffix, jnp.int8), ((cfg.num_kv_heads,), jnp.float32),
-                     (suffix, jnp.int8), ((cfg.num_kv_heads,), jnp.float32))
+            layer = ((rows, jnp.int8, hkv), ((hkv,), jnp.float32, hkv),
+                     (rows, jnp.int8, hkv), ((hkv,), jnp.float32, hkv))
         else:
-            layer = ((suffix, dtype), (suffix, dtype))
+            layer = ((rows, dtype, hkv), (rows, dtype, hkv))
         return BlockKVCache(num_blocks, block_size,
                             [layer] * cfg.num_layers, quant=quant,
                             name=name)

@@ -107,7 +107,7 @@ def _ref_tokens(model, prompt, max_new):
 def _tiny_pool(num_blocks=6, block_size=4):
     import jax.numpy as jnp
 
-    spec = (((2, 4), jnp.float32), ((2, 4), jnp.float32))
+    spec = (((8,), jnp.float32, 2), ((8,), jnp.float32, 2))
     return BlockKVCache(num_blocks, block_size, [spec])
 
 
@@ -150,7 +150,7 @@ def test_block_pool_geometry():
     assert pool.blocks_for(5) == 2
     assert pool.capacity_tokens == (6 - RESERVED_BLOCKS) * 4
     assert len(pool.tensors) == 1 and len(pool.tensors[0]) == 2
-    assert pool.tensors[0][0].shape == (6, 4, 2, 4)
+    assert pool.tensors[0][0].shape == (6, 4, 8)   # [N, bs, Hkv*D]
 
 
 # ---------------------------------------------------------------------------

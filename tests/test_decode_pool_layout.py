@@ -1,0 +1,240 @@
+"""The paged KV pool's layout on the device (ISSUE 27).
+
+A pool tensor is `[N, block_size, Hkv*D]`: flat rows whose minor dimension
+is a multiple of the chip's 128 lanes, so the runtime keeps the array
+row-major as it is. Stored as `[N, bs, Hkv, 64]` the runtime put N on the
+lanes, and every step executable converted the whole pool to a gatherable
+layout on its way in and back on its way out (PERF.md section 5).
+
+Two proofs: the compiled TPU programs hold no relayout of the pool
+(compile-only, against a described `v5e:2x2`, nothing runs), and on the CPU
+the engine's tokens and the rows it wrote are the parent's bit for bit.
+"""
+import hashlib
+import os
+import re
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.inference import DecodeEngine
+from paddle_tpu.models import gpt
+
+# ---------------------------------------------------------------------------
+# compile-only: what the chip's compiler makes of the pool
+# ---------------------------------------------------------------------------
+
+WIDE = dict(vocab_size=512, hidden_size=256, num_heads=4, num_layers=2,
+            max_position_embeddings=1024)          # 4 heads x 64: Hkv*D = 256
+DECODE_BUCKETS, PREFILL_BUCKET = (1, 4), 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_jax_cache():
+    """A compile for a described chip is written to jax's persistent cache
+    but cannot be read back without the chip: keep it out of there."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _computations(hlo):
+    """{name: [instruction lines]} of an optimized HLO module's text, and
+    the entry computation's name."""
+    comps, entry, cur = {}, None, None
+    for line in hlo.splitlines():
+        m = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            if m.group(1):
+                entry = cur
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _layout_of(type_text):
+    """The layout of `dtype[dims]{layout}` less its memory space."""
+    return re.sub(r"S\(\d+\)", "", type_text[type_text.index("{"):])
+
+
+HLO_DTYPE = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
+
+
+def _pool_reading(compiled, tensors):
+    """How the compiled program treats the pool tensors `tensors` (jax
+    arrays, told apart by dtype and shape): the layouts they appear in,
+    the `copy` / `transpose` / `copy-start` instructions that make one in
+    the entry computation and in any other (the `while` body and what it
+    calls), and the program's temporaries."""
+    hlo = compiled.as_text()
+    comps, entry = _computations(hlo)
+    reading = {}
+    for key in {(HLO_DTYPE[t.dtype.name], tuple(t.shape)) for t in tensors}:
+        ty = re.escape(f"{key[0]}[{','.join(map(str, key[1]))}]")
+        typed = re.compile(ty + r"\{[^}]*\}")
+        made = re.compile(r"= (?:" + typed.pattern + r" (?:copy|transpose)\("
+                          r"|\(" + typed.pattern + r", .* copy-start\()")
+        copies = {name: sum(1 for line in lines if made.search(line))
+                  for name, lines in comps.items()}
+        reading[key] = {
+            "layouts": sorted({_layout_of(m.group(0))
+                               for m in typed.finditer(hlo)}),
+            "entry_copies": copies.pop(entry, 0),
+            "inner_copies": sum(copies.values())}
+    return reading, compiled.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["bf16", "int8"])
+def test_step_programs_hold_no_relayout_of_the_pool(
+        quant, one_chip, no_jax_cache, monkeypatch, record_property):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.jit import aot
+
+    built = {}
+
+    def compile_for_chip(fn, avals, *, tag, donate_argnums=None, **_):
+        avals = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), avals)
+        kw = {} if donate_argnums is None else {
+            "donate_argnums": donate_argnums}
+        built[tag] = jax.jit(fn, **kw).lower(*avals).compile()
+        return built[tag], "compiled"
+
+    monkeypatch.setattr(aot, "compile_jit", compile_for_chip)
+    paddle.seed(0)
+    net = gpt("gpt_tiny", **WIDE)
+    net.eval()
+    for _, p in net.named_parameters():     # the served dtype: bf16 tiles
+        p._value = p._value.astype(jnp.bfloat16)
+    eng = DecodeEngine(net, max_length=1024, block_size=16, quant=quant,
+                       decode_buckets=DECODE_BUCKETS,
+                       prefill_buckets=(PREFILL_BUCKET,), prefill_chunk=0,
+                       default_timeout=60.0)
+    try:
+        cfg = net.cfg
+        rows = cfg.num_kv_heads * cfg.head_dim
+        assert cfg.head_dim == 64 and rows >= 128
+        layer = eng.pool.tensors[0]
+        pool_bytes = sum(t.size * t.dtype.itemsize
+                         for ts in eng.pool.tensors for t in ts)
+        values = [t for t in layer if t.shape[2:] == (rows,)]
+        scales = [t for t in layer if t.shape[2:] != (rows,)]
+        assert len(values) == 2 and len(scales) == 2 * (quant == "int8")
+        for b in DECODE_BUCKETS:
+            eng._decode_fn(b)
+        eng._prefill_fn(PREFILL_BUCKET)
+        tags = [f"decode-step-b{b}" for b in DECODE_BUCKETS] \
+            + [f"decode-prefill-p{PREFILL_BUCKET}"]
+        for tag in tags:
+            got, temp = _pool_reading(built[tag], values)
+            (got,) = got.values()
+            record_property(f"{tag}.temp_bytes", temp)
+            record_property(f"{tag}.values", got)
+            if scales:              # recorded, not judged (PERF.md sec. 7)
+                (read,) = _pool_reading(built[tag], scales)[0].values()
+                record_property(f"{tag}.scales", read)
+            # one layout wherever a pool tensor appears (argument, result,
+            # loop carry, every fusion between them), so no copy has a
+            # pool tensor in one layout as operand and in another as
+            # result: what the entry computation still copies is the
+            # un-donated pool, plainly, and the loop copies none
+            assert len(got["layouts"]) == 1, (tag, got)
+            assert got["inner_copies"] == 0, (tag, got)
+            assert temp < pool_bytes, (tag, temp, pool_bytes)
+    finally:
+        eng.shutdown(drain_timeout=10.0)
+
+
+# ---------------------------------------------------------------------------
+# CPU: tokens and written rows against the parent's, bit for bit
+# ---------------------------------------------------------------------------
+
+TINY = dict(vocab_size=97, hidden_size=48, num_heads=4, num_kv_heads=2,
+            num_layers=2, rope=True, swiglu=True, rms_norm=True,
+            max_position_embeddings=64, tie_word_embeddings=False)
+
+# Recorded from the parent commit (3d78f20, pool [N, bs, Hkv, D]) by this
+# very procedure: the served tokens, and sha256[:16] of every pool tensor's
+# bytes in the engine's (layer, entry) order.
+PARENT = {
+    None: ([[91, 53, 78, 72, 87, 49, 14, 72, 87, 53],
+            [1, 14, 72, 87, 19, 24]],
+           ["909b117860808f1f", "93f22409611614d1",
+            "a37cb7b08792039b", "4404ddd64b55e39e"]),
+    "int8": ([[91, 53, 78, 72, 87, 49, 14, 72, 87, 53],
+              [1, 14, 72, 87, 19, 24]],
+             ["70da0cc5427ea6e8", "f2f1993b2feca6a6",
+              "7dff4527cf5dd7ba", "9c9ccf1968f84290",
+              "f3f13ac891b810ca", "560fedbc4dd22f00",
+              "83529f4947f08db6", "0ff619f79702e48f"]),
+}
+
+
+def _prompt(seed, n=6):
+    return np.random.RandomState(seed).randint(
+        0, TINY["vocab_size"], (n,)).astype(np.int32)
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["bf16", "int8"])
+def test_tokens_and_written_rows_equal_the_parents(quant, tmp_path,
+                                                   monkeypatch):
+    """One sequence after the other, so which block holds what is the
+    allocator's own order: a one-chunk prompt, then one of two chunks. A
+    flat row is the parent's [Hkv, D] row with its bytes in the same
+    order, so the digests compare the pools as [N, bs, Hkv, D]."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    paddle.seed(7)
+    m = gpt("gpt_tiny", **TINY)
+    m.eval()
+    eng = DecodeEngine(m, max_length=48, block_size=8,
+                       decode_buckets=(1, 2, 4), prefill_buckets=(8, 16),
+                       prefill_chunk=8, quant=quant, default_timeout=60.0)
+    try:
+        tokens = [list(map(int, eng.generate(_prompt(3), 10))),
+                  list(map(int, eng.generate(_prompt(1, 13), 6)))]
+        hkv, d = m.cfg.num_kv_heads, m.cfg.head_dim
+        digests = []
+        for layer in eng.pool.tensors:
+            for t in layer:
+                a = np.asarray(t)
+                if a.shape[2:] == (hkv * d,):
+                    a = a.reshape(a.shape[:2] + (hkv, d))
+                else:
+                    assert a.shape[2:] == (hkv,)      # int8 scales
+                digests.append(hashlib.sha256(
+                    np.ascontiguousarray(a).tobytes()).hexdigest()[:16])
+    finally:
+        eng.shutdown(drain_timeout=10.0)
+    want_tokens, want_digests = PARENT[quant]
+    assert tokens == want_tokens
+    assert digests == want_digests

@@ -92,7 +92,7 @@ def _quiesced_leak(st):
 def _tiny_pool(num_blocks=8, block_size=4):
     import jax.numpy as jnp
 
-    spec = (((2, 4), jnp.float32), ((2, 4), jnp.float32))
+    spec = (((8,), jnp.float32, 2), ((8,), jnp.float32, 2))
     return BlockKVCache(num_blocks, block_size, [spec])
 
 

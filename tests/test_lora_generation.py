@@ -138,7 +138,9 @@ def test_int8_kv_cache_token_parity():
     assert len(caches[0]) == 4
     kq, ks, vq, vs = caches[0]
     assert str(kq.dtype).endswith("int8") and str(vq.dtype).endswith("int8")
-    assert ks.shape == kq.shape[:-1]
+    # flat int8 rows [B, T, Hkv*D], one f32 scale a (position, head)
+    assert tuple(kq.shape) == (2, 32, m.cfg.num_kv_heads * m.cfg.head_dim)
+    assert tuple(ks.shape) == (2, 32, m.cfg.num_kv_heads)
     # logits parity through a cached prefill step
     lb_model = gpt("gpt_tiny")
     lb_model.eval()

@@ -584,9 +584,10 @@ class TestDecodeEngineTP:
                 assert eng._param_sh[
                     "transformer.layers.0.attn.qkv_proj.weight"
                 ].spec == pspec(None, "tp")
-                # paged KV blocks shard along the kv-head dim
+                # paged KV blocks shard along the kv heads of the flat
+                # [N, bs, Hkv*D] rows: whole heads a shard
                 assert eng.pool.shardings[0][0].spec == \
-                    pspec(None, None, "tp", None)
+                    pspec(None, None, "tp")
                 tp_toks = eng.generate(prompt, 5, timeout=120.0)
                 assert tp_toks == ref
                 st = eng.stats()
