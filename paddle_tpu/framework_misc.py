@@ -122,10 +122,13 @@ def check_shape(shape):
 
 class LazyGuard:
     """Reference: paddle.LazyGuard — delays parameter materialization for
-    giant models. Parameters here are jax arrays initialized on creation;
-    the guard keeps the API contract (usable as a context manager) and
-    marks layers constructed inside it so `model.to()`-style flows can
-    re-initialize cheaply."""
+    giant models. A parameter that `Layer.create_parameter` makes inside
+    the guard knows its shape and dtype and holds no array: its
+    initializer runs at the first read of its value, and a value assigned
+    before that (a checkpoint's, a benchmark's seeded weights) takes its
+    place without the initial one ever being built. A model whose float32
+    initial values would not fit the device beside its real weights is
+    constructed this way."""
 
     _active = False
 

@@ -84,6 +84,21 @@ that already exist in-tree:
   isolated single-sequence steps to pin the blame, mirroring the
   batcher's split-on-failure.
 
+* **Generation by diffusion over blocks** (`block_diffusion=`, off by
+  default; SDAR-class models trained under a mask that is causal over
+  blocks of B positions and full inside one): a sequence's answer grows a
+  block of B tokens at a time. A block starts as B mask tokens; each
+  DENOISING pass forwards its B positions against the cache of all earlier
+  blocks, writes no cache, and fixes the B/T masked positions whose
+  arg-max token is most confident; once all are fixed one COMMIT pass
+  forwards the block again and writes its B cache rows. A decode dispatch
+  is then one `[1, B]` forward a sequence at its block's offset, each
+  sequence in its own phase — the phase is DATA (one executable a bucket,
+  the cache write selected by it), the block's state lives with the
+  sequence between rounds and changes only after a dispatch has returned,
+  and the stream gets a block's tokens when it commits, each with the
+  pass that fixed it (`SequenceStream.passes`).
+
 Determinism contract: the decode step runs the active batch as a
 `lax.scan` over per-sequence sub-steps (the serving twin of
 `compile_batched`'s `lax.map`), so the per-sequence program is IDENTICAL at
@@ -181,6 +196,8 @@ class SequenceStream:
         self.id = seq_id
         self.deadline = deadline
         self.tokens = []          # delivered tokens (engine-appended)
+        self.passes = []          # block diffusion: per token, the pass of
+        #                           its block that fixed it (else empty)
         self._q = queue.Queue()
         self._status = "running"  # running|completed|failed|timed_out|cancelled
         self._error = None
@@ -236,12 +253,16 @@ class SequenceStream:
             raise StopIteration
         raise self._error
 
-    def result(self):
+    def result(self, with_passes=False):
         """Drain the stream to completion and return the full generated
         token list; raises the typed error on failure (partial tokens
-        stay readable via `.tokens`)."""
+        stay readable via `.tokens`). `with_passes=True` returns
+        `(tokens, passes)`: under block diffusion each token's denoising
+        pass (1-based) within its block."""
         for _ in self:
             pass
+        if with_passes:
+            return list(self.tokens), list(self.passes)
         return list(self.tokens)
 
     def poll(self, timeout=None):
@@ -280,11 +301,15 @@ class _Seq:
                  "draft_pos", "draft_outstanding", "spec_proposed",
                  "spec_accepted", "sampling", "adapter", "adapter_slot",
                  "adapter_sig", "sample_base", "out_tokens", "held",
-                 "t_submit", "t_admit", "t_first", "round_admit", "chunks")
+                 "t_submit", "t_admit", "t_first", "round_admit", "chunks",
+                 "prefill_ids", "bd")
 
     def __init__(self, sid, prompt, max_new, deadline):
         self.id = sid
         self.prompt = prompt           # np.int32 [prompt_len]
+        self.prefill_ids = prompt      # what prefill puts in the cache (block
+        #                                diffusion: the whole blocks of it)
+        self.bd = None                 # block diffusion: the open block
         self.max_new = max_new
         self.deadline = deadline
         self.stream = SequenceStream(sid, deadline)
@@ -342,7 +367,7 @@ class DecodeEngine:
                  mesh=None, sharding_rules=None, clock=time.monotonic,
                  prefix_cache=True, prefix_cache_blocks=None,
                  prefill_chunk=None, draft_model=None, speculate_k=0,
-                 draft_num_blocks=None, adapters=None):
+                 draft_num_blocks=None, adapters=None, block_diffusion=None):
         from ...distributed.functional import functionalize
         from ...core.tensor import Tensor
 
@@ -368,6 +393,10 @@ class DecodeEngine:
         self._clock = clock
         self._vocab = getattr(getattr(model, "cfg", None), "vocab_size",
                               None)
+        self._bd = self._check_block_diffusion(
+            block_diffusion, model, quant=quant, mesh=mesh,
+            adapters=adapters, draft_model=draft_model,
+            speculate_k=speculate_k)
 
         if prefill_buckets is None:
             p, buckets = min(8, self.max_length - 1), []
@@ -419,6 +448,13 @@ class DecodeEngine:
                 nb_per_seq + (1 if self._prefix_on else 0))
         self.pool = model.init_block_pool(num_blocks, self.block_size,
                                           quant=quant, name="target")
+        if self._bd is not None and (
+                self.block_size % self._bd["block_length"]
+                or (self._chunk and self._chunk % self._bd["block_length"])):
+            raise ValueError(
+                f"block_diffusion block_length {self._bd['block_length']} "
+                f"must divide block_size {self.block_size}: a committed "
+                f"block's rows lie in one pool block")
 
         # speculative decoding: a draft model proposes speculate_k tokens
         # per round from ITS OWN paged pool (same geometry: max_length /
@@ -534,6 +570,9 @@ class DecodeEngine:
 
         self._apply, self._params, self._buffers = functionalize(
             model, method=wrapped)
+        if self._bd is not None:
+            self._bd_apply = functionalize(
+                model, method=self._make_bd_forward(model))[0]
 
         # tensor-parallel placement (paddle_tpu.sharding): weights shard
         # per their logical-axis annotations / the name-pattern rules,
@@ -581,6 +620,7 @@ class DecodeEngine:
             if self._spec_on else None
 
         self._decode_fns = {}     # bucket -> compiled step
+        self._bd_fns = {}         # bucket -> compiled block-diffusion step
         self._prefill_fns = {}    # prompt bucket -> compiled prefill
         self._verify_fns = {}     # bucket -> compiled K+1-position verify
         self._propose_fns = {}    # bucket -> compiled K-step draft propose
@@ -652,6 +692,20 @@ class DecodeEngine:
         self._spec_draft_dispatches = 0
         self._spec_catchup_chunks = 0
         self._spec_fallbacks = 0
+        # block diffusion (a dispatch forwards a block a sequence; a
+        # forward is one sequence's share of a dispatch) and the expert
+        # layers' counts read back with its tokens
+        self._bd_forwards = 0
+        self._bd_commit_forwards = 0
+        self._bd_tokens_fixed = 0
+        self._bd_blocks_committed = 0
+        self._bd_head_dispatches = 0   # dispatches with a denoising pass
+        self._bd_context_tokens = 0    # keys the forwards attended to
+        self._moe_tokens = None        # [layers, experts] positions routed
+        self._moe_distinct = 0         # experts touched, summed over
+        #                                layers and dispatches
+        self._moe_load_sum = 0.0       # fullest expert over the mean, summed
+        self._moe_load_n = 0           # ... over this many (layer, dispatch)
         # scheduler rounds: written by the scheduler thread alone
         # (_slow_rounds under _lock: stats() reads it)
         self._round_no = 0
@@ -715,6 +769,12 @@ class DecodeEngine:
             # geometry (rank/slots/target layers) is part of the
             # program's identity exactly like the weight avals above
             h.update(f"adapters:{self._adapters.geometry()}".encode())
+        # what shapes the program and is no parameter's shape (a block
+        # mask, a routing rule): named by the model, "" for the models
+        # that have none, whose keys stay as they were
+        sig = getattr(self.model, "decode_signature", lambda: "")()
+        if sig or self._bd is not None:
+            h.update(f"model:{sig}:bd:{self._bd}".encode())
         if self.mesh is not None:
             # a TP engine compiles different programs — its disk-cache
             # entries must never collide with the single-device ones
@@ -814,12 +874,22 @@ class DecodeEngine:
         max_new = int(max_new_tokens)
         if max_new < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new}")
-        if ids.shape[0] + max_new > self.max_length:
+        if self._bd is not None and (committed or not (
+                sampling is None or sampling.is_greedy())):
+            raise ValueError(
+                "block diffusion decodes greedily from the prompt: "
+                "sampling params and resume_committed are refused (a "
+                "resumed prompt would move the blocks' boundaries)")
+        rows = self._cache_rows(ids.shape[0], max_new)
+        if rows > self.max_length:
             raise ValueError(
                 f"prompt ({ids.shape[0]}) + max_new_tokens ({max_new}) "
-                f"exceeds max_length {self.max_length}")
-        worst = self.pool.blocks_for(ids.shape[0] + max_new) + (
-            1 if self._prefix_on and ids.shape[0] % self.block_size else 0)
+                f"exceeds max_length {self.max_length}"
+                + ("" if self._bd is None else
+                   f" ({rows} cache rows in whole blocks)"))
+        worst = self.pool.blocks_for(rows) + (
+            1 if self._prefix_on and self._prefill_len(ids.shape[0])
+            % self.block_size else 0)
         if worst > self.pool.num_blocks - RESERVED_BLOCKS:
             raise ValueError(
                 f"request needs {worst} worst-case blocks but the pool "
@@ -852,6 +922,7 @@ class DecodeEngine:
                     f"— request shed; retry with backoff")
             self._ids += 1
             seq = _Seq(self._ids, ids.astype(np.int32), max_new, dl)
+            seq.prefill_ids = seq.prompt[:self._prefill_len(len(seq.prompt))]
             seq.sampling = sampling
             seq.sample_base = committed
             if adapter is not None:
@@ -906,6 +977,75 @@ class DecodeEngine:
             seq.cancelled = True
             self._cv.notify()
 
+    # -- block diffusion: configuration and geometry -----------------------
+    @staticmethod
+    def _check_block_diffusion(opt, model, *, quant, mesh, adapters,
+                               draft_model, speculate_k):
+        """The `block_diffusion` option as a dict of ints, or None. What
+        has no test together with it is refused here, at construction."""
+        if not opt:
+            return None
+        try:
+            bd = {k: int(opt[k]) for k in ("block_length",
+                                           "denoising_steps",
+                                           "mask_token_id")}
+        except (KeyError, TypeError, ValueError) as e:
+            raise ValueError(
+                f"block_diffusion needs integer block_length, "
+                f"denoising_steps and mask_token_id, got {opt!r}") from e
+        bl, steps = bd["block_length"], bd["denoising_steps"]
+        if bl < 1 or steps < 1 or bl % steps:
+            raise ValueError(
+                f"block_diffusion: denoising_steps ({steps}) must divide "
+                f"block_length ({bl}): a pass fixes block_length / "
+                f"denoising_steps positions")
+        cfg = getattr(model, "cfg", None)
+        want = getattr(cfg, "block_attention", 0) or 1
+        if want != bl:
+            raise ValueError(
+                f"block_diffusion block_length {bl} is not the model's "
+                f"attention block ({want}): the mask the model runs under "
+                f"is its configuration's `block_attention`")
+        vocab = getattr(cfg, "vocab_size", None)
+        if vocab is not None and not 0 <= bd["mask_token_id"] < vocab:
+            raise ValueError(
+                f"block_diffusion mask_token_id {bd['mask_token_id']} "
+                f"outside the vocabulary [0, {vocab})")
+        if not (hasattr(model, "transformer") and hasattr(model, "_project")):
+            raise ValueError(
+                "block_diffusion needs a model with `transformer."
+                "forward_step` and `_project` (GPTForCausalLM): a commit "
+                "pass runs the trunk without the head")
+        refused = [n for n, on in (
+            ("quant", quant is not None
+             or getattr(model, "cache_quant", None) is not None),
+            ("mesh", mesh is not None), ("adapters", adapters is not None),
+            ("draft_model / speculate_k",
+             draft_model is not None or speculate_k)) if on]
+        if refused:
+            raise ValueError(
+                f"block_diffusion does not compose with "
+                f"{', '.join(refused)} yet (untested together: refused "
+                f"rather than served unproven)")
+        return bd
+
+    def _prefill_len(self, plen):
+        """Prompt tokens that prefill puts in the cache: all of them, or
+        under block diffusion the whole blocks (the remainder opens the
+        first generated block as positions already fixed)."""
+        if self._bd is None:
+            return plen
+        return plen // self._bd["block_length"] * self._bd["block_length"]
+
+    def _cache_rows(self, plen, max_new):
+        """Cache rows a request can come to write: prompt + answer, under
+        block diffusion rounded up to whole blocks (the last block's tail
+        is computed and committed, not delivered)."""
+        if self._bd is None:
+            return plen + max_new
+        bl = self._bd["block_length"]
+        return -(-(plen + max_new) // bl) * bl
+
     # -- compiled programs -------------------------------------------------
     def _avals(self, arrays):
         import jax
@@ -916,9 +1056,9 @@ class DecodeEngine:
     def _weight_avals(self):
         import jax
 
-        pv = {n: jax.ShapeDtypeStruct(tuple(p.shape), p._value.dtype)
+        pv = {n: jax.ShapeDtypeStruct(tuple(p.shape), p.dtype)
               for n, p in self._params.items()}
-        bv = {n: jax.ShapeDtypeStruct(tuple(b.shape), b._value.dtype)
+        bv = {n: jax.ShapeDtypeStruct(tuple(b.shape), b.dtype)
               for n, b in self._buffers.items()}
         return pv, bv
 
@@ -975,6 +1115,110 @@ class DecodeEngine:
                 entry.append(t.at[block, off].set(row.astype(t.dtype)))
             out.append(tuple(entry))
         return out
+
+    def _scatter_rows(self, pool_ts, new_caches, table, pos, n, live):
+        """Write the `n` cache rows a block forward produced at `pos` back
+        into the pool where `live` (a commit pass), else into reserved
+        block 0, the padding sink (a denoising pass writes no cache). The
+        rows lie in one pool block: `n` divides `block_size` and `pos` is
+        a multiple of `n`."""
+        import jax
+        import jax.numpy as jnp
+
+        block = jnp.where(live != 0, table[pos // self.block_size], 0)
+        off = pos % self.block_size
+        out = []
+        for layer_ts, layer_new in zip(pool_ts, new_caches):
+            entry = []
+            for t, c in zip(layer_ts, layer_new):
+                rows = jax.lax.dynamic_slice_in_dim(c[0], pos, n, axis=0)
+                entry.append(jax.lax.dynamic_update_slice(
+                    t, rows.astype(t.dtype)[None],
+                    (block, off) + (0,) * (t.ndim - 2)))
+            out.append(tuple(entry))
+        return out
+
+    @staticmethod
+    def _make_bd_forward(model):
+        """The traced block forward: tokens [1, B] at offset `pos` against
+        the gathered cache. Returns per position the arg-max token of its
+        own logits (no shift) and that token's softmax probability, the
+        caches with the block's rows written, and the expert layers'
+        position counts `[layers, experts]`. A commit pass (`commit` != 0)
+        needs no logits and skips the head."""
+        import jax
+        import jax.numpy as jnp
+        from ...core.tensor import Tensor
+        from ...models.moe import expert_counts
+
+        def forward(tokens, cache_vals, pos, commit):
+            cts = [tuple(Tensor(a) for a in entry) for entry in cache_vals]
+            with expert_counts() as counts:
+                hidden, new_caches = model.transformer.forward_step(
+                    Tensor(tokens), cts, Tensor(pos))
+
+            def head(h):
+                lg = model._project(Tensor(h))._value[0].astype(jnp.float32)
+                top = jnp.max(lg, axis=-1)
+                return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
+                        jnp.exp(top - jax.nn.logsumexp(lg, axis=-1)))
+
+            def skip(h):
+                return (jnp.zeros(h.shape[1], jnp.int32),
+                        jnp.zeros(h.shape[1], jnp.float32))
+
+            best, conf = jax.lax.cond(commit != 0, skip, head, hidden._value)
+            counts = jnp.stack(counts) if counts \
+                else jnp.zeros((0, 0), jnp.int32)
+            return (best, conf,
+                    [tuple(t._value for t in nc) for nc in new_caches],
+                    counts)
+
+        return forward
+
+    def _bd_fn(self, bucket):
+        """Block-diffusion step for `bucket` sequences: one `[1, B]`
+        forward a sequence at its block's offset (the shape of
+        `_verify_fn`), the sequences scanned as in the plain step. A
+        sequence's phase is DATA (`commit`): the same program denoises one
+        sequence and commits another, and only a commit writes cache rows
+        (a denoising pass's rows sink into reserved block 0). Padded slots
+        (`valid` 0) commit nothing and count no expert."""
+        fn = self._bd_fns.get(bucket)
+        if fn is not None:
+            return fn
+        import jax
+        import jax.numpy as jnp
+        from ...jit import aot
+
+        bl = self._bd["block_length"]
+
+        def step(pv, bv, pool_ts, tokens, positions, tables, commit, valid):
+            def body(pool_ts, x):
+                toks, pos0, table, com, ok = x
+                caches = self._gather(pool_ts, table)
+                (best, conf, new_caches, counts), _ = self._bd_apply(
+                    pv, bv, toks.reshape(1, bl), caches, pos0, com)
+                pool_ts = self._scatter_rows(pool_ts, new_caches, table,
+                                             pos0, bl, com * ok)
+                return pool_ts, (best, conf, counts * ok)
+
+            pool_ts, (best, conf, counts) = jax.lax.scan(
+                body, pool_ts, (tokens, positions, tables, commit, valid))
+            return pool_ts, (best, conf, jnp.sum(counts, axis=0))
+
+        pv, bv = self._weight_avals()
+        row = jax.ShapeDtypeStruct((bucket,), jnp.int32)
+        avals = (pv, bv, self._avals(self.pool.tensors),
+                 jax.ShapeDtypeStruct((bucket, bl), jnp.int32), row,
+                 jax.ShapeDtypeStruct((bucket, self._nb), jnp.int32),
+                 row, row)
+        compiled, source = aot.compile_jit(
+            step, avals, fingerprint=self._fingerprint, cache=self._cache,
+            tag=f"decode-step-bd-b{bucket}", audit_ctx=self._audit_ctx(pv))
+        self._note_compile(source)
+        self._bd_fns[bucket] = compiled
+        return compiled
 
     def _adapter_avals(self):
         """Abstract values of the adapter slot stacks riding every
@@ -1454,7 +1698,8 @@ class DecodeEngine:
         sentinel can treat any later compile as a finding. Returns
         ``{"decode": [...], "prefill": [...]}``."""
         for b in self.decode_buckets:
-            self._decode_fn(b)
+            # one step executable a bucket either way
+            (self._decode_fn if self._bd is None else self._bd_fn)(b)
         for p in self.prefill_buckets:
             self._prefill_fn(p)
         if self._prefix_on:
@@ -1681,8 +1926,8 @@ class DecodeEngine:
                         self._prefill_round()
                 if self._active:
                     with _otrace.span(_DECODE["round"],
-                                      profile=True):
-                        self._decode_round()
+                                      profile=True) as phase:
+                        self._decode_round(phase)
         except Exception as exc:  # noqa: BLE001 — scheduler must
             # survive anything: fail the implicated sequences with a
             # typed error instead of silently dying with them stuck
@@ -1760,13 +2005,13 @@ class DecodeEngine:
                         >= self.max_active:
                     return
                 seq = self._waiting[0]
-                plen = len(seq.prompt)
+                plen = len(seq.prefill_ids)
                 cow = 1 if (self._prefix_on
                             and plen % self.block_size) else 0
                 seq.reserved_total = self.pool.blocks_for(
-                    plen + seq.max_new) + cow
+                    self._cache_rows(len(seq.prompt), seq.max_new)) + cow
                 entry = self._match_prefix(
-                    seq.prompt, seq.adapter_sig,
+                    seq.prefill_ids, seq.adapter_sig,
                     full_ok=self._is_greedy(seq)) \
                     if self._prefix_on else None
                 matched = len(entry["blocks"]) if entry else 0
@@ -1781,7 +2026,7 @@ class DecodeEngine:
                     # the draft pool has no prefix cache to evict from:
                     # its worst case (every live sequence speculating K
                     # tokens past its final position) must simply fit
-                    dworst = self._draft_worst(plen, seq.max_new)
+                    dworst = self._draft_worst(len(seq.prompt), seq.max_new)
                     dreserve = sum(s.draft_outstanding
                                    for s in self._active) \
                         + sum(s.draft_outstanding
@@ -1805,7 +2050,7 @@ class DecodeEngine:
         refcounts instead of re-prefilling the shared tokens) and route
         it: a full-prompt hit joins the running batch immediately — zero
         prompt compute — anything else enters the chunked-prefill queue."""
-        plen = len(seq.prompt)
+        plen = len(seq.prefill_ids)
         seq.t_admit = time.perf_counter()
         seq.round_admit = self._round_no
         if self._h_queue_wait is not None and seq.submitted_at is not None:
@@ -1834,7 +2079,12 @@ class DecodeEngine:
                 self._peak_resident = max(
                     self._peak_resident,
                     len(self._active) + len(self._prefill_q))
-            self._deliver(seq, int(entry["next_token"]))
+            if self._bd is not None:
+                # nothing to deliver yet: the first block opens (a prompt
+                # shorter than a block has no prefill at all)
+                self._bd_open_block(seq, seq.prompt[plen:])
+            else:
+                self._deliver(seq, int(entry["next_token"]))
             return
         seq.state = _PREFILL
         with self._cv:
@@ -1861,8 +2111,8 @@ class DecodeEngine:
         with self._cv:
             if self._stopping or not self._prefill_q:
                 return
-            seq = min(self._prefill_q,
-                      key=lambda s: (len(s.prompt) - s.prefill_pos, s.id))
+            seq = min(self._prefill_q, key=lambda s: (
+                len(s.prefill_ids) - s.prefill_pos, s.id))
         try:
             self._prefill_chunk(seq)
         except PoolClosed as e:
@@ -1881,7 +2131,7 @@ class DecodeEngine:
         chunk the sequence publishes its prefix-cache entries and joins
         the running batch."""
         names = _PREFILL
-        plen = len(seq.prompt)
+        plen = len(seq.prefill_ids)
         start = seq.prefill_pos
         remaining = plen - start
         this_len = self._chunk if (self._chunk
@@ -1909,7 +2159,7 @@ class DecodeEngine:
             hist = self._hist_row(seq)
             samp = self._samp_row(seq)
             tokens = np.full((1, pbucket), self.pad_token_id, np.int32)
-            tokens[0, :this_len] = seq.prompt[start:start + this_len]
+            tokens[0, :this_len] = seq.prefill_ids[start:start + this_len]
             table = self._padded_table(seq, self._nb + self._prefill_tail)
             pool_ts = self.pool.tensors
         hook = self._fault_hook
@@ -1958,8 +2208,9 @@ class DecodeEngine:
 
     def _prefill_done(self, seq, new_pool, tok, done):
         """Commit one prompt chunk: the pool, the prefix-cache entries it
-        completes and, after the last chunk, the first token."""
-        plen = len(seq.prompt)
+        completes and, after the last chunk, the first token (block
+        diffusion: the first open block)."""
+        plen = len(seq.prefill_ids)
         self.pool.tensors = new_pool
         seq.prefill_pos = done
         seq.chunks += 1
@@ -1976,7 +2227,7 @@ class DecodeEngine:
             # full-prompt hit would deliver it to someone else.
             with self._cv:
                 self._prefix_insert(
-                    "chunk", seq.prompt[:done],
+                    "chunk", seq.prefill_ids[:done],
                     seq.blocks[:done // self.block_size], tok,
                     seq.adapter_sig)
         if done < plen:
@@ -1991,8 +2242,8 @@ class DecodeEngine:
         if self._prefix_on and self._is_greedy(seq) \
                 and not (self._chunk and plen % self._chunk == 0):
             with self._cv:
-                self._prefix_insert("full", seq.prompt, seq.blocks, tok,
-                                    seq.adapter_sig)
+                self._prefix_insert("full", seq.prefill_ids, seq.blocks,
+                                    tok, seq.adapter_sig)
         with self._lock:
             self._prefills += 1
         seq.state = _ACTIVE
@@ -2001,7 +2252,12 @@ class DecodeEngine:
             if seq in self._prefill_q:
                 self._prefill_q.remove(seq)
             self._active.append(seq)
-        self._deliver(seq, tok)
+        if self._bd is not None:
+            # the prefill's own next token is no token of a block model:
+            # the prompt's remainder opens the first block instead
+            self._bd_open_block(seq, seq.prompt[plen:])
+        else:
+            self._deliver(seq, tok)
 
     # -- prefix cache (copy-on-write block sharing) ------------------------
     # All helpers below run on the scheduler thread with _cv held (the
@@ -2191,7 +2447,7 @@ class DecodeEngine:
         with self._lock:
             self._cow_copies += 1
 
-    def _decode_round(self):
+    def _decode_round(self, phase=None):
         # step-boundary sweep: cancelled / expired sequences leave before
         # another step is spent on them
         for seq in list(self._active):
@@ -2224,8 +2480,29 @@ class DecodeEngine:
             # round) rejoin the plain batch — generation never stalls
             # behind a long catch-up
             active += self._speculate_round(spec)
-        if active:
+        if active and self._bd is not None:
+            self._bd_round(active, phase)
+        elif active:
             self._plain_round(active)
+
+    def _grow_for_write(self, active):
+        """Lazy block growth + copy-on-write for the row(s) this step
+        writes at `seq.pos` (see `_plain_round`); a sequence the pool
+        cannot serve fails and leaves `active`."""
+        for seq in list(active):
+            try:
+                if seq.pos >= len(seq.blocks) * self.block_size:
+                    seq.blocks += self.pool.alloc(1, owner=seq.id)
+                    seq.outstanding -= 1
+                else:
+                    bi = seq.pos // self.block_size
+                    if self.pool.refcount(seq.blocks[bi]) > 1:
+                        self._cow_block(seq, bi)
+            except OutOfBlocks as e:
+                active.remove(seq)
+                self._finish(seq, "failed", RequestFailed(
+                    f"sequence {seq.id}: block pool exhausted "
+                    f"mid-decode (admission reserve bug)", cause=e))
 
     def _plain_round(self, active):
         # lazy block growth + copy-on-write: the admission reserve
@@ -2236,20 +2513,7 @@ class DecodeEngine:
         # that one block first and drops its shared reference.
         names = _DECODE
         with _otrace.span(names["grow"], profile=True):
-            for seq in list(active):
-                try:
-                    if seq.pos >= len(seq.blocks) * self.block_size:
-                        seq.blocks += self.pool.alloc(1, owner=seq.id)
-                        seq.outstanding -= 1
-                    else:
-                        bi = seq.pos // self.block_size
-                        if self.pool.refcount(seq.blocks[bi]) > 1:
-                            self._cow_block(seq, bi)
-                except OutOfBlocks as e:
-                    active.remove(seq)
-                    self._finish(seq, "failed", RequestFailed(
-                        f"sequence {seq.id}: block pool exhausted "
-                        f"mid-decode (admission reserve bug)", cause=e))
+            self._grow_for_write(active)
         if not active:
             return
         try:
@@ -2271,7 +2535,7 @@ class DecodeEngine:
                 self._deliver(seq, int(tok))
 
     def _run_linked_step(self, name, event_name, seqs, hook_tag, info,
-                         dispatch, sweep=False):
+                         dispatch, sweep=False, member_attrs=None):
         """Shared scaffolding for every gathered multi-sequence dispatch
         (plain decode step, speculative propose, speculative verify):
         fault hook, one step-trace root span LINKING every member
@@ -2281,13 +2545,16 @@ class DecodeEngine:
         XLA call, optional non-finite sweep over the freshly written
         pool, and the sanctioned host fetch — one implementation, three
         steps. `dispatch()` runs the compiled program and returns
-        `(new_pool_tensors, host_array)`."""
+        `(new_pool_tensors, host_array)` (the block step: a tuple of
+        arrays); `member_attrs` (one dict a sequence) joins that member's
+        back-link event."""
         hook = self._fault_hook
         ids = [s.id for s in seqs]
         traced = ([s for s in seqs
                    if s.span.ctx is not None and s.span.ctx.sampled]
                   if _otrace.enabled() else [])
         member_extra = {k: v for k, v in info.items() if k != "bucket"}
+        per_member = {s.id: a for s, a in zip(seqs, member_attrs or ())}
         names = _DECODE
         rnd = self._round_no
         self._last_step = (info.get("bucket"), ids)
@@ -2315,11 +2582,14 @@ class DecodeEngine:
                 with _san.allow_host_sync("decode.token_fetch"), \
                         _otrace.span_in(names["fetch"], hctx,
                                         profile=True):
-                    out = new_pool, np.asarray(host)
+                    out = new_pool, (
+                        tuple(np.asarray(a) for a in host)
+                        if isinstance(host, tuple) else np.asarray(host))
             for s in traced:
                 _otrace.event_in(
                     event_name, s.span.ctx,
                     attrs={"seq": s.id, "pos": int(s.pos), **member_extra,
+                           **per_member.get(s.id, {}),
                            "step_trace": step_span.trace_id_hex})
             return out
 
@@ -2358,6 +2628,149 @@ class DecodeEngine:
             self._step_slots += bucket
             self._step_active += n
         return nxt[:n]
+
+    # -- block diffusion round ---------------------------------------------
+    # One dispatch a round for the whole active batch: every sequence
+    # forwards its open block once, in its own phase. What the host keeps
+    # of a block (`seq.bd`) changes only in `_bd_advance`, after the
+    # dispatch has returned: a wedged step that is re-submitted, and a
+    # failed step re-run as isolated singles, start from the same state.
+
+    def _bd_open_block(self, seq, fixed=()):
+        """The next block of `seq`, all mask tokens but for `fixed` (the
+        prompt's remainder, at the head of the first block)."""
+        bl, mask = self._bd["block_length"], self._bd["mask_token_id"]
+        tokens = np.full(bl, mask, np.int32)
+        tokens[:len(fixed)] = fixed
+        seq.bd = {"tokens": tokens,
+                  "masked": np.arange(bl) >= len(fixed),
+                  "passes": np.zeros(bl, np.int32),  # pass that fixed it
+                  "t": 0,                   # denoising passes it has had
+                  "given": len(fixed),      # leading positions not generated
+                  "index": 0 if seq.bd is None else seq.bd["index"] + 1}
+
+    def _bd_round(self, active, phase=None):
+        names = _DECODE
+        with _otrace.span(names["grow"], profile=True):
+            # the block's rows are written by its commit pass only, into
+            # ONE pool block (block_length divides block_size): the same
+            # grow / copy-on-write rule as a plain step's row at seq.pos
+            self._grow_for_write(active)
+        if not active:
+            return
+        commits = sum(not s.bd["masked"].any() for s in active)
+        if phase is not None:
+            phase.set_attr("denoise", len(active) - commits)
+            phase.set_attr("commit", commits)
+        try:
+            out = self._bd_dispatch(active)
+        except PoolClosed:
+            return           # engine stopping; shutdown fails leftovers
+        except RequestFailed as e:
+            if len(active) == 1:
+                self._finish(active[0], "failed", e)
+                return
+            with self._lock:
+                self._isolations += 1
+            for seq in list(active):
+                if seq.state != _ACTIVE:
+                    continue
+                try:
+                    out = self._bd_dispatch([seq])
+                except PoolClosed:
+                    return
+                except RequestFailed as e1:
+                    self._finish(seq, "failed", e1)
+                    continue
+                self._bd_advance(seq, *(row[0] for row in out))
+            return
+        with _otrace.span(names["deliver"], profile=True):
+            for i, seq in enumerate(active):
+                self._bd_advance(seq, *(row[i] for row in out))
+
+    def _bd_dispatch(self, active):
+        """One block forward a sequence. Returns per sequence (whether it
+        was a commit pass, the arg-max tokens [B], their confidences
+        [B])."""
+        n = len(active)
+        bl = self._bd["block_length"]
+        bucket = next(b for b in self.decode_buckets if b >= n)
+        with _otrace.span(_DECODE["pack"], profile=True):
+            fn = self._bd_fn(bucket)
+            pv, bv = self._weights()
+            tokens = np.full((bucket, bl), self.pad_token_id, np.int32)
+            positions = np.zeros(bucket, np.int32)
+            tables = np.zeros((bucket, self._nb), np.int32)  # pad -> 0
+            commit = np.zeros(bucket, np.int32)
+            valid = np.zeros(bucket, np.int32)
+            valid[:n] = 1
+            for i, seq in enumerate(active):
+                tokens[i] = seq.bd["tokens"]
+                positions[i] = seq.pos
+                tables[i] = self._padded_table(seq)
+                commit[i] = not seq.bd["masked"].any()
+            pool_ts = self.pool.tensors
+        ncommit = int(commit.sum())
+        member = [{"block": s.bd["index"], "pass": 0 if c else s.bd["t"] + 1}
+                  for s, c in zip(active, commit)]
+        new_pool, (best, conf, counts) = self._run_linked_step(
+            "decode.step", "decode.step_join", active, "decode",
+            {"bucket": bucket, "denoise": n - ncommit, "commit": ncommit,
+             "block": [m["block"] for m in member],
+             "pass": [m["pass"] for m in member]},
+            lambda: fn(pv, bv, pool_ts, tokens, positions, tables, commit,
+                       valid),
+            sweep=True, member_attrs=member)
+        self.pool.tensors = new_pool
+        with self._lock:
+            self._steps_run += 1
+            self._step_slots += bucket
+            self._step_active += n
+            self._bd_forwards += n
+            self._bd_commit_forwards += ncommit
+            self._bd_head_dispatches += ncommit < n
+            self._bd_context_tokens += int(positions[:n].sum()) + n * bl
+            if counts.size:
+                if self._moe_tokens is None:
+                    self._moe_tokens = np.zeros(counts.shape, np.int64)
+                self._moe_tokens += counts
+                self._moe_distinct += int((counts > 0).sum())
+                mean = counts.sum(axis=1) / counts.shape[1]
+                self._moe_load_sum += float(
+                    (counts.max(axis=1) / np.maximum(mean, 1e-30)).sum())
+                self._moe_load_n += counts.shape[0]
+        return commit[:n].astype(bool), best[:n], conf[:n]
+
+    def _bd_advance(self, seq, committed, best, conf):
+        """Take one returned forward into the sequence's block state: a
+        denoising pass fixes the block_length / denoising_steps masked
+        positions of highest confidence (`low_confidence_static`; ties to
+        the lower position, a stable sort); a commit pass has written the
+        block's cache rows, so its generated tokens go to the stream, each
+        with the pass that fixed it, and the next block opens."""
+        st, bl = seq.bd, self._bd["block_length"]
+        if not committed:
+            st["t"] += 1
+            cand = np.flatnonzero(st["masked"])
+            order = np.argsort(-conf[cand].astype(np.float32),
+                               kind="stable")
+            fix = cand[order[:bl // self._bd["denoising_steps"]]]
+            st["tokens"][fix] = best[fix]
+            st["masked"][fix] = False
+            st["passes"][fix] = st["t"]
+            with self._lock:
+                self._bd_tokens_fixed += len(fix)
+            return
+        seq.pos += bl
+        with self._lock:
+            self._bd_blocks_committed += 1
+        self._bd_open_block(seq)
+        for tok, t in zip(st["tokens"][st["given"]:],
+                          st["passes"][st["given"]:]):
+            seq.stream.passes.append(int(t))
+            self._deliver(seq, int(tok))
+            if seq.state == _DONE:      # max_new or EOS inside the block:
+                break                   # its tail is not delivered
 
     def _run_isolated(self, seqs):
         for seq in list(seqs):
@@ -2895,6 +3308,21 @@ class DecodeEngine:
                         if self._spec_verify_dispatches else 0.0,
                 },
             }
+            if self._bd is not None:
+                snap["block_diffusion"] = dict(self._bd)
+                snap.update(
+                    bd_forwards=self._bd_forwards,
+                    bd_commit_forwards=self._bd_commit_forwards,
+                    bd_tokens_fixed=self._bd_tokens_fixed,
+                    bd_blocks_committed=self._bd_blocks_committed,
+                    bd_head_dispatches=self._bd_head_dispatches,
+                    bd_context_tokens=self._bd_context_tokens)
+                if self._moe_tokens is not None:
+                    snap.update(
+                        moe_expert_tokens=self._moe_tokens.tolist(),
+                        moe_distinct_experts=self._moe_distinct,
+                        moe_load_max_over_mean_sum=self._moe_load_sum,
+                        moe_layer_dispatches=self._moe_load_n)
         th = self._h_ttft.snapshot()
         snap["ttft"] = {"count": th["count"], "avg_s": th["avg"],
                         "p50_s": th["p50"], "p99_s": th["p99"]}

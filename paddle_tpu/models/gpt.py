@@ -48,10 +48,21 @@ class GPTConfig:
     layer_norm_epsilon: float = 1e-5
     initializer_range: float = 0.02
     dtype: str = "float32"
+    head_dim: int = 0                # 0 → hidden_size // num_heads
+    qk_norm: bool = False            # RMSNorm over each head of q and of k
+    #                                  before the rotation (Qwen3-class)
+    num_experts: int = 0             # >0 → the MLP is a sparse-expert layer
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0   # width of one expert
+    norm_topk_prob: bool = True      # chosen experts' weights sum to 1
+    block_attention: int = 0         # B > 1 → causal over blocks of B
+    #                                  positions, full inside one
 
     def __post_init__(self):
         if self.num_kv_heads == 0:
             self.num_kv_heads = self.num_heads
+        if self.head_dim == 0:
+            self.head_dim = self.hidden_size // self.num_heads
         if self.intermediate_size == 0:
             if self.swiglu:
                 # LLaMA sizing: 2/3 * 4h rounded to multiple of 128 (lane width)
@@ -59,10 +70,6 @@ class GPTConfig:
                     128 * math.ceil(8 * self.hidden_size / 3 / 128))
             else:
                 self.intermediate_size = 4 * self.hidden_size
-
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_heads
 
 
 # Named configs matching BASELINE.md workloads.
@@ -125,6 +132,10 @@ class GPTAttention(nn.Layer):
                                   weight_attr=_normal_attr(
                                       std / math.sqrt(2 * cfg.num_layers)),
                                   bias_attr=None if bias else False)
+        if cfg.qk_norm:
+            # one learned weight of head_dim, shared by a projection's heads
+            self.q_norm = nn.RMSNorm(hd, epsilon=cfg.layer_norm_epsilon)
+            self.k_norm = nn.RMSNorm(hd, epsilon=cfg.layer_norm_epsilon)
         self.dropout = nn.Dropout(cfg.dropout)
 
     def forward(self, x, position_ids=None, cache=None):
@@ -139,6 +150,8 @@ class GPTAttention(nn.Layer):
         q = ops.reshape(q, [b, s, cfg.num_heads, hd])
         k = ops.reshape(k, [b, s, cfg.num_kv_heads, hd])
         v = ops.reshape(v, [b, s, cfg.num_kv_heads, hd])
+        if cfg.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
         if cfg.rope:
             q, k = F.apply_rotary_pos_emb(q, k, position_ids,
                                           theta=cfg.rope_theta)
@@ -153,16 +166,25 @@ class GPTAttention(nn.Layer):
                 out, nkq, nks, nvq, nvs = apply(
                     "cached_attn_int8", _cached_attn_int8_impl,
                     [q, k, v, kq_c, ks_c, vq_c, vs_c, pos],
-                    {"num_heads": cfg.num_heads})
+                    {"num_heads": cfg.num_heads,
+                     "block": cfg.block_attention})
                 out = ops.reshape(out, [b, s, q_sz])
                 return self.out_proj(out), (nkq, nks, nvq, nvs)
             k_cache, v_cache, pos = cache
             out, new_k, new_v = apply(
                 "cached_attn", _cached_attn_impl,
                 [q, k, v, k_cache, v_cache, pos],
-                {"num_heads": cfg.num_heads})
+                {"num_heads": cfg.num_heads, "block": cfg.block_attention})
             out = ops.reshape(out, [b, s, q_sz])
             return self.out_proj(out), (new_k, new_v)
+        if cfg.block_attention > 1:
+            # no flash kernel takes the block mask: the plain masked core
+            # of the cached path, over this sequence's own keys
+            out = apply("block_attn", _block_attn_impl, [q, k, v],
+                        {"num_heads": cfg.num_heads,
+                         "block": cfg.block_attention})
+            out = ops.reshape(out, [b, s, q_sz])
+            return self.dropout(self.out_proj(out))
         if cfg.num_kv_heads != cfg.num_heads:
             rep = cfg.num_heads // cfg.num_kv_heads
             k = ops.repeat_interleave(k, rep, axis=2)
@@ -174,10 +196,12 @@ class GPTAttention(nn.Layer):
 
 
 def _cached_attn_core(q, kk, vv, pos, num_heads, k_scale=None,
-                      v_scale=None):
+                      v_scale=None, block=0):
     """Shared cached-attention core: GQA repeat, causal mask over global
     positions, softmax, PV. Optional per-(position, head) scales fold into
-    score/prob space (the int8-cache path)."""
+    score/prob space (the int8-cache path). `block` B > 1 makes the mask
+    causal over blocks of B positions and full inside one: query i sees
+    key j when floor(j / B) <= floor(i / B)."""
     import jax
 
     hkv = kk.shape[2]
@@ -195,7 +219,10 @@ def _cached_attn_core(q, kk, vv, pos, num_heads, k_scale=None,
         scores = scores * jnp.transpose(k_scale, (0, 2, 1))[:, :, None, :]
     scores = scores * scale
     q_idx = pos + jnp.arange(s)[:, None]
-    mask = jnp.arange(t)[None, :] <= q_idx  # causal over global positions
+    k_idx = jnp.arange(t)[None, :]
+    if block > 1:
+        q_idx, k_idx = q_idx // block, k_idx // block
+    mask = k_idx <= q_idx  # causal over global positions (or blocks)
     scores = jnp.where(mask[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     if v_scale is not None:   # fold into [B,H,q,T] probs before PV
@@ -219,15 +246,22 @@ def _heads(cache, like):
     return cache.reshape(cache.shape[:2] + like.shape[2:])
 
 
-def _cached_attn_impl(q, k_new, v_new, k_cache, v_cache, pos, *, num_heads):
+def _cached_attn_impl(q, k_new, v_new, k_cache, v_cache, pos, *, num_heads,
+                      block=0):
     """q [B,s,H,D]; k/v_new [B,s,Hkv,D]; caches [B,T,Hkv*D] flat rows; pos
     scalar global offset of this chunk. Returns (out, new_k_cache,
     new_v_cache), the caches flat as they came."""
     k_cache = _write_rows(k_cache, k_new, pos)
     v_cache = _write_rows(v_cache, v_new, pos)
     out = _cached_attn_core(q, _heads(k_cache, k_new), _heads(v_cache, v_new),
-                            pos, num_heads)
+                            pos, num_heads, block=block)
     return out, k_cache, v_cache
+
+
+def _block_attn_impl(q, k, v, *, num_heads, block):
+    """Uncached attention under the block mask: q [B,S,H,D], k/v
+    [B,S,Hkv,D], every query at its own position."""
+    return _cached_attn_core(q, k, v, 0, num_heads, block=block)
 
 
 def _quant_kv(x):
@@ -241,7 +275,7 @@ def _quant_kv(x):
 
 
 def _cached_attn_int8_impl(q, k_new, v_new, kq_c, ks_c, vq_c, vs_c, pos, *,
-                           num_heads):
+                           num_heads, block=0):
     """int8 KV cache decode: caches store int8 values + f32 per-position
     scales ([B,T,Hkv*D] int8 flat rows + [B,T,Hkv] f32 — half the
     decode-loop HBM read of a bf16 cache). New K/V are quantized at write;
@@ -259,7 +293,8 @@ def _cached_attn_int8_impl(q, k_new, v_new, kq_c, ks_c, vq_c, vs_c, pos, *,
     # a bf16 cache, docs/decode_perf.md round-4 addendum).
     out = _cached_attn_core(q, _heads(kq_c, k_new).astype(q.dtype),
                             _heads(vq_c, v_new).astype(q.dtype),
-                            pos, num_heads, k_scale=ks_c, v_scale=vs_c)
+                            pos, num_heads, k_scale=ks_c, v_scale=vs_c,
+                            block=block)
     return out, kq_c, ks_c, vq_c, vs_c
 
 
@@ -300,7 +335,12 @@ class GPTBlock(nn.Layer):
         self.ln_1 = _make_norm(cfg)
         self.attn = GPTAttention(cfg)
         self.ln_2 = _make_norm(cfg)
-        self.mlp = GPTMLP(cfg)
+        if cfg.num_experts:
+            from .moe import SparseExperts
+
+            self.mlp = SparseExperts(cfg)
+        else:
+            self.mlp = GPTMLP(cfg)
 
     def forward(self, x, position_ids=None, cache=None):
         if cache is not None:
@@ -389,6 +429,17 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, position_ids=None):
         return self._project(self.transformer(input_ids, position_ids))
+
+    def decode_signature(self):
+        """What shapes a compiled decode program beyond the parameters'
+        shapes, for the engine's compile-cache key: "" for a configuration
+        that uses none of it (keys as they were), else the values."""
+        cfg = self.cfg
+        if not (cfg.block_attention > 1 or cfg.num_experts or cfg.qk_norm):
+            return ""
+        return (f"block{cfg.block_attention}:top{cfg.num_experts_per_tok}:"
+                f"norm{int(cfg.norm_topk_prob)}:theta{cfg.rope_theta}:"
+                f"eps{cfg.layer_norm_epsilon}")
 
     def _resolve_cache_quant(self, quant):
         """Resolve the KV-cache quantization mode with a documented
@@ -525,6 +576,10 @@ def flops_per_token(cfg: GPTConfig, seq_len: int) -> float:
         + cfg.num_layers * (
             cfg.hidden_size * (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim
             + cfg.num_heads * cfg.head_dim * cfg.hidden_size
-            + cfg.hidden_size * cfg.intermediate_size * (3 if cfg.swiglu else 2)))
+            + (3 * cfg.hidden_size * cfg.moe_intermediate_size
+               * cfg.num_experts_per_tok + cfg.hidden_size * cfg.num_experts
+               if cfg.num_experts else
+               cfg.hidden_size * cfg.intermediate_size
+               * (3 if cfg.swiglu else 2))))
     attn = 12 * cfg.num_layers * cfg.hidden_size * seq_len
     return 6.0 * n_params + attn

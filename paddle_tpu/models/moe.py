@@ -1,0 +1,152 @@
+"""Sparse-expert feed-forward layer of the served decoder (Qwen3-MoE class).
+
+    p = softmax_f32(W_r h)                       over all E experts
+    y = sum_{e in topk(p)} w_e * W_down,e (silu(W_gate,e h) * W_up,e h)
+    w_e = p_e / sum_topk p   (`norm_topk_prob`), else p_e
+
+No token is dropped and no capacity is set: every position gets exactly its
+k experts. The experts are stacked `[E, ...]` (gate and up fused along the
+last axis), and the layer picks one of two exact schedules from its static
+shapes:
+
+* few positions (positions x k <= E, a decode block): one pass over the
+  positions x k assignments, each reading its own expert's weights out of
+  the stack — at most positions x k experts' bytes move, not all E;
+* many positions (a prompt chunk): one pass over the E experts, each
+  applied to every position and weighted 0 where it was not chosen.
+
+`distributed/moe.py::MoELayer` is the GShard capacity-factor training layer
+and drops tokens; this one serves.
+
+The layer also returns how many positions chose each expert (`[E]` int32).
+A caller that wants the counts opens `expert_counts()` around the forward
+and finds one array a layer in the list it yields (the decode engine sums
+them over a dispatch); outside such a block they are dropped.
+"""
+from __future__ import annotations
+
+import contextlib
+import math
+import threading
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..core.dispatch import apply
+
+_TLS = threading.local()
+
+
+@contextlib.contextmanager
+def expert_counts():
+    """Collect every `SparseExperts` forward's per-expert position counts
+    traced inside the block, in layer order."""
+    prev = getattr(_TLS, "counts", None)
+    _TLS.counts = out = []
+    try:
+        yield out
+    finally:
+        _TLS.counts = prev
+
+
+def route(h, router_w, top_k, norm_topk):
+    """(expert ids [T,k], their weights [T,k] float32, counts [E] int32).
+    The softmax runs in float32 over all experts; `lax.top_k` takes the
+    lower index of two equal scores."""
+    logits = jnp.einsum("th,he->te", h.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    counts = jnp.zeros(router_w.shape[-1], jnp.int32).at[
+        idx.reshape(-1)].add(1)
+    return idx, w, counts
+
+
+def _expert(h, gate_up, down):
+    """One expert's SwiGLU on h [T, hidden]: float32 accumulation, the
+    activation in the activations' dtype."""
+    gu = jnp.dot(h, gate_up, preferred_element_type=jnp.float32)
+    gate, up = jnp.split(gu, 2, axis=-1)
+    act = (jax.nn.silu(gate) * up).astype(h.dtype)
+    return jnp.dot(act, down, preferred_element_type=jnp.float32)
+
+
+def _by_assignment(h, idx, w, gate_up, down):
+    """positions x k passes, each reading one expert out of the stack."""
+    t, k = idx.shape
+
+    def body(a, acc):
+        row, e = a // k, idx.reshape(-1)[a]
+        x = jax.lax.dynamic_slice_in_dim(h, row, 1, axis=0)
+        y = _expert(x, gate_up[e], down[e]) * w.reshape(-1)[a]
+        return jax.lax.dynamic_update_slice_in_dim(
+            acc, jax.lax.dynamic_slice_in_dim(acc, row, 1, axis=0) + y,
+            row, axis=0)
+
+    return jax.lax.fori_loop(0, t * k, body,
+                             jnp.zeros(h.shape, jnp.float32))
+
+
+def _by_expert(h, idx, w, gate_up, down):
+    """E passes, each expert over every position, weight 0 where the
+    position did not choose it."""
+    n = gate_up.shape[0]
+    # [T, E]: the chosen experts' weights scattered over all experts
+    dense = jnp.zeros((h.shape[0], n), jnp.float32).at[
+        jnp.arange(h.shape[0])[:, None], idx].set(w)
+
+    def body(acc, x):
+        gu, dn, col = x
+        return acc + _expert(h, gu, dn) * col[:, None], None
+
+    acc, _ = jax.lax.scan(body, jnp.zeros(h.shape, jnp.float32),
+                          (gate_up, down, dense.T))
+    return acc
+
+
+def _sparse_experts_impl(x, router_w, gate_up, down, *, top_k, norm_topk):
+    shape = x.shape
+    h = x.reshape(-1, shape[-1])
+    idx, w, counts = route(h, router_w, top_k, norm_topk)
+    few = h.shape[0] * top_k <= gate_up.shape[0]
+    y = (_by_assignment if few else _by_expert)(h, idx, w, gate_up, down)
+    return y.astype(x.dtype).reshape(shape), counts
+
+
+class SparseExperts(nn.Layer):
+    """`num_experts` SwiGLU experts of width `moe_intermediate_size`,
+    `num_experts_per_tok` a position, no shared expert, no bias."""
+
+    def __init__(self, cfg):
+        super().__init__()
+        h, m, n = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+        if not 1 <= cfg.num_experts_per_tok <= n or m < 1:
+            raise ValueError(
+                f"sparse experts need 1 <= num_experts_per_tok "
+                f"({cfg.num_experts_per_tok}) <= num_experts ({n}) and a "
+                f"moe_intermediate_size ({m})")
+        std = cfg.initializer_range
+        self.top_k = int(cfg.num_experts_per_tok)
+        self.norm_topk = bool(cfg.norm_topk_prob)
+        normal = nn.initializer.Normal
+        self.router = nn.Linear(h, n, bias_attr=False, weight_attr=nn.ParamAttr(
+            initializer=normal(0.0, std)))
+        self.experts_gate_up = self.create_parameter(
+            [n, h, 2 * m], default_initializer=normal(0.0, std))
+        self.experts_down = self.create_parameter(
+            [n, m, h], default_initializer=normal(
+                0.0, std / math.sqrt(2 * cfg.num_layers)))
+
+    def forward(self, x):
+        y, counts = apply(
+            "sparse_experts", _sparse_experts_impl,
+            [x, self.router.weight, self.experts_gate_up, self.experts_down],
+            {"top_k": self.top_k, "norm_topk": self.norm_topk})
+        sink = getattr(_TLS, "counts", None)
+        if sink is not None:
+            sink.append(counts._value)
+        return y

@@ -91,7 +91,25 @@ class Layer:
         if init is None:
             init = Constant(0.0) if is_bias else XavierUniform()
         shape = [int(s) for s in shape]
-        value = init._init(shape, dtypes.convert_dtype(dtype))
+        dtype = dtypes.convert_dtype(dtype)
+        from ...framework_misc import LazyGuard
+
+        if LazyGuard._active:
+            # a lazy binding (core/lazy.py): shape and dtype are there at
+            # once, the initializer runs at the first read of the value,
+            # and a value written before that takes its place unbuilt
+            from ...core.lazy import EngineRef
+
+            p = Parameter(jnp.zeros((), dtype), trainable=trainable,
+                          name=name)
+
+            def materialize():
+                p._v_ = init._init(shape, dtype)
+                return p._v_
+
+            p._v_ = EngineRef(materialize, shape, dtype)
+            return p
+        value = init._init(shape, dtype)
         p = Parameter(value, trainable=trainable, name=name)
         return p
 
