@@ -54,14 +54,16 @@ that already exist in-tree:
   be attended — the same garbage-row argument chunked prefill makes).
   Decode is memory-bound (bandwidth_frac <= 0.53), so verifying K
   tokens under one streaming of the target weights is nearly free
-  throughput. The verify step is a `lax.scan` of the IDENTICAL
-  per-position decode body the plain decode step runs, so the target's
-  argmax at every verified position is bit-identical to sequential
-  greedy decode — which makes speculative output provably BIT-IDENTICAL
-  to `speculate_k=0` at every bucket size, int8 KV and prefix sharing
-  included. Draft and target each own a refcounted `BlockKVCache`
-  (same conservation law; COW rules unchanged), and admission reserves
-  the draft's worst-case blocks alongside the target's.
+  throughput. The verify step is a `lax.scan` over sequences of the
+  per-position decode body, the same equations the plain decode step
+  batches, so the target's argmax at every verified position is that
+  of sequential greedy decode within the determinism contract below
+  (bit-identical on the CPU backend, where tier-1 observes speculative
+  output equal to `speculate_k=0` at every bucket size, int8 KV and
+  prefix sharing included). Draft and target each own a refcounted
+  `BlockKVCache` (same conservation law; COW rules unchanged), and
+  admission reserves the draft's worst-case blocks alongside the
+  target's.
 
 * **Bucketed AOT step executables** (`jit/aot.compile_jit`): the decode
   step is compiled once per batch-size bucket and persisted in the
@@ -99,14 +101,29 @@ that already exist in-tree:
   and the stream gets a block's tokens when it commits, each with the
   pass that fixed it (`SequenceStream.passes`).
 
-Determinism contract: the decode step runs the active batch as a
-`lax.scan` over per-sequence sub-steps (the serving twin of
-`compile_batched`'s `lax.map`), so the per-sequence program is IDENTICAL at
-every bucket size — per-token outputs are bit-identical to running the
-sequence alone. (A row-vectorized step is NOT row-bit-stable through XLA
-CPU matmuls; measured while building this engine.) Decoding is greedy
-(argmax) — the deterministic mode the bit-equality and fault-isolation
-invariants are proven over.
+Determinism contract: the plain decode step runs the active batch through
+ONE batched forward (`_forward_bucket`: the model's per-sequence cached
+step traced once and batched by `vmap`, so a weight crosses HBM once a
+step, not once a sequence). What that promises: a fixed batch composition
+gives the same tokens and the same pool bytes run after run; a sequence's
+rows are written by its own slot and by no other (padded slots write
+reserved block 0); and a sequence's logits agree with the float32
+reference within the tolerance the benchmark enforces (`token_gap` <= 0.1
+where the float8 control reads 0.18-0.40; `benchmarks/limits/`). What it
+no longer promises BY THE PROGRAM'S SHAPE is bit-equality of a sequence's
+tokens across bucket sizes: the per-sequence equations are the same, but
+a backend may sum a batched matmul's rows in another order than the same
+row alone. The tier-1 tests still observe that equality on the CPU
+backend (solo vs batched, int8 KV, prefix sharing), and
+`tests/test_decode_batched_step.py` holds the logits of a row in a bucket
+to a written tolerance of the row alone, which is what remains true on a
+backend that is not row-stable. `_run_isolated` (single-sequence re-runs
+after a failed step) and the speculative verify both work from committed
+state as before; their tokens equal the batched step's by that same
+tolerance, not by construction. The speculative verify / propose steps
+and the block-diffusion step keep their `lax.scan` over per-sequence
+sub-steps (ROADMAP D3). Greedy decoding (argmax) is the deterministic
+mode the equality and fault-isolation invariants are tested over.
 
 Usage::
 
@@ -1101,20 +1118,37 @@ class DecodeEngine:
 
     def _scatter_row(self, pool_ts, new_caches, table, pos):
         """Write the cache row the step produced at `pos` back into the
-        pool (the only row `decode_step` changed)."""
-        import jax
-
+        pool (one sequence of a scanned step)."""
         block = table[pos // self.block_size]
         off = pos % self.block_size
-        out = []
-        for layer_ts, layer_new in zip(pool_ts, new_caches):
-            entry = []
-            for t, c in zip(layer_ts, layer_new):
-                row = jax.lax.dynamic_index_in_dim(c, pos, axis=1,
+        return [tuple(t.at[block, off].set(r.astype(t.dtype))
+                      for t, r in zip(layer_ts, layer_rows))
+                for layer_ts, layer_rows in zip(
+                    pool_ts, self._new_rows(new_caches, pos))]
+
+    @staticmethod
+    def _new_rows(new_caches, pos):
+        """The cache row of every pool tensor that a one-token step wrote
+        at `pos` (the only row `decode_step` changed)."""
+        import jax
+
+        return [tuple(jax.lax.dynamic_index_in_dim(c, pos, axis=1,
                                                    keepdims=False)[0]
-                entry.append(t.at[block, off].set(row.astype(t.dtype)))
-            out.append(tuple(entry))
-        return out
+                      for c in layer) for layer in new_caches]
+
+    def _scatter_new_rows(self, pool_ts, rows, tables, positions):
+        """Write a bucket's new rows (`_new_rows`, stacked `[B, ...]`)
+        into the pool, a tensor by one scatter at `(table[pos // bs],
+        pos % bs)`. Padded slots carry table 0 and position 0: they all
+        land on one row of reserved block 0, the padding sink."""
+        import jax.numpy as jnp
+
+        blocks = jnp.take_along_axis(
+            tables, (positions // self.block_size)[:, None], axis=1)[:, 0]
+        offs = positions % self.block_size
+        return [tuple(t.at[blocks, offs].set(r.astype(t.dtype))
+                      for t, r in zip(layer_ts, layer_rows))
+                for layer_ts, layer_rows in zip(pool_ts, rows)]
 
     def _scatter_rows(self, pool_ts, new_caches, table, pos, n, live):
         """Write the `n` cache rows a block forward produced at `pos` back
@@ -1179,7 +1213,9 @@ class DecodeEngine:
     def _bd_fn(self, bucket):
         """Block-diffusion step for `bucket` sequences: one `[1, B]`
         forward a sequence at its block's offset (the shape of
-        `_verify_fn`), the sequences scanned as in the plain step. A
+        `_verify_fn`), the sequences scanned one after another (the plain
+        step batches them; here a vmapped expert gather would copy each
+        sequence's chosen experts: ROADMAP S8a). A
         sequence's phase is DATA (`commit`): the same program denoises one
         sequence and commits another, and only a commit writes cache rows
         (a denoising pass's rows sink into reserved block 0). Padded slots
@@ -1233,6 +1269,32 @@ class DecodeEngine:
         return self._adapters.stacks() \
             if self._adapters is not None else {}
 
+    def _forward_bucket(self, pv, bv, ats, pool_ts, tokens, positions,
+                        tables, aids):
+        """ONE forward for a bucket of one-token steps (traced): float32
+        logits `[B, vocab]` and the cache rows the step made (`_new_rows`,
+        stacked `[B, ...]`), the pool itself untouched.
+
+        The per-sequence program (the model's one definition of a cached
+        step, `decode_step`) is traced once and batched by `vmap` with
+        the pool, the weights and the adapter stacks closed over: every
+        dense layer sees a `[B, 1, hidden]` activation, so a weight
+        crosses HBM once a step and not once a sequence. Attention stays
+        per sequence in meaning: each row attends to its own cache rows
+        at its own position through its own block table, and the adapter
+        delta gathers each row's own slot (slot 0 = the base model)."""
+        import jax
+        import jax.numpy as jnp
+
+        def one(tok, pos, table, aid):
+            caches = self._gather(pool_ts, table)
+            (logits, new_caches), _ = self._apply(
+                pv, bv, tok.reshape(1, 1), caches, pos, ats, aid)
+            return (logits[0, -1].astype(jnp.float32),
+                    self._new_rows(new_caches, pos))
+
+        return jax.vmap(one)(tokens, positions, tables, aids)
+
     def _decode_fn(self, bucket):
         fn = self._decode_fns.get(bucket)
         if fn is not None:
@@ -1244,31 +1306,20 @@ class DecodeEngine:
 
         def step(pv, bv, ats, pool_ts, tokens, positions, tables,
                  aids, hist, samp):
-            def body(pool_ts, x):
-                tok, pos, table, aid, hrow, srow = x
-                caches = self._gather(pool_ts, table)
-                (logits, new_caches), _ = self._apply(
-                    pv, bv, tok.reshape(1, 1), caches, pos, ats, aid)
-                # greedy rows (`srow["greedy"] == 1`) select the raw-
-                # logits argmax behind a where — bit-identical to the
-                # pre-sampling engine; sampled rows draw from the
-                # counter-keyed per-sequence RNG
-                nxt = sample_token(
-                    logits[0, -1].astype(jnp.float32), srow, hrow)
-                pool_ts = self._scatter_row(pool_ts, new_caches, table, pos)
-                return pool_ts, nxt
-            # scan over the batch: each sequence runs the IDENTICAL
-            # per-sequence program at every bucket size (bit-identical to
-            # running alone — compile_batched's lax.map argument), writes
-            # land in its own blocks (padded rows in reserved block 0),
-            # and the whole bucket is ONE gathered XLA dispatch. The
-            # adapter delta gathers each sequence's own slot (slot 0 =
-            # base model, selected back bitwise), so a mixed-tenant
-            # mixed-sampling batch is still this one executable.
-            pool_ts, nxt = jax.lax.scan(
-                body, pool_ts,
-                (tokens, positions, tables, aids, hist, samp))
-            return pool_ts, nxt
+            logits, rows = self._forward_bucket(
+                pv, bv, ats, pool_ts, tokens, positions, tables, aids)
+            # greedy rows (`samp["greedy"] == 1`) select the raw-logits
+            # argmax behind a where; sampled rows draw from the counter-
+            # keyed per-sequence RNG. Row by row, not under `vmap`: the
+            # session's `rbg` keys draw other bits when batched, and a
+            # sequence's stream may not depend on its slot or its
+            # batchmates (a resumed or isolated sequence redraws it). A
+            # mixed-tenant mixed-sampling batch is still this one
+            # executable.
+            nxt = jax.lax.map(lambda row: sample_token(*row),
+                              (logits, samp, hist))
+            return self._scatter_new_rows(
+                pool_ts, rows, tables, positions), nxt
 
         pv, bv = self._weight_avals()
         ats_avals = self._adapter_avals()
@@ -1290,10 +1341,17 @@ class DecodeEngine:
             in_sh = (pv_sh, bv_sh, ats_sh, pool_sh, repl, repl, repl,
                      repl, repl, samp_sh)
             out_sh = (pool_sh, repl)
+        # `extra_key` names THIS program in its executables' cache keys:
+        # `compile_jit` keys on (tag, fingerprint, avals), not on the
+        # program's text, and the fingerprint's own version string also
+        # keys the prefill, COW and block-diffusion executables, which
+        # did not change when this step became one batched forward.
+        # Bump it whenever this program's text changes
         compiled, source = aot.compile_jit(
             step, avals, fingerprint=self._fingerprint, cache=self._cache,
             tag=f"decode-step-b{bucket}", in_shardings=in_sh,
-            out_shardings=out_sh, audit_ctx=self._audit_ctx(pv))
+            out_shardings=out_sh, audit_ctx=self._audit_ctx(pv),
+            extra_key="batched-forward-v2")
         self._note_compile(source)
         self._decode_fns[bucket] = compiled
         return compiled
@@ -2468,7 +2526,7 @@ class DecodeEngine:
             # (b) all K+1 verify rows fit the normal block table — near
             # max_length (at most the last K tokens) it falls back to
             # plain steps, keeping the verify gather width identical to
-            # the decode step's (the bit-exactness invariant)
+            # the decode step's (the same dense view, the same sums)
             limit = self._nb * self.block_size
             spec = [s for s in active
                     if s.max_new - s.generated > 1
@@ -2793,8 +2851,8 @@ class DecodeEngine:
     #   2. propose          — ONE draft dispatch: K autoregressive tokens
     #                         per sequence into the draft pool
     #   3. verify           — ONE target dispatch: K+1 positions scored
-    #                         per sequence (bit-identical per-position
-    #                         program to the plain decode step)
+    #                         per sequence (the per-position equations
+    #                         the plain decode step batches)
     #   4. commit/rollback  — greedy acceptance: longest draft prefix
     #                         matching the target argmax + the target's
     #                         correction/bonus token committed; rejected
@@ -2802,8 +2860,9 @@ class DecodeEngine:
     #                         pools' rows past the committed position are
     #                         rewritten before they can ever be attended)
     # A failed shared propose/verify dispatch falls back to plain
-    # isolated decode from committed state — survivors stay bit-exact and
-    # no uncommitted token is ever delivered.
+    # isolated decode from committed state — survivors lose nothing (their
+    # tokens the batched step's within the module's determinism contract)
+    # and no uncommitted token is ever delivered.
 
     def _committed_tokens(self, seq):
         """Every committed token (prompt + generated), index == cache
@@ -3031,7 +3090,7 @@ class DecodeEngine:
             # the fault may be speculation-specific): fall back to plain
             # ISOLATED decode from the committed state. No uncommitted
             # token was delivered, the draft rolls back positionally
-            # (draft_pos is untouched), and survivors stay bit-exact —
+            # (draft_pos is untouched), and survivors lose nothing —
             # a genuinely-poisoned sequence then fails alone in its own
             # single-sequence dispatch.
             with self._lock:

@@ -10,6 +10,7 @@ Two proofs: the compiled TPU programs hold no relayout of the pool
 (compile-only, against a described `v5e:2x2`, nothing runs), and on the CPU
 the engine's tokens and the rows it wrote are the parent's bit for bit.
 """
+import contextlib
 import hashlib
 import os
 import re
@@ -112,9 +113,10 @@ def _pool_reading(compiled, tensors):
     return reading, compiled.memory_analysis().temp_size_in_bytes
 
 
-@pytest.mark.parametrize("quant", [None, "int8"], ids=["bf16", "int8"])
-def test_step_programs_hold_no_relayout_of_the_pool(
-        quant, one_chip, no_jax_cache, monkeypatch, record_property):
+@contextlib.contextmanager
+def _engine_compiled_for_chip(quant, one_chip, monkeypatch):
+    """An engine over a `WIDE` bf16 model whose step programs compile for
+    the described chip: yields (engine, {tag: compiled})."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.jit import aot
@@ -141,7 +143,17 @@ def test_step_programs_hold_no_relayout_of_the_pool(
                        prefill_buckets=(PREFILL_BUCKET,), prefill_chunk=0,
                        default_timeout=60.0)
     try:
-        cfg = net.cfg
+        yield eng, built
+    finally:
+        eng.shutdown(drain_timeout=10.0)
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["bf16", "int8"])
+def test_step_programs_hold_no_relayout_of_the_pool(
+        quant, one_chip, no_jax_cache, monkeypatch, record_property):
+    with _engine_compiled_for_chip(quant, one_chip, monkeypatch) as (
+            eng, built):
+        cfg = eng.model.cfg
         rows = cfg.num_kv_heads * cfg.head_dim
         assert cfg.head_dim == 64 and rows >= 128
         layer = eng.pool.tensors[0]
@@ -171,8 +183,67 @@ def test_step_programs_hold_no_relayout_of_the_pool(
             assert len(got["layouts"]) == 1, (tag, got)
             assert got["inner_copies"] == 0, (tag, got)
             assert temp < pool_bytes, (tag, temp, pool_bytes)
-    finally:
-        eng.shutdown(drain_timeout=10.0)
+
+
+def _reached_from_loops(comps):
+    """The computations a `while` runs: its body and condition, and
+    whatever those call (fusions, nested loops, branches)."""
+    called = re.compile(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)"
+                        r"|branch_computations=\{([^}]*)\}")
+
+    def callees(line):
+        for one, many in called.findall(line):
+            yield from ([one] if one else re.findall(r"%([\w.\-]+)", many))
+
+    todo = [c for lines in comps.values() for line in lines
+            if " while(" in line
+            for c in re.findall(r"(?:body|condition)=%([\w.\-]+)", line)]
+    reached = set()
+    while todo:
+        name = todo.pop()
+        if name not in reached:
+            reached.add(name)
+            todo += [c for line in comps[name] for c in callees(line)]
+    return reached
+
+
+def test_decode_step_reads_every_weight_once_outside_any_loop(
+        one_chip, no_jax_cache, monkeypatch, record_property):
+    """The plain decode step is one batched forward (ISSUE 29): a weight
+    is an operand of instructions of the entry computation and of nothing
+    a `while` runs. Under the scan of batch-1 forwards the weights were
+    carried into the loop and read once a sequence. On the chip the dense
+    layers are fusions, not `dot`s: shapes are matched, not opcodes."""
+    bucket = DECODE_BUCKETS[-1]
+    with _engine_compiled_for_chip(None, one_chip, monkeypatch) as (
+            eng, built):
+        eng._decode_fn(bucket)
+        compiled = built[f"decode-step-b{bucket}"]
+        weights = {n: tuple(p.shape) for n, p in eng._params.items()
+                   if n.endswith(("qkv_proj.weight", "out_proj.weight",
+                                  "up_proj.weight", "down_proj.weight",
+                                  "wte.weight"))}
+    assert len({n.rsplit(".", 2)[-2] for n in weights}) == 5, weights
+    comps, entry = _computations(compiled.as_text())
+    loops = _reached_from_loops(comps)
+    assert entry not in loops
+    record_property(f"decode-step-b{bucket}.temp_bytes",
+                    compiled.memory_analysis().temp_size_in_bytes)
+    record_property(f"decode-step-b{bucket}.loop_computations", len(loops))
+    for shape in sorted(set(weights.values())):
+        ty = f"bf16[{','.join(map(str, shape))}]{{"
+        in_loops = [(name, line.strip()[:160]) for name in loops
+                    for line in comps[name] if ty in line]
+        assert not in_loops, (shape, in_loops[:3])
+        params = [re.match(r"\s*%([\w.\-]+) = ", line).group(1)
+                  for line in comps[entry]
+                  if ty in line and " parameter(" in line]
+        assert params, shape
+        for name in params:
+            used = re.compile(r"[(, ]%" + re.escape(name) + r"[,)]")
+            readers = [line for line in comps[entry]
+                       if used.search(line.split(" = ", 1)[-1])]
+            assert readers, (shape, name)
 
 
 # ---------------------------------------------------------------------------
