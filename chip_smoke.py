@@ -94,7 +94,7 @@ def _custom_call_kernels(lowered_text):
 def trainer_phase(report, counter, *, model="gpt_base", batch=16,
                   seq_len=1024, fused=5, mesh=None, expect_flash=True,
                   label="trainer"):
-    """bench.py::bench_gpt's path: one step, then two fused dispatches.
+    """The flagship trainer's path: one step, then two fused dispatches.
     Returns the loss trajectory and timings."""
     import jax
     import paddle_tpu as paddle

@@ -488,8 +488,8 @@ class ShardedTrainStep:
         """(avals, specs) of the engine's full declared state — params
         plus optimizer slots (keyed ``opt/<param>/<slot>``, sharded like
         their param). The one enumeration behind both the graphcheck
-        ``<site>::params`` per-chip watermark and the BENCH_POD state
-        gate (`graphcheck.params_bytes_per_chip`)."""
+        ``<site>::params`` per-chip watermark and the fsdp state-shrink
+        test (`graphcheck.params_bytes_per_chip`)."""
         avals = {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
                  for n, v in self.param_vals.items()}
         specs = dict(self.param_specs)

@@ -85,8 +85,6 @@ define_flag("check_nan_inf", False,
             "scan op outputs for NaN/Inf in eager dispatch")
 define_flag("check_nan_inf_level", 0, "0 raise, 1 warn")
 define_flag("eager_delete_tensor_gb", 0.0, "kept for parity; XLA owns GC")
-define_flag("use_pallas_attention", True,
-            "use the Pallas flash kernel when shapes allow")
 define_flag("benchmark", False, "per-step timing logs")
 define_flag("allocator_strategy", "auto_growth", "parity; XLA allocates")
 define_flag("cudnn_deterministic", False, "parity alias: deterministic ops")

@@ -75,7 +75,7 @@ def cache_dir():
 
 def enable_compile_cache():
     """Turn on jax's persistent compilation cache at `compile_cache_root()`
-    and return the root. Entry points (bench.py, chip_smoke.py) call this
+    and return the root. Entry points (benchmarks/run.py, chip_smoke.py) call this
     once before their first compile. With `$JAX_COMPILATION_CACHE_DIR` set
     jax has already pointed itself there and nothing is set in code."""
     root = compile_cache_root()

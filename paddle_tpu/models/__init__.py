@@ -2,7 +2,7 @@
 named in BASELINE.md)."""
 from .gpt import (  # noqa: F401
     GPTConfig, GPTModel, GPTForCausalLM, gpt, CONFIGS as GPT_CONFIGS,
-    flops_per_token, CacheQuantError,
+    CacheQuantError,
 )
 from .resnet import (  # noqa: F401
     ResNet, BasicBlock, BottleneckBlock,

@@ -567,19 +567,3 @@ def gpt(name="gpt_base", **overrides):
     d.update(overrides)
     return GPTForCausalLM(GPTConfig(**d))
 
-
-def flops_per_token(cfg: GPTConfig, seq_len: int) -> float:
-    """Approximate training FLOPs/token (fwd+bwd ≈ 6*N + attention term) for
-    MFU accounting (BASELINE.md north-star)."""
-    n_params = (
-        cfg.vocab_size * cfg.hidden_size * (1 if cfg.tie_word_embeddings else 2)
-        + cfg.num_layers * (
-            cfg.hidden_size * (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim
-            + cfg.num_heads * cfg.head_dim * cfg.hidden_size
-            + (3 * cfg.hidden_size * cfg.moe_intermediate_size
-               * cfg.num_experts_per_tok + cfg.hidden_size * cfg.num_experts
-               if cfg.num_experts else
-               cfg.hidden_size * cfg.intermediate_size
-               * (3 if cfg.swiglu else 2))))
-    attn = 12 * cfg.num_layers * cfg.hidden_size * seq_len
-    return 6.0 * n_params + attn

@@ -3,26 +3,12 @@ BASELINE.md config 1: ResNet-50 ImageNet).
 
 TPU notes: NCHW inputs for API parity with the reference (XLA on TPU
 re-layouts convs internally); BatchNorm stats update only in train mode.
-Opt-in (PADDLE_TPU_FUSED_RESBLOCK=1): on TPU + NHWC + bf16, stride-1
-identity bottleneck blocks route through the fused Pallas kernel family
-(ops/pallas/fused_resblock.py — the analog of the reference's
-fused_scale_bias_relu_conv_bn CUDA kernel), which keeps the conv+BN+relu
-chain VMEM-resident instead of streaming every link through HBM. Measured
-slower than XLA's per-op path in-model, so DISABLED by default — see the
-round-4 section of docs/resnet50_roofline.md for the full measurement
-record. =force enables off-TPU (interpret mode, tests only).
 """
 from __future__ import annotations
-
-import os
 
 from .. import nn
 from .. import ops
 from ..nn import functional as F
-
-
-def _fused_blocks_mode():
-    return os.environ.get("PADDLE_TPU_FUSED_RESBLOCK", "0")
 
 
 class BottleneckBlock(nn.Layer):
@@ -48,50 +34,7 @@ class BottleneckBlock(nn.Layer):
         self.downsample = downsample
         self.stride = stride
 
-    def _can_fuse(self, x=None):
-        # every BN must itself be in batch-stats training mode: frozen-BN
-        # fine-tuning (bn.eval() / use_global_stats) takes the unfused path
-        for bn in (self.bn1, self.bn2, self.bn3):
-            if not bn.training or bn._use_global_stats:
-                return False
-        if not (self.training and self.downsample is None
-                and self.stride == 1 and self._data_format == "NHWC"
-                and self.conv2._groups == 1
-                and self.conv2._dilation == (1, 1)):
-            return False
-        mode = _fused_blocks_mode()
-        if mode == "0":
-            return False
-        if mode == "force":
-            return True
-        # the kernels run bf16 MXU math; fusing an f32 model would silently
-        # change its numerics, so require bf16 inputs outside force mode
-        if x is not None and str(x.dtype) not in ("bfloat16",
-                                                  "paddle.bfloat16"):
-            return False
-        import jax
-        return jax.default_backend() == "tpu"
-
-    def _forward_fused(self, x):
-        from ..ops.pallas.fused_resblock import fused_block_impl
-        from ..ops._helpers import apply
-        y, mu1, v1, mu2, v2, mu3, v3 = apply(
-            "fused_bottleneck", fused_block_impl,
-            (x, self.conv1.weight, self.conv2.weight, self.conv3.weight,
-             self.bn1.weight, self.bn1.bias, self.bn2.weight, self.bn2.bias,
-             self.bn3.weight, self.bn3.bias),
-            {"eps": float(self.bn1._epsilon)})
-        from ..nn.functional.norm import update_running_stats
-        n = x.size // x.shape[-1]
-        for bn, mean, var in ((self.bn1, mu1, v1), (self.bn2, mu2, v2),
-                              (self.bn3, mu3, v3)):
-            update_running_stats(bn._mean, bn._variance, mean, var,
-                                 bn._momentum, n)
-        return y
-
     def forward(self, x):
-        if self._can_fuse(x):
-            return self._forward_fused(x)
         identity = x
         out = self.relu(self.bn1(self.conv1(x)))
         out = self.relu(self.bn2(self.conv2(out)))

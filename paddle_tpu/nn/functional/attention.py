@@ -83,19 +83,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                      {"mesh": mesh, "mode": mode, "seq_axis": seq_axis,
                       "causal": bool(is_causal)})
     if dropout_p > 0.0 and training:
-        # dropout inside attention probs: fused Pallas kernel with
-        # in-kernel PRNG at short seq on TPU (BASELINE config 2's hot
-        # path); composed implementation otherwise
-        if _short_attn_ok(q, attn_mask, dropout_p):
-            from ...ops import random as rnd
-            kd = rnd.next_key()
-            if jnp.issubdtype(kd.dtype, jax.dtypes.prng_key):
-                kd = jax.random.key_data(kd)
-            seed = jax.lax.convert_element_type(
-                jnp.ravel(kd)[:1], jnp.int32)
-            return apply("sdpa_short", _sdpa_short_impl,
-                         (q, k, v, Tensor(seed)),
-                         {"p": float(dropout_p), "causal": bool(is_causal)})
         return _sdpa_dropout(q, k, v, attn_mask, dropout_p, is_causal)
     if attn_mask is not None:
         return apply("sdpa_mask", _sdpa_mask_impl, (q, k, v, wrap(attn_mask)),
@@ -103,23 +90,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return apply("sdpa", _sdpa_impl, (q, k, v),
                  {"causal": bool(is_causal), "scale": None,
                   "mesh": cp[0] if cp is not None else None})
-
-
-_SHORT_ATTN = os.environ.get("PADDLE_TPU_SHORT_ATTENTION", "0") != "0"
-
-
-def _short_attn_ok(q, attn_mask, p):
-    if not _SHORT_ATTN or attn_mask is not None or q.ndim != 4:
-        return False
-    from ...ops.pallas import short_attention as sa
-    # in-kernel PRNG needs real TPU (no interpret-mode lowering)
-    return (jax.default_backend() == "tpu" and sa.supports_p(p)
-            and sa.supported(tuple(q.shape), attn_mask, None))
-
-
-def _sdpa_short_impl(q, k, v, seed, *, p, causal):
-    from ...ops.pallas.short_attention import short_attention
-    return short_attention(q, k, v, seed, p, causal)
 
 
 def _sdpa_dropout(q, k, v, attn_mask, dropout_p, is_causal):

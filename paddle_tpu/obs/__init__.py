@@ -5,8 +5,8 @@ item 5): a process-wide metrics registry (`Counter` / `Gauge` /
 `Histogram` with fixed log-spaced buckets → p50/p95/p99 without
 per-sample storage), exporters (`snapshot()` nested JSON,
 `prometheus_text()` exposition, the opt-in `MetricsServer` HTTP
-endpoint with ``/metrics`` + ``/healthz``), and the SLO regression gate
-(`obs.slo` + ``SLO_BASELINE.json`` + ``BENCH_SLO=1 python bench.py``).
+endpoint with ``/metrics`` + ``/healthz``), and declared objectives with
+their gate (`obs.slo`: `Objective`, `evaluate`, `write_baseline`).
 
 Instrumented out of the box (each registers its existing `stats()` dict
 as a collector — single source of truth, no duplicated bookkeeping):

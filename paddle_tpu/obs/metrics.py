@@ -3,7 +3,7 @@
 The framework's production pieces each kept private counters
 (`ServingPool.stats()`, `ServingRouter.stats()`, `DecodeEngine.stats()`,
 `engine.stats` dispatch counts...). This module is the ONE surface an
-operator — or the bench SLO ratchet — watches:
+operator — or the router's SLO autoscaler — watches:
 
 * **`Counter` / `Gauge` / `Histogram`** — standalone metric objects. The
   histogram uses FIXED log-spaced buckets, so p50/p95/p99 come from ~30
@@ -191,8 +191,8 @@ class Histogram(_Metric):
     def counts(self):
         """Copy of the per-bucket counts (last entry = overflow). With
         `quantile(q, counts=...)` this supports windowed quantiles: diff
-        two counts() snapshots and quantile the delta (the SLO bench
-        excludes its warm-up this way)."""
+        two counts() snapshots and quantile the delta (the router's
+        SLO autoscaler windows its p99s this way)."""
         return list(self._counts)
 
     def quantile(self, q, counts=None):

@@ -1,12 +1,11 @@
-"""paddle_tpu.obs.slo — declared service-level objectives + regression gate.
+"""paddle_tpu.obs.slo — declared service-level objectives + their gate.
 
-The correctness suites already fail a PR that breaks an invariant; this
-module makes PERF regressions fail the same way (ROADMAP open item 5).
-The pattern is the tracelint baseline ratchet (PR 5): a checked-in
-``SLO_BASELINE.json`` freezes the bounds, ``BENCH_SLO=1 python bench.py``
-measures the declared objectives on the CPU serving smoke and exits
-nonzero on any breach, and an intentional perf change re-writes the
-baseline (``BENCH_SLO_WRITE=1``) in the same PR that explains it.
+An objective is a named number with a direction; a baseline mapping (or
+a file written by `write_baseline`) holds the bound each one is held
+to; `evaluate` says which are breached. `ServingRouter`'s autoscaler
+evaluates its windowed p99s against the configured ceilings through
+this module. Where a baseline file is checked in, an intentional change
+re-writes it (`write_baseline`) in the same PR that explains it.
 
 An `Objective` names ONE number and its direction:
 
@@ -27,11 +26,8 @@ from __future__ import annotations
 import json
 import os
 
-__all__ = ["Objective", "SERVING_SMOKE", "ROUTER_STREAM", "evaluate",
-           "load_baseline", "write_baseline", "format_report",
-           "BASELINE_FILENAME"]
-
-BASELINE_FILENAME = "SLO_BASELINE.json"
+__all__ = ["Objective", "evaluate", "load_baseline", "write_baseline",
+           "format_report"]
 
 
 class Objective:
@@ -64,52 +60,13 @@ class Objective:
                 f"unit={self.unit!r}, slack={self.slack})")
 
 
-#: The CPU serving-smoke objectives bench.py's BENCH_SLO=1 section
-#: measures (docs/observability.md documents each knob). TPU-measured
-#: objectives ride the same machinery with their own baseline entries.
-SERVING_SMOKE = [
-    Objective("serving_smoke.p99_latency_s", "max",
-              description="p99 end-to-end request latency (admission -> "
-                          "completion) of the batched CPU serving smoke "
-                          "at its measured concurrency, read from the "
-                          "serving.request_seconds histogram",
-              unit="s", slack=5.0),
-    Objective("serving_smoke.throughput_rps", "min",
-              description="completed requests/sec of the same run",
-              unit="req/s", slack=4.0),
-    Objective("serving_smoke.queue_depth_peak", "max",
-              description="peak admission-queue depth during the run "
-                          "(pool stats queue_depth_peak) — a scheduling "
-                          "regression shows up here before latency does",
-              unit="requests", slack=3.0),
-    Objective("train_smoke.steps_per_sec", "min",
-              description="optimizer steps/sec of a tiny CPU training "
-                          "loop through Engine.train_batch (dispatch "
-                          "overhead floor)",
-              unit="steps/s", slack=5.0),
-]
-
-#: Streaming-through-the-HA-tier objectives: bench.py's BENCH_SLO=1
-#: section also drives generations through a ServingRouter over stub
-#: decode replicas (no XLA in the loop), so this bound gates the
-#: ROUTER's streaming overhead — affinity placement, admission, pump
-#: delivery of the first frame — not model compute.
-ROUTER_STREAM = [
-    Objective("router_stream.ttft_p99_s", "max",
-              description="p99 time-to-first-token of streams routed "
-                          "through a ServingRouter over stub decode "
-                          "replicas (fed to router.ttft_seconds)",
-              unit="s", slack=4.0),
-]
-
-
 def load_baseline(path):
     """Read a baseline file -> {objective_name: {"kind", "bound", ...}}.
-    Raises FileNotFoundError with the ratchet workflow in the message."""
+    Raises FileNotFoundError naming the function that writes one."""
     if not os.path.exists(path):
         raise FileNotFoundError(
-            f"SLO baseline {path!r} not found — run with BENCH_SLO_WRITE=1 "
-            f"to measure and write one, then check it in")
+            f"SLO baseline {path!r} not found — measure the objectives, "
+            f"call write_baseline() to write one, then check it in")
     with open(path) as f:
         data = json.load(f)
     return data.get("objectives", {})
@@ -119,8 +76,7 @@ def write_baseline(path, values, objectives, note="", merge=None):
     """Ratchet: freeze bounds from `values` (objective name -> measured
     float) with each objective's slack applied. Returns the written
     mapping. `merge` (a mapping from `load_baseline`) carries over
-    existing rows for objectives not being re-ratcheted — e.g. the conv
-    bench gate ratchets one platform's rows at a time."""
+    existing rows for objectives not being re-ratcheted."""
     objs = dict(merge) if merge else {}
     for obj in objectives:
         if obj.name not in values:
@@ -141,7 +97,7 @@ def write_baseline(path, values, objectives, note="", merge=None):
     return objs
 
 
-def evaluate(values, baseline, objectives=None):
+def evaluate(values, baseline, objectives):
     """Gate `values` (objective name -> measured float) against the
     `baseline` mapping from `load_baseline`. Every declared objective
     must have BOTH a measurement and a baseline bound; a missing side is
@@ -150,7 +106,6 @@ def evaluate(values, baseline, objectives=None):
         {"ok": bool, "results": [{name, kind, value, bound, ok,
                                   reason?}, ...], "breaches": [name...]}
     """
-    objectives = SERVING_SMOKE if objectives is None else objectives
     results = []
     for obj in objectives:
         entry = baseline.get(obj.name)
@@ -163,8 +118,8 @@ def evaluate(values, baseline, objectives=None):
                        reason="objective declared but not measured")
         elif entry is None or entry.get("bound") is None:
             row.update(ok=False,
-                       reason="no baseline bound (BENCH_SLO_WRITE=1 to "
-                              "ratchet one)")
+                       reason="no baseline bound (write_baseline() "
+                              "ratchets one)")
         elif entry.get("kind", obj.kind) != obj.kind:
             row.update(ok=False,
                        reason=f"baseline kind {entry.get('kind')!r} != "

@@ -82,8 +82,7 @@ def test_detection_loss_decreases_and_postprocess_localizes():
 
 def test_ppyoloe_layout_parity():
     """NHWC (MXU-native conv layout) must reproduce the NCHW loss exactly
-    given the same weights — the bench's channels-last option relies on it
-    (bench.py config 3)."""
+    given the same weights — `data_format="NHWC"` relies on it."""
     import paddle_tpu as paddle
     from paddle_tpu.vision.models import PPYOLOE
 

@@ -67,6 +67,62 @@ def test_resnet18_train_eval():
     np.testing.assert_allclose(rm2, m.bn1._buffers["_mean"].numpy())
 
 
+@pytest.mark.parametrize("data_format", ["NCHW", "NHWC"])
+def test_bottleneck_block_trains(data_format):
+    """The bottleneck block's one forward (conv-BN-ReLU x 3 + identity)
+    trains: every weight gets a gradient, every BN moves its running
+    mean in train mode and leaves it in eval mode, and NHWC reproduces
+    NCHW on the same weights."""
+    from paddle_tpu.models.resnet import BottleneckBlock
+
+    paddle.seed(5)
+    blk = BottleneckBlock(16, 4, data_format=data_format)
+    x = np.random.RandomState(5).randn(2, 16, 6, 6).astype("float32")
+    nhwc = data_format == "NHWC"
+    inp = paddle.to_tensor(x.transpose(0, 2, 3, 1) if nhwc else x)
+    before = [bn._buffers["_mean"].numpy().copy()
+              for bn in (blk.bn1, blk.bn2, blk.bn3)]
+    y = blk(inp)
+    assert y.shape == list(inp.shape)
+    (y * y).mean().backward()
+    for name, p in blk.named_parameters():
+        assert p.grad is not None, name
+    for bn, was in zip((blk.bn1, blk.bn2, blk.bn3), before):
+        assert not np.allclose(was, bn._buffers["_mean"].numpy())
+    blk.eval()
+    kept = blk.bn3._buffers["_mean"].numpy().copy()
+    out = blk(inp).numpy()
+    np.testing.assert_allclose(kept, blk.bn3._buffers["_mean"].numpy())
+    if nhwc:
+        ref = BottleneckBlock(16, 4)
+        ref.set_state_dict(blk.state_dict())
+        ref.eval()
+        np.testing.assert_allclose(
+            out, ref(paddle.to_tensor(x)).numpy().transpose(0, 2, 3, 1),
+            rtol=1e-4, atol=1e-5)
+
+
+def test_sdpa_attention_dropout_path():
+    """Dropout on attention probabilities is the composed path alone:
+    it bites in training, a seed repeats it, and `training=False` or
+    `dropout_p=0` is the plain attention."""
+    rng = np.random.RandomState(6)
+    q, k, v = (paddle.to_tensor(rng.randn(2, 8, 2, 16).astype("float32"))
+               for _ in range(3))
+    plain = F.scaled_dot_product_attention(q, k, v, is_causal=True).numpy()
+    paddle.seed(11)
+    a = F.scaled_dot_product_attention(q, k, v, dropout_p=0.5,
+                                       is_causal=True).numpy()
+    paddle.seed(11)
+    b = F.scaled_dot_product_attention(q, k, v, dropout_p=0.5,
+                                       is_causal=True).numpy()
+    np.testing.assert_array_equal(a, b)
+    assert not np.allclose(a, plain, atol=1e-3)
+    off = F.scaled_dot_product_attention(q, k, v, dropout_p=0.5,
+                                         is_causal=True, training=False)
+    np.testing.assert_allclose(off.numpy(), plain, rtol=1e-5, atol=1e-6)
+
+
 def test_rope_rotation_property():
     # rotating by position p then attending is equivalent to relative shift:
     # check norm preservation (rotation is orthogonal)

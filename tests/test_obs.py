@@ -2,12 +2,10 @@
 
 Kept cheap on purpose (ROADMAP suite-budget caveat): stub predictors
 (no XLA programs), a private registry per test (no cross-test state),
-one tiny Engine build for the collector bridge, and the BENCH_SLO
-end-to-end subprocess slow-marked.
+one tiny Engine build for the collector bridge.
 """
 import gc
 import json
-import sys
 import time
 import urllib.error
 import urllib.request
@@ -513,7 +511,7 @@ def test_slo_write_and_load_baseline(tmp_path):
     assert loaded == written
     rep = slo.evaluate({"a.lat": 0.39, "a.rps": 101.0}, loaded, objs)
     assert rep["ok"]
-    with pytest.raises(FileNotFoundError, match="BENCH_SLO_WRITE"):
+    with pytest.raises(FileNotFoundError, match=r"write_baseline\(\)"):
         slo.load_baseline(str(tmp_path / "missing.json"))
     with pytest.raises(ValueError):
         slo.Objective("bad", "between")
@@ -521,21 +519,8 @@ def test_slo_write_and_load_baseline(tmp_path):
         slo.Objective("bad", "max", slack=0.5)
 
 
-def test_checked_in_baseline_covers_declared_objectives():
-    import os
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), slo.BASELINE_FILENAME)
-    baseline = slo.load_baseline(path)
-    for obj in slo.SERVING_SMOKE + slo.ROUTER_STREAM:
-        assert obj.name in baseline, (
-            f"declared objective {obj.name} has no checked-in bound — "
-            f"run BENCH_SLO_WRITE=1 python bench.py and commit")
-        assert baseline[obj.name]["kind"] == obj.kind
-
-
 # ---------------------------------------------------------------------------
-# CLI + end-to-end
+# CLI
 # ---------------------------------------------------------------------------
 
 def test_metrics_dump_cli_scrape_modes(capsys):
@@ -616,21 +601,3 @@ def test_sharding_mesh_collector_snapshot():
     assert snap["param_shard_fractions"]["w"] == 0.125
     assert snap["params_sharded"] == 1
     reg.unregister_collector(key)
-
-
-@pytest.mark.slow
-def test_bench_slo_gate_end_to_end():
-    """BENCH_SLO=1 python bench.py evaluates the declared SLOs against
-    the checked-in baseline, scrapes the live endpoint, and exits 0."""
-    import os
-    import subprocess
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_SLO="1", JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        cwd=repo, env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["vs_baseline"] == 1.0
-    assert "SLO gate: PASS" in proc.stderr
