@@ -94,9 +94,10 @@ that already exist in-tree:
   blocks, writes no cache, and fixes the B/T masked positions whose
   arg-max token is most confident; once all are fixed one COMMIT pass
   forwards the block again and writes its B cache rows. A decode dispatch
-  is then one `[1, B]` forward a sequence at its block's offset, each
-  sequence in its own phase — the phase is DATA (one executable a bucket,
-  the cache write selected by it), the block's state lives with the
+  is then one `[1, B]` forward a sequence at its block's offset, the
+  bucket's forwards batched into one (`_bd_fn`), each sequence in its own
+  phase — the phase is DATA (one executable a bucket, the cache write
+  selected by it), the block's state lives with the
   sequence between rounds and changes only after a dispatch has returned,
   and the stream gets a block's tokens when it commits, each with the
   pass that fixed it (`SequenceStream.passes`).
@@ -120,10 +121,23 @@ to a written tolerance of the row alone, which is what remains true on a
 backend that is not row-stable. `_run_isolated` (single-sequence re-runs
 after a failed step) and the speculative verify both work from committed
 state as before; their tokens equal the batched step's by that same
-tolerance, not by construction. The speculative verify / propose steps
-and the block-diffusion step keep their `lax.scan` over per-sequence
-sub-steps (ROADMAP D3). Greedy decoding (argmax) is the deterministic
-mode the equality and fault-isolation invariants are tested over.
+tolerance, not by construction. The block-diffusion step (`_bd_fn`) is
+batched the same way and promises the same: a fixed composition repeats
+itself, pool bytes included; a block's rows are written by its own slot's
+commit pass and by nothing else (denoising passes and padded slots write
+reserved block 0); and a sequence's tokens agree with the float32
+reference within the benchmark's tolerance (`token_gap` <= 0.005 where
+the float8 control reads 0.02-0.04, `pick_gap` <= 0.02). Across bucket
+sizes even the order of a sequence's sums differs there: a sparse-expert
+layer accumulates a position's experts in expert order where the
+dispatch has more assignments than the layer has experts, else in
+assignment order (`models/moe.py`), both in float32;
+`tests/test_sdar_block_diffusion.py` holds a sequence in a bucket of 16
+to a written tolerance of the sequence alone. The speculative verify /
+propose steps keep their `lax.scan` over per-sequence sub-steps (they
+accept and reject in sequence, and no cell measures them). Greedy
+decoding (argmax) is the deterministic mode the equality and
+fault-isolation invariants are tested over.
 
 Usage::
 
@@ -588,8 +602,9 @@ class DecodeEngine:
         self._apply, self._params, self._buffers = functionalize(
             model, method=wrapped)
         if self._bd is not None:
-            self._bd_apply = functionalize(
-                model, method=self._make_bd_forward(model))[0]
+            self._bd_apply = tuple(
+                functionalize(model, method=m)[0]
+                for m in self._make_bd_forward(model))
 
         # tensor-parallel placement (paddle_tpu.sharding): weights shard
         # per their logical-axis annotations / the name-pattern rules,
@@ -721,6 +736,7 @@ class DecodeEngine:
         self._moe_tokens = None        # [layers, experts] positions routed
         self._moe_distinct = 0         # experts touched, summed over
         #                                layers and dispatches
+        self._moe_reads = 0            # experts the schedule read, same sum
         self._moe_load_sum = 0.0       # fullest expert over the mean, summed
         self._moe_load_n = 0           # ... over this many (layer, dispatch)
         # scheduler rounds: written by the scheduler thread alone
@@ -1150,75 +1166,87 @@ class DecodeEngine:
                       for t, r in zip(layer_ts, layer_rows))
                 for layer_ts, layer_rows in zip(pool_ts, rows)]
 
-    def _scatter_rows(self, pool_ts, new_caches, table, pos, n, live):
-        """Write the `n` cache rows a block forward produced at `pos` back
-        into the pool where `live` (a commit pass), else into reserved
-        block 0, the padding sink (a denoising pass writes no cache). The
-        rows lie in one pool block: `n` divides `block_size` and `pos` is
-        a multiple of `n`."""
+    def _scatter_new_blocks(self, pool_ts, rows, tables, positions, live):
+        """Write a bucket's block rows (`[B, n, ...]` a pool tensor, the
+        rows a block forward made at `positions`) into the pool, a tensor
+        by one scatter of B windows: a slot's own block where `live` (a
+        commit pass), else reserved block 0, the padding sink (a denoising
+        pass and a padded slot write no cache). A window lies in one pool
+        block: `n` divides `block_size` and a position is a multiple of
+        `n`."""
         import jax
         import jax.numpy as jnp
 
-        block = jnp.where(live != 0, table[pos // self.block_size], 0)
-        off = pos % self.block_size
+        blocks = jnp.take_along_axis(
+            tables, (positions // self.block_size)[:, None], axis=1)[:, 0]
+        at = jnp.stack([jnp.where(live != 0, blocks, 0),
+                        positions % self.block_size], axis=1)
         out = []
-        for layer_ts, layer_new in zip(pool_ts, new_caches):
+        for layer_ts, layer_rows in zip(pool_ts, rows):
             entry = []
-            for t, c in zip(layer_ts, layer_new):
-                rows = jax.lax.dynamic_slice_in_dim(c[0], pos, n, axis=0)
-                entry.append(jax.lax.dynamic_update_slice(
-                    t, rows.astype(t.dtype)[None],
-                    (block, off) + (0,) * (t.ndim - 2)))
+            for t, r in zip(layer_ts, layer_rows):
+                dims = jax.lax.ScatterDimensionNumbers(
+                    update_window_dims=tuple(range(1, t.ndim)),
+                    inserted_window_dims=(0,),
+                    scatter_dims_to_operand_dims=(0, 1))
+                entry.append(jax.lax.scatter(t, at, r.astype(t.dtype), dims))
             out.append(tuple(entry))
         return out
 
     @staticmethod
     def _make_bd_forward(model):
-        """The traced block forward: tokens [1, B] at offset `pos` against
-        the gathered cache. Returns per position the arg-max token of its
-        own logits (no shift) and that token's softmax probability, the
-        caches with the block's rows written, and the expert layers'
-        position counts `[layers, experts]`. A commit pass (`commit` != 0)
-        needs no logits and skips the head."""
+        """The two traced halves of a block forward. `trunk`: tokens
+        [1, B] at offset `pos` against the gathered cache, returns the
+        final hidden states [B, hidden], the caches with the block's rows
+        written, and the expert layers' position counts `[layers,
+        experts]`. `head`: per position of hidden [..., hidden] the arg-max
+        token of its own logits (no shift) and that token's softmax
+        probability."""
         import jax
         import jax.numpy as jnp
         from ...core.tensor import Tensor
         from ...models.moe import expert_counts
 
-        def forward(tokens, cache_vals, pos, commit):
+        def trunk(tokens, cache_vals, pos):
             cts = [tuple(Tensor(a) for a in entry) for entry in cache_vals]
             with expert_counts() as counts:
                 hidden, new_caches = model.transformer.forward_step(
                     Tensor(tokens), cts, Tensor(pos))
-
-            def head(h):
-                lg = model._project(Tensor(h))._value[0].astype(jnp.float32)
-                top = jnp.max(lg, axis=-1)
-                return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
-                        jnp.exp(top - jax.nn.logsumexp(lg, axis=-1)))
-
-            def skip(h):
-                return (jnp.zeros(h.shape[1], jnp.int32),
-                        jnp.zeros(h.shape[1], jnp.float32))
-
-            best, conf = jax.lax.cond(commit != 0, skip, head, hidden._value)
             counts = jnp.stack(counts) if counts \
                 else jnp.zeros((0, 0), jnp.int32)
-            return (best, conf,
+            return (hidden._value[0],
                     [tuple(t._value for t in nc) for nc in new_caches],
                     counts)
 
-        return forward
+        def head(hidden):
+            lg = model._project(Tensor(hidden))._value.astype(jnp.float32)
+            top = jnp.max(lg, axis=-1)
+            return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
+                    jnp.exp(top - jax.nn.logsumexp(lg, axis=-1)))
+
+        return trunk, head
 
     def _bd_fn(self, bucket):
-        """Block-diffusion step for `bucket` sequences: one `[1, B]`
-        forward a sequence at its block's offset (the shape of
-        `_verify_fn`), the sequences scanned one after another (the plain
-        step batches them; here a vmapped expert gather would copy each
-        sequence's chosen experts: ROADMAP S8a). A
-        sequence's phase is DATA (`commit`): the same program denoises one
-        sequence and commits another, and only a commit writes cache rows
-        (a denoising pass's rows sink into reserved block 0). Padded slots
+        """Block-diffusion step for `bucket` sequences as ONE batched
+        forward: the per-sequence block forward (`[1, B]` tokens at the
+        sequence's own offset against its own gathered view) traced once
+        and batched by `vmap` with the weights and the pool closed over,
+        as `_forward_bucket` does for the plain step. Every dense
+        projection sees `[bucket, B, hidden]` and reads its weight once a
+        dispatch, and the expert layer sees the dispatch's bucket x B
+        positions at once (`models/moe.py::apply_experts` folds the batch
+        axis into its positions), so an expert crosses HBM at most once a
+        layer a dispatch. What it promises is `_forward_bucket`'s contract
+        (module docstring), not bit-equality across bucket sizes: a
+        sequence's expert sum runs in expert order where the dispatch has
+        more assignments than the layer has experts, else in assignment
+        order.
+
+        A sequence's phase is DATA (`commit`): the same program denoises
+        one sequence and commits another, and only a commit writes cache
+        rows, after the forward (a denoising pass's rows sink into
+        reserved block 0). The head runs once for the whole bucket and is
+        skipped where no sequence of the dispatch denoises. Padded slots
         (`valid` 0) commit nothing and count no expert."""
         fn = self._bd_fns.get(bucket)
         if fn is not None:
@@ -1228,20 +1256,30 @@ class DecodeEngine:
         from ...jit import aot
 
         bl = self._bd["block_length"]
+        trunk, head = self._bd_apply
 
         def step(pv, bv, pool_ts, tokens, positions, tables, commit, valid):
-            def body(pool_ts, x):
-                toks, pos0, table, com, ok = x
+            def one(toks, pos0, table):
                 caches = self._gather(pool_ts, table)
-                (best, conf, new_caches, counts), _ = self._bd_apply(
-                    pv, bv, toks.reshape(1, bl), caches, pos0, com)
-                pool_ts = self._scatter_rows(pool_ts, new_caches, table,
-                                             pos0, bl, com * ok)
-                return pool_ts, (best, conf, counts * ok)
+                (hidden, new_caches, counts), _ = trunk(
+                    pv, bv, toks.reshape(1, bl), caches, pos0)
+                rows = [tuple(jax.lax.dynamic_slice_in_dim(
+                    c[0], pos0, bl, axis=0) for c in layer)
+                    for layer in new_caches]
+                return hidden, rows, counts
 
-            pool_ts, (best, conf, counts) = jax.lax.scan(
-                body, pool_ts, (tokens, positions, tables, commit, valid))
-            return pool_ts, (best, conf, jnp.sum(counts, axis=0))
+            hidden, rows, counts = jax.vmap(one)(tokens, positions, tables)
+            # one cond for the dispatch, outside the vmap: a cond a
+            # sequence would become a select of both branches under it
+            best, conf = jax.lax.cond(
+                jnp.all((commit != 0) | (valid == 0)),
+                lambda h: (jnp.zeros(h.shape[:2], jnp.int32),
+                           jnp.zeros(h.shape[:2], jnp.float32)),
+                lambda h: head(pv, bv, h)[0], hidden)
+            pool_ts = self._scatter_new_blocks(
+                pool_ts, rows, tables, positions, commit * valid)
+            counts = jnp.sum(counts * valid[:, None, None], axis=0)
+            return pool_ts, (best, conf, counts)
 
         pv, bv = self._weight_avals()
         row = jax.ShapeDtypeStruct((bucket,), jnp.int32)
@@ -1249,9 +1287,13 @@ class DecodeEngine:
                  jax.ShapeDtypeStruct((bucket, bl), jnp.int32), row,
                  jax.ShapeDtypeStruct((bucket, self._nb), jnp.int32),
                  row, row)
+        # its own `extra_key`, as `_decode_fn`'s: a cache filled by the
+        # scanned step under (tag, fingerprint, avals) must not serve this
+        # program. Bump it whenever this program's text changes
         compiled, source = aot.compile_jit(
             step, avals, fingerprint=self._fingerprint, cache=self._cache,
-            tag=f"decode-step-bd-b{bucket}", audit_ctx=self._audit_ctx(pv))
+            tag=f"decode-step-bd-b{bucket}", audit_ctx=self._audit_ctx(pv),
+            extra_key="batched-forward-v1")
         self._note_compile(source)
         self._bd_fns[bucket] = compiled
         return compiled
@@ -2750,6 +2792,8 @@ class DecodeEngine:
         """One block forward a sequence. Returns per sequence (whether it
         was a commit pass, the arg-max tokens [B], their confidences
         [B])."""
+        from ...models.moe import experts_read
+
         n = len(active)
         bl = self._bd["block_length"]
         bucket = next(b for b in self.decode_buckets if b >= n)
@@ -2793,6 +2837,9 @@ class DecodeEngine:
                     self._moe_tokens = np.zeros(counts.shape, np.int64)
                 self._moe_tokens += counts
                 self._moe_distinct += int((counts > 0).sum())
+                self._moe_reads += counts.shape[0] * experts_read(
+                    bucket * bl, self.model.cfg.num_experts_per_tok,
+                    counts.shape[1])
                 mean = counts.sum(axis=1) / counts.shape[1]
                 self._moe_load_sum += float(
                     (counts.max(axis=1) / np.maximum(mean, 1e-30)).sum())
@@ -3380,6 +3427,7 @@ class DecodeEngine:
                     snap.update(
                         moe_expert_tokens=self._moe_tokens.tolist(),
                         moe_distinct_experts=self._moe_distinct,
+                        moe_expert_reads=self._moe_reads,
                         moe_load_max_over_mean_sum=self._moe_load_sum,
                         moe_layer_dispatches=self._moe_load_n)
         th = self._h_ttft.snapshot()

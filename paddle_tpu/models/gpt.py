@@ -204,21 +204,22 @@ def _cached_attn_core(q, kk, vv, pos, num_heads, k_scale=None,
     key j when floor(j / B) <= floor(i / B)."""
     import jax
 
-    hkv = kk.shape[2]
-    if hkv != num_heads:
-        rep = num_heads // hkv
-        kk = jnp.repeat(kk, rep, axis=2)
-        vv = jnp.repeat(vv, rep, axis=2)
-        if k_scale is not None:
-            k_scale = jnp.repeat(k_scale, rep, axis=2)
-            v_scale = jnp.repeat(v_scale, rep, axis=2)
-    s, t = q.shape[1], kk.shape[1]
+    b, s, hkv, t = q.shape[0], q.shape[1], kk.shape[2], kk.shape[1]
+    rep = num_heads // hkv
+    q_pos = jnp.arange(s)
+    if rep > 1:
+        # grouped-query: the `rep` query heads of a KV head attend as
+        # `rep` more query rows of that head, so K and V (and their
+        # scales) are read as stored and never copied a query head
+        q = q.reshape(b, s, hkv, rep, -1).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, s * rep, hkv, -1)
+        q_pos = jnp.repeat(q_pos, rep)
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32)
     if k_scale is not None:   # [B,T,H] -> [B,H,1,T]
         scores = scores * jnp.transpose(k_scale, (0, 2, 1))[:, :, None, :]
     scores = scores * scale
-    q_idx = pos + jnp.arange(s)[:, None]
+    q_idx = pos + q_pos[:, None]
     k_idx = jnp.arange(t)[None, :]
     if block > 1:
         q_idx, k_idx = q_idx // block, k_idx // block
@@ -228,7 +229,11 @@ def _cached_attn_core(q, kk, vv, pos, num_heads, k_scale=None,
     if v_scale is not None:   # fold into [B,H,q,T] probs before PV
         probs = probs * jnp.transpose(v_scale, (0, 2, 1))[:, :, None, :]
     probs = probs.astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+    if rep > 1:
+        out = out.reshape(b, s, rep, hkv, -1).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, s, num_heads, -1)
+    return out
 
 
 def _write_rows(cache, new, pos):
@@ -433,13 +438,18 @@ class GPTForCausalLM(nn.Layer):
     def decode_signature(self):
         """What shapes a compiled decode program beyond the parameters'
         shapes, for the engine's compile-cache key: "" for a configuration
-        that uses none of it (keys as they were), else the values."""
+        that uses none of it (keys as they were), else the values. A
+        grouped-query model names its attention's form too: its query
+        heads attend as rows of their KV head (`_cached_attn_core`), a
+        program of its own since the K/V repeat went, which an executable
+        cached before must not serve."""
         cfg = self.cfg
+        gqa = "gqa-rows" if cfg.num_kv_heads != cfg.num_heads else ""
         if not (cfg.block_attention > 1 or cfg.num_experts or cfg.qk_norm):
-            return ""
+            return gqa
         return (f"block{cfg.block_attention}:top{cfg.num_experts_per_tok}:"
                 f"norm{int(cfg.norm_topk_prob)}:theta{cfg.rope_theta}:"
-                f"eps{cfg.layer_norm_epsilon}")
+                f"eps{cfg.layer_norm_epsilon}" + (":" + gqa if gqa else ""))
 
     def _resolve_cache_quant(self, quant):
         """Resolve the KV-cache quantization mode with a documented

@@ -6,14 +6,24 @@
 
 No token is dropped and no capacity is set: every position gets exactly its
 k experts. The experts are stacked `[E, ...]` (gate and up fused along the
-last axis), and the layer picks one of two exact schedules from its static
-shapes:
+last axis), and `apply_experts` picks one of two exact schedules from its
+static shapes:
 
-* few positions (positions x k <= E, a decode block): one pass over the
-  positions x k assignments, each reading its own expert's weights out of
-  the stack — at most positions x k experts' bytes move, not all E;
-* many positions (a prompt chunk): one pass over the E experts, each
-  applied to every position and weighted 0 where it was not chosen.
+* few positions (positions x k <= E): one pass over the positions x k
+  assignments, each reading its own expert's weights out of the stack — at
+  most positions x k experts' bytes move, not all E;
+* many positions: one pass over the E experts, each applied to every
+  position and weighted 0 where it was not chosen — every expert's bytes
+  move once.
+
+"Positions" are the DISPATCH's, not one sequence's: under `jax.vmap` with
+the weights unbatched (the decode engine's block-diffusion step batches a
+bucket's per-sequence forwards that way) `apply_experts` folds the batch
+axis into the positions and chooses the schedule for all of them, so a
+bucket of 16 blocks of 4 is 64 positions in one pass over the experts, and
+never a gather that copies each sequence's chosen experts out of the
+stack. A prompt chunk comes un-batched and is its own 256 positions. The
+router stays an ordinary traced function: its counts are per sequence.
 
 `distributed/moe.py::MoELayer` is the GShard capacity-factor training layer
 and drops tokens; this one serves.
@@ -108,12 +118,43 @@ def _by_expert(h, idx, w, gate_up, down):
     return acc
 
 
+def experts_read(positions, top_k, num_experts):
+    """How many experts' weights the schedule `apply_experts` picks for
+    `positions` positions reads out of a layer's stack."""
+    return min(positions * top_k, num_experts)
+
+
+@jax.custom_batching.custom_vmap
+def apply_experts(h, idx, w, gate_up, down):
+    """The chosen experts applied to h [T, hidden]: float32 [T, hidden],
+    sum over a position's k experts `idx` [T, k] of their SwiGLU weighted
+    by `w` [T, k]."""
+    few = idx.size <= gate_up.shape[0]
+    return (_by_assignment if few else _by_expert)(h, idx, w, gate_up, down)
+
+
+@apply_experts.def_vmap
+def _fold_batch(axis_size, in_batched, h, idx, w, gate_up, down):
+    """Batched positions over shared weights are more positions."""
+    if list(in_batched) != [True, True, True, False, False]:
+        # nobody serves so (a stack of experts a sequence, say): nothing
+        # is shared, each sequence on its own
+        axes = [0 if b else None for b in in_batched]
+        return jax.vmap(apply_experts.fun, in_axes=axes)(
+            h, idx, w, gate_up, down), True
+
+    def fold(a):
+        return a.reshape((-1,) + a.shape[2:])
+
+    y = apply_experts(fold(h), fold(idx), fold(w), gate_up, down)
+    return y.reshape(h.shape), True
+
+
 def _sparse_experts_impl(x, router_w, gate_up, down, *, top_k, norm_topk):
     shape = x.shape
     h = x.reshape(-1, shape[-1])
     idx, w, counts = route(h, router_w, top_k, norm_topk)
-    few = h.shape[0] * top_k <= gate_up.shape[0]
-    y = (_by_assignment if few else _by_expert)(h, idx, w, gate_up, down)
+    y = apply_experts(h, idx, w, gate_up, down)
     return y.astype(x.dtype).reshape(shape), counts
 
 

@@ -256,18 +256,25 @@ TINY = dict(vocab_size=97, hidden_size=48, num_heads=4, num_kv_heads=2,
 
 # Recorded from the parent commit (3d78f20, pool [N, bs, Hkv, D]) by this
 # very procedure: the served tokens, and sha256[:16] of every pool tensor's
-# bytes in the engine's (layer, entry) order.
+# bytes in the engine's (layer, entry) order. The tokens, layer 0's rows
+# and layer 1's int8 values are 3d78f20's still. Layer 1's float rows and
+# scales were recorded again at ISSUE 31, by the same procedure: the
+# model's grouped-query heads attend as rows of their KV head since then
+# (`_cached_attn_core`, no K/V repeat), which sums the same float32
+# products in another order, so what layer 1 projects from layer 0's
+# attention differs in its last bits (3d78f20 read a37cb7b08792039b,
+# 4404ddd64b55e39e and, int8, 560fedbc4dd22f00, 0ff619f79702e48f).
 PARENT = {
     None: ([[91, 53, 78, 72, 87, 49, 14, 72, 87, 53],
             [1, 14, 72, 87, 19, 24]],
            ["909b117860808f1f", "93f22409611614d1",
-            "a37cb7b08792039b", "4404ddd64b55e39e"]),
+            "9614ddc65af2addb", "d2b92a8527751b07"]),
     "int8": ([[91, 53, 78, 72, 87, 49, 14, 72, 87, 53],
               [1, 14, 72, 87, 19, 24]],
              ["70da0cc5427ea6e8", "f2f1993b2feca6a6",
               "7dff4527cf5dd7ba", "9c9ccf1968f84290",
-              "f3f13ac891b810ca", "560fedbc4dd22f00",
-              "83529f4947f08db6", "0ff619f79702e48f"]),
+              "f3f13ac891b810ca", "da9f9995a0b72eec",
+              "83529f4947f08db6", "d61562c52d1380e1"]),
 }
 
 
