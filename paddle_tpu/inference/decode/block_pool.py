@@ -49,6 +49,17 @@ the identical refcount/reserved-slot-0/LRU-eviction contract (slots
 instead of blocks, `release_owned` instead of `free_owned`), so
 multi-tenant decode shares one allocator mental model end to end.
 
+A model with RECURRENT layers (the gated delta rule of
+`models/linear_attention.py`) keeps, for those layers, a state of fixed size
+a sequence and no rows at all. Such a layer's entry in the pool is one SLOT a
+sequence — tensors `[num_slots + 1, ...]`, not `[num_blocks, block_size,
+...]` — handed out by `alloc_slot` at admission and returned by `free_slot`
+when the sequence ends. Slot 0 is reserved like block 0: padded rows of a
+bucketed step read and write it. A slot is never shared and never copied;
+what the engine cannot do with one (prefix reuse, copy-on-write,
+speculation's rollback) it refuses at construction. `slots + free slots +
+reserved == total` holds beside the blocks' law.
+
 Invariant (asserted by the decode fault-injection harness):
 ``allocated + free + reserved == total`` at all times (a block is
 "allocated" while it has >= 1 reference, however many holders share it),
@@ -65,6 +76,8 @@ __all__ = ["BlockKVCache", "OutOfBlocks"]
 
 #: block ids below this are never allocated (block 0 = padding sink)
 RESERVED_BLOCKS = 1
+#: likewise slot 0 of a recurrent layer's state
+RESERVED_SLOTS = 1
 
 
 class OutOfBlocks(RuntimeError):
@@ -96,10 +109,16 @@ class BlockKVCache:
             speculative decode engine runs TWO pools (the target model's
             and the draft model's, same conservation law each), and a
             leak report must say which one leaked.
+        slot_layers: per-layer booleans, True where the layer's entry is
+            one slot a sequence (a recurrent state) and its `entry_specs`
+            tuple holds ``(suffix_shape, dtype)`` pairs, allocated as
+            ``[num_slots + 1, *suffix_shape]``. None: every layer holds
+            blocks.
+        num_slots: slots to hand out (needed with a slot layer).
     """
 
     def __init__(self, num_blocks, block_size, entry_specs, quant=None,
-                 name=None):
+                 name=None, slot_layers=None, num_slots=0):
         import jax.numpy as jnp
 
         if block_size < 1:
@@ -114,13 +133,34 @@ class BlockKVCache:
         self.name = name
         #: per-layer tuples of device arrays; the engine replaces this
         #: wholesale after each committed (prefill/decode) step
+        self.slot_layers = tuple(bool(x) for x in (
+            slot_layers or [False] * len(entry_specs)))
+        self.num_slots = int(num_slots) if any(self.slot_layers) else 0
+        if any(self.slot_layers) and self.num_slots < 1:
+            raise ValueError(
+                f"a pool with slot layers needs num_slots >= 1, got "
+                f"{num_slots}")
         self.tensors = [
+            tuple(jnp.zeros((self.num_slots + RESERVED_SLOTS, *spec[0]),
+                            spec[1]) for spec in layer) if is_slot else
             tuple(jnp.zeros((self.num_blocks, self.block_size, *suffix),
                             dtype)
                   for suffix, dtype, _ in layer)
-            for layer in entry_specs]
-        self._kv_heads = [tuple(h for _, _, h in layer)
-                          for layer in entry_specs]
+            for layer, is_slot in zip(entry_specs, self.slot_layers)]
+        self._kv_heads = [() if is_slot else tuple(h for _, _, h in layer)
+                          for layer, is_slot in zip(entry_specs,
+                                                    self.slot_layers)]
+        #: device bytes of ONE slot over all slot layers
+        self.slot_bytes = sum(
+            math.prod(t.shape[1:]) * t.dtype.itemsize
+            for layer, is_slot in zip(self.tensors, self.slot_layers)
+            if is_slot for t in layer)
+        self._free_slots = list(range(self.num_slots + RESERVED_SLOTS - 1,
+                                      RESERVED_SLOTS - 1, -1))
+        self._slot_of = {}         # owner -> slot id
+        self.slot_allocs = 0
+        self.slot_frees = 0
+        self.peak_slots = 0
         self.mesh = None        # set by shard_() for tensor-parallel pools
         self.shardings = None
         self._lock = _locks.new_lock("decode.block_pool")
@@ -146,6 +186,10 @@ class BlockKVCache:
         import jax
         from ... import sharding as _shardlib
 
+        if any(self.slot_layers):
+            raise ValueError(
+                "a pool with recurrent-state slots does not shard: only "
+                "rows of kv heads have a sharding rule")
         self.mesh = mesh
         self.shardings = [
             tuple(_shardlib.logical_to_sharding(
@@ -288,6 +332,40 @@ class BlockKVCache:
                     self.frees += 1
             return dropped
 
+    # -- recurrent-state slots ---------------------------------------------
+    def alloc_slot(self, owner):
+        """The slot `owner` keeps its recurrent state in (one an owner;
+        asking again returns the same). Raises `OutOfBlocks` when every
+        slot is taken. What the slot holds is its last owner's: the
+        engine zeroes it before the first chunk."""
+        with self._lock:
+            if owner in self._slot_of:
+                return self._slot_of[owner]
+            if not self._free_slots:
+                self.failed_allocs += 1
+                raise OutOfBlocks(
+                    f"state slots exhausted: all {self.num_slots} in use")
+            slot = self._free_slots.pop()
+            self._slot_of[owner] = slot
+            self.slot_allocs += 1
+            self.peak_slots = max(self.peak_slots, len(self._slot_of))
+            return slot
+
+    def free_slot(self, owner):
+        """Return `owner`'s slot (None, and nothing done, where it holds
+        none: idempotent like `free_owned`)."""
+        with self._lock:
+            slot = self._slot_of.pop(owner, None)
+            if slot is not None:
+                self._free_slots.append(slot)
+                self.slot_frees += 1
+            return slot
+
+    @property
+    def slots_in_use(self):
+        with self._lock:
+            return len(self._slot_of)
+
     # -- copy-on-write -----------------------------------------------------
     def copy_block(self, src, dst):
         """Device-copy block `src`'s rows into block `dst` across every
@@ -298,8 +376,9 @@ class BlockKVCache:
         single-dispatch copy instead (`DecodeEngine._cow_fn`), which
         aliases the pool buffers in place."""
         self.tensors = [
-            tuple(t.at[dst].set(t[src]) for t in layer)
-            for layer in self.tensors]
+            layer if is_slot else tuple(t.at[dst].set(t[src])
+                                        for t in layer)
+            for layer, is_slot in zip(self.tensors, self.slot_layers)]
 
     @property
     def free_count(self):
@@ -329,8 +408,18 @@ class BlockKVCache:
                                 if len(hs) > 1)
             shared_refs = sum(len(hs) - 1 for hs in self._refs.values()
                               if len(hs) > 1)
+            slots = len(self._slot_of)
+            assert slots + len(self._free_slots) == self.num_slots, (
+                f"slot conservation violated: {slots} in use + "
+                f"{len(self._free_slots)} free != {self.num_slots}")
             return {
                 "name": self.name,
+                "state_slots_total": self.num_slots,
+                "state_slots": slots,
+                "state_slots_peak": self.peak_slots,
+                "state_slot_bytes": self.slot_bytes,
+                "state_slot_allocs": self.slot_allocs,
+                "state_slot_frees": self.slot_frees,
                 "total": self.num_blocks,
                 "reserved": RESERVED_BLOCKS,
                 "block_size": self.block_size,

@@ -102,6 +102,19 @@ that already exist in-tree:
   and the stream gets a block's tokens when it commits, each with the
   pass that fixed it (`SequenceStream.passes`).
 
+* **Recurrent layers beside attention** (a model whose configuration gives
+  some layers the gated delta rule, `models/linear_attention.py`): such a
+  layer's cache is a state of fixed size, not a row a position. The pool
+  holds it as one SLOT a sequence (`block_pool`): given and zeroed when the
+  sequence is admitted, read and written by slot where an attention layer's
+  rows go by block table (`_gather`, `_scatter_new_rows`, the prefill's
+  scatter), handed from one prompt chunk to the next with the convolution's
+  window, and returned when the sequence ends. A prompt chunk tells the
+  model how many of its bucket's positions are real: padding that rows
+  tolerate would corrupt a state. What assumes a cache made of rows (the
+  prefix cache, copy-on-write, speculation, block diffusion, int8 KV, a
+  mesh, adapters) is refused at construction (`_check_recurrent`).
+
 Determinism contract: the plain decode step runs the active batch through
 ONE batched forward (`_forward_bucket`: the model's per-sequence cached
 step traced once and batched by `vmap`, so a weight crosses HBM once a
@@ -333,7 +346,7 @@ class _Seq:
                  "spec_accepted", "sampling", "adapter", "adapter_slot",
                  "adapter_sig", "sample_base", "out_tokens", "held",
                  "t_submit", "t_admit", "t_first", "round_admit", "chunks",
-                 "prefill_ids", "bd")
+                 "prefill_ids", "bd", "slot")
 
     def __init__(self, sid, prompt, max_new, deadline):
         self.id = sid
@@ -341,6 +354,7 @@ class _Seq:
         self.prefill_ids = prompt      # what prefill puts in the cache (block
         #                                diffusion: the whole blocks of it)
         self.bd = None                 # block diffusion: the open block
+        self.slot = 0                  # recurrent layers: its state's slot
         self.max_new = max_new
         self.deadline = deadline
         self.stream = SequenceStream(sid, deadline)
@@ -396,7 +410,7 @@ class DecodeEngine:
                  pad_token_id=0, compile_cache=None, fault_hook=None,
                  hang_grace=0.1, supervise_interval=0.02, metrics=None,
                  mesh=None, sharding_rules=None, clock=time.monotonic,
-                 prefix_cache=True, prefix_cache_blocks=None,
+                 prefix_cache=None, prefix_cache_blocks=None,
                  prefill_chunk=None, draft_model=None, speculate_k=0,
                  draft_num_blocks=None, adapters=None, block_diffusion=None):
         from ...distributed.functional import functionalize
@@ -428,6 +442,14 @@ class DecodeEngine:
             block_diffusion, model, quant=quant, mesh=mesh,
             adapters=adapters, draft_model=draft_model,
             speculate_k=speculate_k)
+        # layers whose cache is a recurrent state (0: none, the programs
+        # and their keys as they were)
+        self._recurrent = self._check_recurrent(
+            model, quant=quant, mesh=mesh, adapters=adapters,
+            draft_model=draft_model, speculate_k=speculate_k,
+            prefix_cache=prefix_cache, block_diffusion=block_diffusion)
+        if prefix_cache is None:
+            prefix_cache = not self._recurrent
 
         if prefill_buckets is None:
             p, buckets = min(8, self.max_length - 1), []
@@ -466,6 +488,9 @@ class DecodeEngine:
                     f"buckets {self.prefill_buckets} and a multiple of "
                     f"block_size {self.block_size}")
             self._chunk = c
+        if self._chunk:
+            # a chunked prompt never needs a bucket of its own length
+            self.max_prompt = self.max_length - 1
 
         # paged KV pool — the model owns the geometry (cache-entry order,
         # dtypes, quant layout precedence); default capacity fits a full
@@ -477,8 +502,10 @@ class DecodeEngine:
         if num_blocks is None:
             num_blocks = RESERVED_BLOCKS + self.max_active * (
                 nb_per_seq + (1 if self._prefix_on else 0))
-        self.pool = model.init_block_pool(num_blocks, self.block_size,
-                                          quant=quant, name="target")
+        self.pool = model.init_block_pool(
+            num_blocks, self.block_size, quant=quant, name="target",
+            # one state slot a resident sequence
+            **({"num_slots": self.max_active} if self._recurrent else {}))
         if self._bd is not None and (
                 self.block_size % self._bd["block_length"]
                 or (self._chunk and self._chunk % self._bd["block_length"])):
@@ -585,11 +612,16 @@ class DecodeEngine:
         # adapter context so the pool's post-hooks see them; an empty
         # stacks dict (no adapter pool, or the spec verify path) traces
         # the bare base model — static emptiness, never a retrace.
-        def wrapped(tokens, cache_vals, pos, ats, aid):
+        def wrapped(tokens, cache_vals, pos, ats, aid, valid=None):
             from .adapter_pool import adapter_context
 
             cts = [tuple(Tensor(a) for a in entry) for entry in cache_vals]
-            if ats:
+            if valid is not None:
+                # a prompt chunk of a model with recurrent layers: how many
+                # of the bucket's positions are real
+                logits, new_caches = model.decode_step(
+                    Tensor(tokens), cts, Tensor(pos), Tensor(valid))
+            elif ats:
                 with adapter_context(ats, aid):
                     logits, new_caches = model.decode_step(
                         Tensor(tokens), cts, Tensor(pos))
@@ -658,6 +690,7 @@ class DecodeEngine:
         self._propose_fns = {}    # bucket -> compiled K-step draft propose
         self._draft_prefill_fns = {}   # prompt bucket -> draft catch-up
         self._cow_fn_c = None     # compiled donated block-copy (COW)
+        self._zero_fn_c = None    # compiled donated state-slot zeroing
         self._compiled = 0
         self._disk_loaded = 0
 
@@ -739,6 +772,10 @@ class DecodeEngine:
         self._moe_reads = 0            # experts the schedule read, same sum
         self._moe_load_sum = 0.0       # fullest expert over the mean, summed
         self._moe_load_n = 0           # ... over this many (layer, dispatch)
+        # recurrent layers: positions that went through the chunked form
+        # (prompt chunks) and through the one-step form (decode)
+        self._lin_chunk_tokens = 0
+        self._lin_step_tokens = 0
         # scheduler rounds: written by the scheduler thread alone
         # (_slow_rounds under _lock: stats() reads it)
         self._round_no = 0
@@ -1062,6 +1099,46 @@ class DecodeEngine:
                 f"rather than served unproven)")
         return bd
 
+    @staticmethod
+    def _check_recurrent(model, *, quant, mesh, adapters, draft_model,
+                         speculate_k, prefix_cache, block_diffusion):
+        """How many of the model's layers keep a recurrent state. With
+        any, what rests on a cache made of rows is refused here: a state
+        cannot be matched by prefix, shared by reference, copied a block
+        at a time or rolled back a position."""
+        def count(m):
+            return getattr(m, "recurrent_layers", lambda: 0)()
+
+        n = count(model)
+        if draft_model is not None and count(draft_model):
+            raise ValueError(
+                "draft_model has recurrent layers: speculation rolls a "
+                "rejected position back by rewriting its row, and a "
+                "recurrent state has no rows")
+        if not n:
+            return 0
+        refused = [name for name, on in (
+            ("prefix_cache (and its copy-on-write): a cached prefix is "
+             "blocks of rows, and the state after it was not kept",
+             bool(prefix_cache)),
+            ("draft_model / speculate_k: a rejected position cannot be "
+             "rolled back out of a state",
+             draft_model is not None or bool(speculate_k)),
+            ("block_diffusion: a denoising pass must leave the cache as "
+             "it was", bool(block_diffusion)),
+            ("quant: the int8 layout is for rows",
+             quant is not None
+             or getattr(model, "cache_quant", None) is not None),
+            ("mesh: state slots have no sharding rule", mesh is not None),
+            ("adapters: untested with the delta-rule projections",
+             adapters is not None)) if on]
+        if refused:
+            raise ValueError(
+                f"this model keeps a recurrent state in {n} of its layers "
+                f"(one slot a sequence, not rows); it does not compose "
+                f"with " + "; ".join(refused))
+        return n
+
     def _prefill_len(self, plen):
         """Prompt tokens that prefill puts in the cache: all of them, or
         under block diffusion the whole blocks (the remainder opens the
@@ -1115,15 +1192,24 @@ class DecodeEngine:
         pool_sh = [tuple(layer) for layer in self.pool.shardings]
         return self._param_sh, self._buf_sh, pool_sh, repl
 
-    def _gather(self, pool_ts, table, nb=None):
+    def _slot_layer(self, i):
+        """Whether layer `i` of the target pool holds a recurrent state
+        (one slot a sequence) and not blocks of rows."""
+        return bool(self._recurrent) and self.pool.slot_layers[i]
+
+    def _gather(self, pool_ts, table, nb=None, slot=None):
         """Dense per-sequence cache view: every pool tensor gathered
         through the block table into [1, NB*block_size, ...]. Prefill
         passes an EXTENDED table (`nb = _nb + _prefill_tail`, tail rows
         pointing at reserved block 0) so a chunk's bucket padding can
-        never clamp the in-graph cache update."""
+        never clamp the in-graph cache update. A recurrent layer's entry
+        is read by `slot`: [1, ...], the whole of it."""
         nb = self._nb if nb is None else nb
         caches = []
-        for layer in pool_ts:
+        for i, layer in enumerate(pool_ts):
+            if self._slot_layer(i):
+                caches.append(tuple(t[slot][None] for t in layer))
+                continue
             entry = []
             for t in layer:
                 g = t[table]                       # [NB, bs, *suffix]
@@ -1142,29 +1228,37 @@ class DecodeEngine:
                 for layer_ts, layer_rows in zip(
                     pool_ts, self._new_rows(new_caches, pos))]
 
-    @staticmethod
-    def _new_rows(new_caches, pos):
+    def _new_rows(self, new_caches, pos):
         """The cache row of every pool tensor that a one-token step wrote
-        at `pos` (the only row `decode_step` changed)."""
+        at `pos` (the only row `decode_step` changed); of a recurrent
+        layer, the whole new entry."""
         import jax
 
-        return [tuple(jax.lax.dynamic_index_in_dim(c, pos, axis=1,
+        return [tuple(c[0] for c in layer) if self._slot_layer(i) else
+                tuple(jax.lax.dynamic_index_in_dim(c, pos, axis=1,
                                                    keepdims=False)[0]
-                      for c in layer) for layer in new_caches]
+                      for c in layer)
+                for i, layer in enumerate(new_caches)]
 
-    def _scatter_new_rows(self, pool_ts, rows, tables, positions):
+    def _scatter_new_rows(self, pool_ts, rows, tables, positions,
+                          slots=None):
         """Write a bucket's new rows (`_new_rows`, stacked `[B, ...]`)
         into the pool, a tensor by one scatter at `(table[pos // bs],
-        pos % bs)`. Padded slots carry table 0 and position 0: they all
-        land on one row of reserved block 0, the padding sink."""
+        pos % bs)`, a recurrent layer's entries at `slots`. Padded slots
+        carry table 0, position 0 and slot 0: they all land on one row of
+        reserved block 0 and on reserved slot 0, the padding sinks."""
         import jax.numpy as jnp
 
         blocks = jnp.take_along_axis(
             tables, (positions // self.block_size)[:, None], axis=1)[:, 0]
         offs = positions % self.block_size
-        return [tuple(t.at[blocks, offs].set(r.astype(t.dtype))
+        return [tuple(t.at[slots].set(r.astype(t.dtype))
                       for t, r in zip(layer_ts, layer_rows))
-                for layer_ts, layer_rows in zip(pool_ts, rows)]
+                if self._slot_layer(i) else
+                tuple(t.at[blocks, offs].set(r.astype(t.dtype))
+                      for t, r in zip(layer_ts, layer_rows))
+                for i, (layer_ts, layer_rows) in enumerate(
+                    zip(pool_ts, rows))]
 
     def _scatter_new_blocks(self, pool_ts, rows, tables, positions, live):
         """Write a bucket's block rows (`[B, n, ...]` a pool tensor, the
@@ -1312,7 +1406,7 @@ class DecodeEngine:
             if self._adapters is not None else {}
 
     def _forward_bucket(self, pv, bv, ats, pool_ts, tokens, positions,
-                        tables, aids):
+                        tables, aids, slots=None):
         """ONE forward for a bucket of one-token steps (traced): float32
         logits `[B, vocab]` and the cache rows the step made (`_new_rows`,
         stacked `[B, ...]`), the pool itself untouched.
@@ -1328,14 +1422,17 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        def one(tok, pos, table, aid):
-            caches = self._gather(pool_ts, table)
+        def one(tok, pos, table, aid, *slot):
+            caches = self._gather(pool_ts, table,
+                                  slot=slot[0] if slot else None)
             (logits, new_caches), _ = self._apply(
                 pv, bv, tok.reshape(1, 1), caches, pos, ats, aid)
             return (logits[0, -1].astype(jnp.float32),
                     self._new_rows(new_caches, pos))
 
-        return jax.vmap(one)(tokens, positions, tables, aids)
+        # a model with recurrent layers reads each row's state by its slot
+        return jax.vmap(one)(tokens, positions, tables, aids,
+                             *(() if slots is None else (slots,)))
 
     def _decode_fn(self, bucket):
         fn = self._decode_fns.get(bucket)
@@ -1347,9 +1444,10 @@ class DecodeEngine:
         from ..sampling import sample_token, samp_pack_avals
 
         def step(pv, bv, ats, pool_ts, tokens, positions, tables,
-                 aids, hist, samp):
+                 aids, hist, samp, slots=None):
             logits, rows = self._forward_bucket(
-                pv, bv, ats, pool_ts, tokens, positions, tables, aids)
+                pv, bv, ats, pool_ts, tokens, positions, tables, aids,
+                slots)
             # greedy rows (`samp["greedy"] == 1`) select the raw-logits
             # argmax behind a where; sampled rows draw from the counter-
             # keyed per-sequence RNG. Row by row, not under `vmap`: the
@@ -1361,7 +1459,7 @@ class DecodeEngine:
             nxt = jax.lax.map(lambda row: sample_token(*row),
                               (logits, samp, hist))
             return self._scatter_new_rows(
-                pool_ts, rows, tables, positions), nxt
+                pool_ts, rows, tables, positions, slots), nxt
 
         pv, bv = self._weight_avals()
         ats_avals = self._adapter_avals()
@@ -1374,6 +1472,10 @@ class DecodeEngine:
                  jax.ShapeDtypeStruct((bucket, self.max_length),
                                       jnp.int32),
                  samp_avals)
+        if self._recurrent:
+            # the state slots ride last: the other models' programs keep
+            # their signature
+            avals += (jax.ShapeDtypeStruct((bucket,), jnp.int32),)
         in_sh = out_sh = None
         sh = self._step_shardings()
         if sh is not None:
@@ -1417,15 +1519,21 @@ class DecodeEngine:
         nb_written = math.ceil(pbucket / self.block_size)
         nb_table = self._nb + self._prefill_tail
 
-        def scatter(pool_ts, new_caches, table, start):
+        def scatter(pool_ts, new_caches, table, start, slot=None):
             # scatter the written rows block-by-block from the chunk's
             # start block; rows past the real tokens are garbage that
             # decode overwrites position-by-position before it can ever
             # be attended, and rows past the allocated blocks land in
-            # reserved block 0 (the padding sink)
+            # reserved block 0 (the padding sink). A recurrent layer hands
+            # its state and window to the next chunk through its slot
             sb = start // self.block_size
             out = []
-            for layer_ts, layer_new in zip(pool_ts, new_caches):
+            for i, (layer_ts, layer_new) in enumerate(zip(pool_ts,
+                                                          new_caches)):
+                if multiplex and self._slot_layer(i):
+                    out.append(tuple(t.at[slot].set(c[0].astype(t.dtype))
+                                     for t, c in zip(layer_ts, layer_new)))
+                    continue
                 entry = []
                 for t, c in zip(layer_ts, layer_new):
                     new_t = t
@@ -1444,19 +1552,24 @@ class DecodeEngine:
             from ..sampling import sample_token
 
             def prefill(pv, bv, ats, pool_ts, tokens, start, valid_len,
-                        table, aid, hist, samp):
+                        table, aid, hist, samp, slot=None):
                 # chunk-aware prefill: tokens [1, pbucket] hold prompt
                 # positions [start, start + valid_len); `start` is
                 # always block-aligned (0 for a monolithic prefill).
                 # Attention over already-written earlier chunks rides
-                # the same gathered view.
-                caches = self._gather(pool_ts, table, nb=nb_table)
-                (logits, new_caches), _ = apply(pv, bv, tokens, caches,
-                                                start, ats, aid)
+                # the same gathered view. With recurrent layers the model
+                # is told `valid_len`: the bucket's padding must leave the
+                # state as the last real position left it.
+                caches = self._gather(pool_ts, table, nb=nb_table,
+                                      slot=slot)
+                (logits, new_caches), _ = apply(
+                    pv, bv, tokens, caches, start, ats, aid,
+                    *((valid_len,) if self._recurrent else ()))
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], valid_len - 1, axis=0, keepdims=False)
                 nxt = sample_token(last.astype(jnp.float32), samp, hist)
-                return scatter(pool_ts, new_caches, table, start), nxt
+                return scatter(pool_ts, new_caches, table, start,
+                               slot), nxt
         else:
             def prefill(pv, bv, pool_ts, tokens, start, valid_len, table):
                 caches = self._gather(pool_ts, table, nb=nb_table)
@@ -1494,6 +1607,8 @@ class DecodeEngine:
                  jax.ShapeDtypeStruct((), jnp.int32),
                  jax.ShapeDtypeStruct((self.max_length,), jnp.int32),
                  samp_avals)
+        if self._recurrent:
+            avals += (jax.ShapeDtypeStruct((), jnp.int32),)
         in_sh = out_sh = None
         sh = self._step_shardings()
         if sh is not None:
@@ -1791,6 +1906,43 @@ class DecodeEngine:
         self._cow_fn_c = compiled
         return compiled
 
+    def _zero_fn(self):
+        """Compiled zeroing of one state slot across the recurrent layers:
+        ONE donated dispatch over those layers' tensors alone (the blocks
+        are not passed), aliased in place like `_cow_fn`'s copy."""
+        if self._zero_fn_c is not None:
+            return self._zero_fn_c
+        import jax
+        import jax.numpy as jnp
+        from ...jit import aot
+
+        def zero(state_ts, slot):
+            return [tuple(t.at[slot].set(jnp.zeros(t.shape[1:], t.dtype))
+                          for t in layer) for layer in state_ts]
+
+        avals = (self._avals(self._state_tensors()),
+                 jax.ShapeDtypeStruct((), jnp.int32))
+        compiled, source = aot.compile_jit(
+            zero, avals, fingerprint=self._fingerprint, cache=self._cache,
+            tag="decode-zero-slot", donate_argnums=(0,),
+            audit_ctx=None if not _gc.enabled() else {"mesh": self.mesh})
+        self._note_compile(source)
+        self._zero_fn_c = compiled
+        return compiled
+
+    def _state_tensors(self):
+        return [layer for i, layer in enumerate(self.pool.tensors)
+                if self._slot_layer(i)]
+
+    def _zero_slot(self, slot):
+        """A slot's state and window back to zero: what position 0 starts
+        from, whoever held the slot before."""
+        zeroed = iter(self._zero_fn()(self._state_tensors(),
+                                      np.asarray(slot, np.int32)))
+        self.pool.tensors = [
+            next(zeroed) if self._slot_layer(i) else layer
+            for i, layer in enumerate(self.pool.tensors)]
+
     def warmup(self):
         """Compile (or disk-load) every decode bucket and prefill bucket
         (plus the COW block-copy when prefix sharing is on) up front, so
@@ -1804,6 +1956,8 @@ class DecodeEngine:
             self._prefill_fn(p)
         if self._prefix_on:
             self._cow_fn()
+        if self._recurrent:
+            self._zero_fn()
         out = {"decode": list(self.decode_buckets),
                "prefill": list(self.prefill_buckets)}
         if self._spec_on:
@@ -2153,6 +2307,11 @@ class DecodeEngine:
         plen = len(seq.prefill_ids)
         seq.t_admit = time.perf_counter()
         seq.round_admit = self._round_no
+        if self._recurrent:
+            # its state's slot (as many slots as batch slots: admission has
+            # just found one of those), zeroed of its last owner's state
+            seq.slot = self.pool.alloc_slot(seq.id)
+            self._zero_slot(seq.slot)
         if self._h_queue_wait is not None and seq.submitted_at is not None:
             self._h_queue_wait.observe(self._clock() - seq.submitted_at,
                                        ctx=seq.span.ctx)
@@ -2262,10 +2421,14 @@ class DecodeEngine:
             tokens[0, :this_len] = seq.prefill_ids[start:start + this_len]
             table = self._padded_table(seq, self._nb + self._prefill_tail)
             pool_ts = self.pool.tensors
+            extra = (np.asarray(seq.slot, np.int32),) \
+                if self._recurrent else ()
         hook = self._fault_hook
         sctx = seq.span.ctx
         chunked = this_len < remaining or start > 0
         rnd = self._round_no
+        lin = {"recurrent_layers": self._recurrent} \
+            if self._recurrent else {}
 
         def run(_member, hctx):
             if hook is not None:
@@ -2282,7 +2445,7 @@ class DecodeEngine:
                     attrs=None if sctx is None else
                     {"seq": seq.id, "bucket": pbucket, "start": start,
                      "tokens": this_len, "prompt_len": plen,
-                     "round": rnd}, profile=True), \
+                     "round": rnd, **lin}, profile=True), \
                     _locks.blocking_region("decode.step_dispatch"):
                 # the hot-sync probe covers the dispatch only; the token
                 # readback below is the step's deliverable (streaming
@@ -2295,7 +2458,7 @@ class DecodeEngine:
                     new_pool, nxt = fn(pv, bv, ats, pool_ts, tokens,
                                        np.asarray(start, np.int32),
                                        np.asarray(this_len, np.int32),
-                                       table, aid, hist, samp)
+                                       table, aid, hist, samp, *extra)
                 self._san_sweep(new_pool)
                 with _san.allow_host_sync("decode.token_fetch"), \
                         _otrace.span_in(names["fetch"], hctx,
@@ -2303,6 +2466,12 @@ class DecodeEngine:
                     return new_pool, int(np.asarray(nxt))
 
         new_pool, tok = self._submit_step(run, names)
+        if self._recurrent:
+            with self._lock:
+                if pbucket > 1:
+                    self._lin_chunk_tokens += this_len
+                else:
+                    self._lin_step_tokens += this_len
         with _otrace.span(names["deliver"], profile=True):
             self._prefill_done(seq, new_pool, tok, start + this_len)
 
@@ -2706,19 +2875,23 @@ class DecodeEngine:
             positions = np.zeros(bucket, np.int32)
             tables = np.zeros((bucket, self._nb), np.int32)  # pad -> 0
             aids = np.zeros(bucket, np.int32)  # pad rows -> slot 0 (no-op)
+            slots = np.zeros(bucket, np.int32)  # pad rows -> state slot 0
             for i, seq in enumerate(active):
                 tokens[i] = seq.last_token
                 positions[i] = seq.pos
                 tables[i] = self._padded_table(seq)
                 aids[i] = seq.adapter_slot
+                slots[i] = seq.slot
             hist = self._hist_pack(active, bucket)
             samp = self._samp_pack(active, bucket)
             pool_ts = self.pool.tensors
+            extra = (slots,) if self._recurrent else ()
         new_pool, nxt = self._run_linked_step(
             "decode.step", "decode.step_join", active, "decode",
-            {"bucket": bucket},
+            {"bucket": bucket, **({"recurrent_layers": self._recurrent}
+                                  if self._recurrent else {})},
             lambda: fn(pv, bv, ats, pool_ts, tokens, positions, tables,
-                       aids, hist, samp),
+                       aids, hist, samp, *extra),
             sweep=True)
         self.pool.tensors = new_pool
         for seq in active:
@@ -2727,6 +2900,8 @@ class DecodeEngine:
             self._steps_run += 1
             self._step_slots += bucket
             self._step_active += n
+            if self._recurrent:
+                self._lin_step_tokens += n
         return nxt[:n]
 
     # -- block diffusion round ---------------------------------------------
@@ -3235,6 +3410,8 @@ class DecodeEngine:
         # drops every reference this sequence holds: exclusive blocks
         # free, shared prefix blocks stay for their other holders
         self.pool.free_owned(seq.id)
+        if self._recurrent:
+            self.pool.free_slot(seq.id)
         if self._adapters is not None:
             self._adapters.release_owned(seq.id)
         if self._spec_on:
@@ -3430,10 +3607,24 @@ class DecodeEngine:
                         moe_expert_reads=self._moe_reads,
                         moe_load_max_over_mean_sum=self._moe_load_sum,
                         moe_layer_dispatches=self._moe_load_n)
+            if self._recurrent:
+                snap.update(
+                    lin_layers=self._recurrent,
+                    lin_chunk_tokens=self._lin_chunk_tokens,
+                    lin_step_tokens=self._lin_step_tokens)
         th = self._h_ttft.snapshot()
         snap["ttft"] = {"count": th["count"], "avg_s": th["avg"],
                         "p50_s": th["p50"], "p99_s": th["p99"]}
-        snap["blocks"] = self.pool.stats()
+        snap["blocks"] = blocks = self.pool.stats()
+        if self._recurrent:
+            # the two kinds of cache side by side: state slots held (and
+            # their bytes) and KV blocks held
+            snap.update(
+                lin_state_slots=blocks["state_slots"],
+                lin_state_slots_peak=blocks["state_slots_peak"],
+                lin_state_bytes=blocks["state_slots"]
+                * blocks["state_slot_bytes"],
+                kv_blocks_in_use=blocks["allocated"])
         if self._adapters is not None:
             snap["adapters"] = self._adapters.stats()
         if self._spec_on:

@@ -15,6 +15,10 @@ TPU-native design:
   row-shard proj/mlp-out, vocab-shard embedding).
 - Rotary or learned positions; pre-LN; GELU or SwiGLU MLP — covers the
   GPT-3-1.3B and LLaMA-2 configs of BASELINE.md (configs 4, 5).
+- Layers of more than one kind (`GPTConfig.layer_pattern`): full attention
+  beside the gated delta rule of `linear_attention.py`, whose cache entry
+  is a recurrent state and not rows; the norm before a sublayer or after
+  it (`norm_after`); no positions at all (`learned_positions=False`).
 """
 from __future__ import annotations
 
@@ -28,6 +32,10 @@ from .. import nn
 from .. import ops
 from ..core.dispatch import apply
 from ..nn import functional as F
+
+
+FULL, LINEAR = "full_attention", "linear_attention"
+LAYER_KINDS = (FULL, LINEAR)
 
 
 @dataclass
@@ -57,8 +65,37 @@ class GPTConfig:
     norm_topk_prob: bool = True      # chosen experts' weights sum to 1
     block_attention: int = 0         # B > 1 → causal over blocks of B
     #                                  positions, full inside one
+    # -- layers of more than one kind (models/linear_attention.py) --------
+    layer_pattern: tuple = ()        # the kinds of one period, repeated over
+    #                                  the depth: "full_attention" |
+    #                                  "linear_attention"; () → all full
+    norm_after: bool = False         # x + norm(sublayer(x)): the norm after
+    #                                  the sublayer, not before it
+    qk_norm_whole: bool = False      # RMSNorm over the whole projected q and
+    #                                  the whole projected k (all heads)
+    learned_positions: bool = True   # without rope: a position table; False
+    #                                  → no positions at all
+    linear_num_heads: int = 0        # gated delta-rule heads (keys = values)
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False   # beta in (0, 2), not (0, 1)
 
     def __post_init__(self):
+        self.layer_pattern = tuple(self.layer_pattern)
+        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+        if unknown or (self.layer_pattern
+                       and self.num_layers % len(self.layer_pattern)):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern}: kinds are "
+                f"{LAYER_KINDS} and a period divides num_layers "
+                f"({self.num_layers})")
+        if LINEAR in self.layer_pattern and not (
+                self.linear_num_heads and self.linear_key_head_dim
+                and self.linear_value_head_dim):
+            raise ValueError(
+                "a linear_attention layer needs linear_num_heads, "
+                "linear_key_head_dim and linear_value_head_dim")
         if self.num_kv_heads == 0:
             self.num_kv_heads = self.num_heads
         if self.head_dim == 0:
@@ -70,6 +107,12 @@ class GPTConfig:
                     128 * math.ceil(8 * self.hidden_size / 3 / 128))
             else:
                 self.intermediate_size = 4 * self.hidden_size
+
+    def layer_kinds(self):
+        """The kind of every layer, in order."""
+        period = self.layer_pattern or (FULL,)
+        return tuple(period[i % len(period)]
+                     for i in range(self.num_layers))
 
 
 # Named configs matching BASELINE.md workloads.
@@ -136,6 +179,10 @@ class GPTAttention(nn.Layer):
             # one learned weight of head_dim, shared by a projection's heads
             self.q_norm = nn.RMSNorm(hd, epsilon=cfg.layer_norm_epsilon)
             self.k_norm = nn.RMSNorm(hd, epsilon=cfg.layer_norm_epsilon)
+        elif cfg.qk_norm_whole:
+            # one weight a channel of the projection, the mean over all heads
+            self.q_norm = nn.RMSNorm(q_out, epsilon=cfg.layer_norm_epsilon)
+            self.k_norm = nn.RMSNorm(kv_out, epsilon=cfg.layer_norm_epsilon)
         self.dropout = nn.Dropout(cfg.dropout)
 
     def forward(self, x, position_ids=None, cache=None):
@@ -147,6 +194,8 @@ class GPTAttention(nn.Layer):
         q_sz = cfg.num_heads * hd
         kv_sz = cfg.num_kv_heads * hd
         q, k, v = ops.split(qkv, [q_sz, kv_sz, kv_sz], axis=-1)
+        if cfg.qk_norm_whole and not cfg.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
         q = ops.reshape(q, [b, s, cfg.num_heads, hd])
         k = ops.reshape(k, [b, s, cfg.num_kv_heads, hd])
         v = ops.reshape(v, [b, s, cfg.num_kv_heads, hd])
@@ -335,10 +384,21 @@ class GPTMLP(nn.Layer):
 
 
 class GPTBlock(nn.Layer):
-    def __init__(self, cfg: GPTConfig):
+    """One decoder layer of `kind`: full attention (`attn`) or the gated
+    delta rule (`lin`, whose cache is a state and not rows), the norms
+    before the sublayers or, with `norm_after`, after them."""
+
+    def __init__(self, cfg: GPTConfig, kind: str = FULL):
         super().__init__()
+        self.kind = kind
+        self.norm_after = cfg.norm_after
         self.ln_1 = _make_norm(cfg)
-        self.attn = GPTAttention(cfg)
+        if kind == LINEAR:
+            from .linear_attention import GatedDeltaNet
+
+            self.lin = GatedDeltaNet(cfg)
+        else:
+            self.attn = GPTAttention(cfg)
         self.ln_2 = _make_norm(cfg)
         if cfg.num_experts:
             from .moe import SparseExperts
@@ -347,15 +407,33 @@ class GPTBlock(nn.Layer):
         else:
             self.mlp = GPTMLP(cfg)
 
-    def forward(self, x, position_ids=None, cache=None):
+    def _mix(self, x, position_ids, cache, pos, valid_len):
+        """The layer's mixer on x; with a cache entry `(out, new entry)`.
+        Attention takes the chunk's offset beside its rows, the delta rule
+        how many of the chunk's positions are real."""
+        if self.kind == LINEAR:
+            return self.lin(x, cache=cache, valid_len=valid_len)
+        if cache is None:
+            return self.attn(x, position_ids)
+        return self.attn(x, position_ids, (*cache, pos))
+
+    def forward(self, x, position_ids=None, cache=None, pos=None,
+                valid_len=None):
+        """`cache`: the layer's entry as `init_cache` lays it out, with the
+        chunk's offset `pos` (and for the delta rule `valid_len`) beside
+        it; returns `(x, new entry)` then."""
+        mixed = self._mix(x if self.norm_after else self.ln_1(x),
+                          position_ids, cache, pos, valid_len)
+        new_cache = None
         if cache is not None:
-            att, new_cache = self.attn(self.ln_1(x), position_ids, cache)
-            x = x + att
+            mixed, new_cache = mixed
+        if self.norm_after:
+            x = x + self.ln_1(mixed)
+            x = x + self.ln_2(self.mlp(x))
+        else:
+            x = x + mixed
             x = x + self.mlp(self.ln_2(x))
-            return x, new_cache
-        x = x + self.attn(self.ln_1(x), position_ids)
-        x = x + self.mlp(self.ln_2(x))
-        return x
+        return x if cache is None else (x, new_cache)
 
 
 class GPTModel(nn.Layer):
@@ -367,13 +445,14 @@ class GPTModel(nn.Layer):
         std = cfg.initializer_range
         self.wte = nn.Embedding(cfg.vocab_size, cfg.hidden_size,
                                 weight_attr=_normal_attr(std))
-        if not cfg.rope:
+        self.learned_positions = not cfg.rope and cfg.learned_positions
+        if self.learned_positions:
             self.wpe = nn.Embedding(cfg.max_position_embeddings,
                                     cfg.hidden_size,
                                     weight_attr=_normal_attr(std))
         self.drop = nn.Dropout(cfg.dropout)
-        self.layers = nn.LayerList([GPTBlock(cfg)
-                                    for _ in range(cfg.num_layers)])
+        self.layers = nn.LayerList([GPTBlock(cfg, kind)
+                                    for kind in cfg.layer_kinds()])
         self.ln_f = _make_norm(cfg)
 
     def forward(self, input_ids, position_ids=None):
@@ -383,28 +462,35 @@ class GPTModel(nn.Layer):
                 ops.unsqueeze(ops.arange(s, dtype="int32"), 0),
                 [input_ids.shape[0], s])
         x = self.wte(input_ids)
-        if not self.cfg.rope:
+        if self.learned_positions:
             x = x + self.wpe(position_ids)
         x = self.drop(x)
         for blk in self.layers:
             x = blk(x, position_ids)
         return self.ln_f(x)
 
-    def forward_step(self, input_ids, caches, pos):
+    def forward_step(self, input_ids, caches, pos, valid_len=None):
         """Cached decode: input_ids [B, s] at global positions
-        [pos, pos+s); caches = [(k, v)] per layer, flat rows
-        [B, T, Hkv*D] (`init_cache`). Returns (hidden, new_caches)."""
+        [pos, pos+s); caches = one entry a layer as `init_cache` lays
+        them out: (k, v) flat rows [B, T, Hkv*D] for an attention layer,
+        (window, state) for a delta-rule layer. `valid_len` (a scalar,
+        default s) says how many of the s positions are real: the rest
+        are a bucket's padding, which rows tolerate (they are overwritten
+        before they are attended) and a recurrent state does not. Returns
+        (hidden, new_caches)."""
         b, s = input_ids.shape
         position_ids = ops.unsqueeze(
             ops.arange(s, dtype="int32"), 0) + pos
         position_ids = ops.expand(position_ids, [b, s])
         x = self.wte(input_ids)
-        if not self.cfg.rope:
+        if self.learned_positions:
             x = x + self.wpe(position_ids)
         new_caches = []
         for blk, entry in zip(self.layers, caches):
-            # entry: (k, v) bf16 cache or (kq, ks, vq, vs) int8 cache
-            x, nc = blk(x, position_ids, cache=(*entry, pos))
+            # entry: (k, v) bf16 cache, (kq, ks, vq, vs) int8 cache, or a
+            # delta-rule layer's (window, state)
+            x, nc = blk(x, position_ids, cache=tuple(entry), pos=pos,
+                        valid_len=valid_len)
             new_caches.append(nc)
         return self.ln_f(x), new_caches
 
@@ -445,11 +531,33 @@ class GPTForCausalLM(nn.Layer):
         cached before must not serve."""
         cfg = self.cfg
         gqa = "gqa-rows" if cfg.num_kv_heads != cfg.num_heads else ""
+        hybrid = ""
+        if cfg.layer_pattern or cfg.norm_after or cfg.qk_norm_whole \
+                or not cfg.learned_positions:
+            # the layers' kinds, the residual form, the q/k norm's reach,
+            # the positions and the delta rule's geometry: none of them is
+            # a parameter's shape alone
+            hybrid = (f"kinds{','.join(k[0] for k in cfg.layer_kinds())}:"
+                      f"after{int(cfg.norm_after)}:"
+                      f"qkwhole{int(cfg.qk_norm_whole)}:"
+                      f"pos{int(cfg.learned_positions)}:"
+                      f"lin{cfg.linear_num_heads}x{cfg.linear_key_head_dim}"
+                      f"x{cfg.linear_value_head_dim}"
+                      f"c{cfg.linear_conv_kernel_dim}"
+                      f"neg{int(cfg.linear_allow_neg_eigval)}:"
+                      f"eps{cfg.layer_norm_epsilon}")
         if not (cfg.block_attention > 1 or cfg.num_experts or cfg.qk_norm):
-            return gqa
+            return ":".join(x for x in (gqa, hybrid) if x)
         return (f"block{cfg.block_attention}:top{cfg.num_experts_per_tok}:"
                 f"norm{int(cfg.norm_topk_prob)}:theta{cfg.rope_theta}:"
-                f"eps{cfg.layer_norm_epsilon}" + (":" + gqa if gqa else ""))
+                f"eps{cfg.layer_norm_epsilon}" + (":" + gqa if gqa else "")
+                + (":" + hybrid if hybrid else ""))
+
+    def recurrent_layers(self):
+        """How many layers keep a recurrent state and no rows (the decode
+        engine gives each resident sequence one state slot for them, and
+        refuses what needs a cache made of rows)."""
+        return self.cfg.layer_kinds().count(LINEAR)
 
     def _resolve_cache_quant(self, quant):
         """Resolve the KV-cache quantization mode with a documented
@@ -499,19 +607,36 @@ class GPTForCausalLM(nn.Layer):
                  cfg.num_kv_heads * cfg.head_dim)
         from ..core.tensor import Tensor
 
-        if quant == "int8":
-            sshape = shape[:2] + (cfg.num_kv_heads,)
-            return [(Tensor(jnp.zeros(shape, jnp.int8)),
-                     Tensor(jnp.zeros(sshape, jnp.float32)),
-                     Tensor(jnp.zeros(shape, jnp.int8)),
-                     Tensor(jnp.zeros(sshape, jnp.float32)))
-                    for _ in range(cfg.num_layers)]
-        return [(Tensor(jnp.zeros(shape, dtype)),
-                 Tensor(jnp.zeros(shape, dtype)))
-                for _ in range(cfg.num_layers)]
+        def entry(kind):
+            if kind == LINEAR:
+                # a delta-rule layer: the convolution's window and the
+                # state, whatever the length (and never quantized)
+                return tuple(Tensor(jnp.zeros((batch_size,) + suffix, dt))
+                             for suffix, dt in self._state_spec(dtype))
+            if quant == "int8":
+                sshape = shape[:2] + (cfg.num_kv_heads,)
+                return (Tensor(jnp.zeros(shape, jnp.int8)),
+                        Tensor(jnp.zeros(sshape, jnp.float32)),
+                        Tensor(jnp.zeros(shape, jnp.int8)),
+                        Tensor(jnp.zeros(sshape, jnp.float32)))
+            return (Tensor(jnp.zeros(shape, dtype)),
+                    Tensor(jnp.zeros(shape, dtype)))
+
+        return [entry(kind) for kind in cfg.layer_kinds()]
+
+    def _state_spec(self, dtype):
+        """(suffix shape, dtype) of a delta-rule layer's cache tensors,
+        one sequence's: the convolution's last K - 1 inputs in the
+        parameters' dtype, the state in float32 (an accumulator over the
+        whole sequence)."""
+        cfg = self.cfg
+        nh, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                      cfg.linear_value_head_dim)
+        return (((cfg.linear_conv_kernel_dim - 1, nh * (2 * dk + dv)),
+                 dtype), ((nh, dv, dk), jnp.float32))
 
     def init_block_pool(self, num_blocks, block_size, dtype=None,
-                        quant=None, name=None):
+                        quant=None, name=None, num_slots=0):
         """Paged twin of `init_cache`: a `BlockKVCache` whose per-layer
         pool tensors use exactly this model's cache-entry order and
         dtypes — `(k, v)` blocks of the parameter dtype, or int8
@@ -529,7 +654,10 @@ class GPTForCausalLM(nn.Layer):
         is owned by the model, not the scheduler; with speculative
         decoding on, the engine calls it on BOTH the target and the
         draft model (`name` tags whose pool is whose — each model owns
-        its own layer count / head geometry)."""
+        its own layer count / head geometry). A model with delta-rule
+        layers gives those layers `(window, state)` entries of
+        `num_slots` slots (one a resident sequence) in the same pool:
+        `BlockKVCache.slot_layers` says which layers they are."""
         from ..inference.decode.block_pool import BlockKVCache
 
         cfg = self.cfg
@@ -543,15 +671,26 @@ class GPTForCausalLM(nn.Layer):
                      (rows, jnp.int8, hkv), ((hkv,), jnp.float32, hkv))
         else:
             layer = ((rows, dtype, hkv), (rows, dtype, hkv))
+        kinds = cfg.layer_kinds()
+        if LINEAR not in kinds:
+            return BlockKVCache(num_blocks, block_size,
+                                [layer] * cfg.num_layers, quant=quant,
+                                name=name)
+        # a delta-rule layer's entry is one slot a sequence, not blocks of
+        # rows: the pool holds `num_slots` of them beside the blocks
+        state = tuple(self._state_spec(dtype))
         return BlockKVCache(num_blocks, block_size,
-                            [layer] * cfg.num_layers, quant=quant,
-                            name=name)
+                            [state if k == LINEAR else layer for k in kinds],
+                            quant=quant, name=name,
+                            slot_layers=[k == LINEAR for k in kinds],
+                            num_slots=num_slots)
 
-    def decode_step(self, input_ids, caches, pos):
+    def decode_step(self, input_ids, caches, pos, valid_len=None):
         """Cached decode step: logits for input_ids at global offset pos
-        plus updated caches (the generation fast path)."""
+        plus updated caches (the generation fast path). `valid_len`: how
+        many of the positions are real (`forward_step`)."""
         hidden, new_caches = self.transformer.forward_step(
-            input_ids, caches, pos)
+            input_ids, caches, pos, valid_len)
         return self._project(hidden), new_caches
 
     def loss(self, input_ids, labels=None, position_ids=None):
