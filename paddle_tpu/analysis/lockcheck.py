@@ -129,11 +129,16 @@ def _caller_site():
 
 
 class _Registry:
-    """Global recorder. Its own guard is a RAW threading.Lock — never an
-    instrumented one (the recorder must not observe itself)."""
+    """Global recorder. Its own guard is a RAW threading lock — never an
+    instrumented one (the recorder must not observe itself) — and a
+    REENTRANT one: an allocation inside a critical section can start a
+    garbage collection, whose callbacks (`obs.trace.watch_gc` opens a
+    span) take instrumented locks on this very thread and so come back
+    in here. Under a plain Lock that thread waited for itself, and every
+    other thread's next acquisition for it."""
 
     def __init__(self):
-        self._mu = threading.Lock()
+        self._mu = threading.RLock()
         self._tls = threading.local()
         self._live = {}        # lock -> (acquirer's held list, entry)
         self.edges = {}        # name -> {name: {"thread","site"}}

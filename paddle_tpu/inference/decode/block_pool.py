@@ -140,13 +140,16 @@ class BlockKVCache:
             raise ValueError(
                 f"a pool with slot layers needs num_slots >= 1, got "
                 f"{num_slots}")
-        self.tensors = [
-            tuple(jnp.zeros((self.num_slots + RESERVED_SLOTS, *spec[0]),
-                            spec[1]) for spec in layer) if is_slot else
-            tuple(jnp.zeros((self.num_blocks, self.block_size, *suffix),
-                            dtype)
+        #: (shape, dtype) of every pool tensor, per layer
+        self._specs = [
+            tuple(((self.num_slots + RESERVED_SLOTS, *spec[0]), spec[1])
+                  for spec in layer) if is_slot else
+            tuple(((self.num_blocks, self.block_size, *suffix), dtype)
                   for suffix, dtype, _ in layer)
             for layer, is_slot in zip(entry_specs, self.slot_layers)]
+        self.tensors = [tuple(jnp.zeros(shape, dtype)
+                              for shape, dtype in layer)
+                        for layer in self._specs]
         self._kv_heads = [() if is_slot else tuple(h for _, _, h in layer)
                           for layer, is_slot in zip(entry_specs,
                                                     self.slot_layers)]
@@ -201,6 +204,28 @@ class BlockKVCache:
             tuple(jax.device_put(t, sh) for t, sh in zip(layer, shs))
             for layer, shs in zip(self.tensors, self.shardings)]
         return self.shardings
+
+    def reset_tensors(self):
+        """New zeroed tensors of the pool's own shapes and placement, in
+        place of the old ones, which are deleted: for the engine whose
+        dispatch consumed the pool and failed. Whatever the rows and
+        slots held is gone; the allocator's books are the caller's to
+        clear (every table it handed out names rows that no longer
+        exist)."""
+        import jax
+        import jax.numpy as jnp
+
+        for layer in self.tensors:
+            for t in layer:
+                if not t.is_deleted():
+                    t.delete()
+        self.tensors = [tuple(jnp.zeros(shape, dtype)
+                              for shape, dtype in layer)
+                        for layer in self._specs]
+        if self.shardings is not None:
+            self.tensors = [
+                tuple(jax.device_put(t, sh) for t, sh in zip(layer, shs))
+                for layer, shs in zip(self.tensors, self.shardings)]
 
     # -- geometry ----------------------------------------------------------
     def blocks_for(self, num_tokens):

@@ -76,8 +76,8 @@ that already exist in-tree:
 * **Streaming through the serving runtime** (`serving.ServingPool`):
   every dispatch runs as a request on an internal supervised pool, so a
   wedged decode step trips the pool's EXISTING hang detection (the
-  wedged worker is retired, capacity restored, and the step — a pure
-  function of the committed state — is simply re-dispatched). Sequence
+  wedged worker is retired, capacity restored, and the step is
+  re-dispatched from the pool as it then stands). Sequence
   admission reuses the serving runtime's typed semantics: bounded
   waiting queue (`Overloaded`), per-sequence monotonic deadlines
   covering queue wait + generation (`DeadlineExceeded`), `PoolClosed`
@@ -85,6 +85,31 @@ that already exist in-tree:
   sequence is evicted ALONE — a failed multi-sequence step is re-run as
   isolated single-sequence steps to pin the blame, mirroring the
   batcher's split-on-failure.
+
+* **One copy of the cache, consumed by every dispatch.** Every program
+  that writes the pool (decode step, block-diffusion step, prompt chunk,
+  verify, propose, draft catch-up, COW copy, slot zeroing) takes it
+  DONATED: the output pool is the input's buffers, updated in place, so a
+  dispatch neither copies the pool in and out of HBM nor holds two of
+  them. The pool is therefore single-owner: `pool.tensors` is read at the
+  last moment before the compiled call, by the step-pool worker that is
+  about to make it, and replaced by the program's output when the call
+  has come back. The fault contract: (a) a dispatch that raises before
+  the compiled call (every `fault_hook` phase, an argument check) leaves
+  the pool whole, and is retried or isolated as ever; (b) a retired
+  worker never dispatches: an attempt that the hang detection gave up on
+  is cancelled under the engine's lock, where a worker also takes the
+  pool, and the re-submission reads the pool afresh (an attempt that
+  timed out inside its compiled call has the pool and is waited for
+  instead: what it brings back, late, is the step); (c) a compiled call
+  that fails AFTER its buffers were consumed (a pool leaf `is_deleted()`)
+  costs the cache, not the sequences: the engine allocates a fresh pool
+  and state slots, drops every table, and sends every resident sequence
+  back through admission to be prefilled again from its committed tokens
+  (prompt + delivered output, the resume path), counted in
+  `stats()["pool_rebuilds"]`. No delivered token changes. The members of
+  the failed dispatch then decode alone until a step of their own has
+  come back, and one that fails alone again is the one that fails.
 
 * **Generation by diffusion over blocks** (`block_diffusion=`, off by
   default; SDAR-class models trained under a mask that is causal over
@@ -166,10 +191,12 @@ or through a `ServingPool(..., decode_engine=engine)` via
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import hashlib
 import itertools
 import logging
 import math
+import os
 import queue
 import statistics
 import threading
@@ -177,6 +204,7 @@ import time
 
 import numpy as np
 
+from ...analysis import commcheck as _cc
 from ...analysis import locks as _locks
 from ...analysis import graphcheck as _gc
 from ...analysis import runtime_san as _san
@@ -346,7 +374,7 @@ class _Seq:
                  "spec_accepted", "sampling", "adapter", "adapter_slot",
                  "adapter_sig", "sample_base", "out_tokens", "held",
                  "t_submit", "t_admit", "t_first", "round_admit", "chunks",
-                 "prefill_ids", "bd", "slot")
+                 "prefill_ids", "bd", "slot", "suspect")
 
     def __init__(self, sid, prompt, max_new, deadline):
         self.id = sid
@@ -355,6 +383,8 @@ class _Seq:
         #                                diffusion: the whole blocks of it)
         self.bd = None                 # block diffusion: the open block
         self.slot = 0                  # recurrent layers: its state's slot
+        self.suspect = False           # was in a dispatch that failed after
+        #                                it consumed the pool: decodes alone
         self.max_new = max_new
         self.deadline = deadline
         self.stream = SequenceStream(sid, deadline)
@@ -391,6 +421,32 @@ class _Seq:
         self.t_first = None            # first token committed
         self.round_admit = None        # scheduler round it was admitted in
         self.chunks = 0                # prefill dispatches it took
+
+
+class _Attempt:
+    """One submission of a step closure to the step pool. Under the
+    engine's lock: `claimed` once its worker has taken the pool for the
+    compiled call (`_consume`), `cancelled` once the hang detection has
+    given up an attempt that had not: its worker, should it wake, then
+    takes no pool. A claimed attempt cannot be given up, it holds the only
+    copy of the cache: `done` and what the closure returned or raised are
+    how a late one still hands its step in."""
+    __slots__ = ("cancelled", "claimed", "done", "result", "error")
+
+    def __init__(self):
+        self.cancelled = self.claimed = False
+        self.done = threading.Event()
+        self.result = self.error = None
+
+
+class _AttemptRetired(RuntimeError):
+    """Raised on a retired step-pool worker in place of its dispatch."""
+
+
+class _PoolRebuilt(Exception):
+    """A dispatch failed after it consumed the pool: the cache was built
+    anew and every resident sequence is back in the waiting queue. Ends the
+    scheduler's round; nothing of the round's own state is valid."""
 
 
 #: registry collector keys need a distinct name per engine instance
@@ -673,11 +729,7 @@ class DecodeEngine:
                     for n, h in holders.items():
                         h._value = jax.device_put(
                             h._value, _shardlib.replicated(mesh, h.ndim))
-                self.draft_pool.tensors = [
-                    tuple(jax.device_put(
-                        t, _shardlib.replicated(mesh, t.ndim))
-                        for t in layer)
-                    for layer in self.draft_pool.tensors]
+                self._place_draft_pool()
 
         self._fingerprint = self._make_fingerprint()
         self._draft_fingerprint = self._make_draft_fingerprint() \
@@ -689,8 +741,8 @@ class DecodeEngine:
         self._verify_fns = {}     # bucket -> compiled K+1-position verify
         self._propose_fns = {}    # bucket -> compiled K-step draft propose
         self._draft_prefill_fns = {}   # prompt bucket -> draft catch-up
-        self._cow_fn_c = None     # compiled donated block-copy (COW)
-        self._zero_fn_c = None    # compiled donated state-slot zeroing
+        self._pool_fns = {}       # "cow": the compiled block copy (COW),
+        #                           "zero": the state-slot zeroing
         self._compiled = 0
         self._disk_loaded = 0
 
@@ -734,6 +786,8 @@ class DecodeEngine:
         self._tokens_out = 0
         self._wedged_steps = 0
         self._isolations = 0
+        self._donated_dispatches = 0   # compiled calls that took a pool
+        self._pool_rebuilds = 0        # fault contract (c)
         self._step_slots = 0
         self._step_active = 0
         self._peak_resident = 0
@@ -817,6 +871,18 @@ class DecodeEngine:
             # last: a concurrent scrape must only see a fully-built engine
             self._metrics.register_collector(
                 f"decode.{self.name}", self.stats)
+
+    def _place_draft_pool(self):
+        """The draft's pool replicated over the mesh, like the draft."""
+        if self.mesh is None:
+            return
+        import jax
+        from ... import sharding as _shardlib
+
+        self.draft_pool.tensors = [
+            tuple(jax.device_put(
+                t, _shardlib.replicated(self.mesh, t.ndim)) for t in layer)
+            for layer in self.draft_pool.tensors]
 
     # -- identity ----------------------------------------------------------
     def _make_fingerprint(self):
@@ -1172,14 +1238,19 @@ class DecodeEngine:
               for n, b in self._buffers.items()}
         return pv, bv
 
-    def _note_compile(self, source):
-        """Count one executable build ("compiled") or persistent-cache
-        load ("disk") — every program builder funnels through this."""
-        with self._lock:
-            if source == "disk":
-                self._disk_loaded += 1
-            else:
-                self._compiled += 1
+    def _installed(self, store, key, compiled, source):
+        """Keep a program `compile_jit` brought up and count its build
+        ("compiled") or persistent-cache load ("disk"): every program
+        builder ends here. None where a `cached_only` builder found
+        nothing cached."""
+        if compiled is not None:
+            with self._lock:
+                if source == "disk":
+                    self._disk_loaded += 1
+                else:
+                    self._compiled += 1
+            store[key] = compiled
+        return compiled
 
     def _step_shardings(self):
         """(pv, bv, pool, scalar) sharding pytrees for the TP step
@@ -1320,7 +1391,7 @@ class DecodeEngine:
 
         return trunk, head
 
-    def _bd_fn(self, bucket):
+    def _bd_fn(self, bucket, cached_only=False):
         """Block-diffusion step for `bucket` sequences as ONE batched
         forward: the per-sequence block forward (`[1, B]` tokens at the
         sequence's own offset against its own gathered view) traced once
@@ -1385,12 +1456,11 @@ class DecodeEngine:
         # scanned step under (tag, fingerprint, avals) must not serve this
         # program. Bump it whenever this program's text changes
         compiled, source = aot.compile_jit(
-            step, avals, fingerprint=self._fingerprint, cache=self._cache,
+            step, avals, cached_only=cached_only,
+            fingerprint=self._fingerprint, cache=self._cache,
             tag=f"decode-step-bd-b{bucket}", audit_ctx=self._audit_ctx(pv),
-            extra_key="batched-forward-v1")
-        self._note_compile(source)
-        self._bd_fns[bucket] = compiled
-        return compiled
+            donate_argnums=(2,), extra_key="batched-forward-v1")
+        return self._installed(self._bd_fns, bucket, compiled, source)
 
     def _adapter_avals(self):
         """Abstract values of the adapter slot stacks riding every
@@ -1434,7 +1504,7 @@ class DecodeEngine:
         return jax.vmap(one)(tokens, positions, tables, aids,
                              *(() if slots is None else (slots,)))
 
-    def _decode_fn(self, bucket):
+    def _decode_fn(self, bucket, cached_only=False):
         fn = self._decode_fns.get(bucket)
         if fn is not None:
             return fn
@@ -1492,13 +1562,12 @@ class DecodeEngine:
         # did not change when this step became one batched forward.
         # Bump it whenever this program's text changes
         compiled, source = aot.compile_jit(
-            step, avals, fingerprint=self._fingerprint, cache=self._cache,
+            step, avals, cached_only=cached_only,
+            fingerprint=self._fingerprint, cache=self._cache,
             tag=f"decode-step-b{bucket}", in_shardings=in_sh,
             out_shardings=out_sh, audit_ctx=self._audit_ctx(pv),
-            extra_key="batched-forward-v2")
-        self._note_compile(source)
-        self._decode_fns[bucket] = compiled
-        return compiled
+            donate_argnums=(3,), extra_key="batched-forward-v2")
+        return self._installed(self._decode_fns, bucket, compiled, source)
 
     def _make_prefill_body(self, pbucket, apply, multiplex=False):
         """The traced chunk-prefill program, shared by the target
@@ -1583,7 +1652,7 @@ class DecodeEngine:
 
         return prefill
 
-    def _prefill_fn(self, pbucket):
+    def _prefill_fn(self, pbucket, cached_only=False):
         fn = self._prefill_fns.get(pbucket)
         if fn is not None:
             return fn
@@ -1620,13 +1689,12 @@ class DecodeEngine:
                      repl, repl, repl, repl, repl, samp_sh)
             out_sh = (pool_sh, repl)
         compiled, source = aot.compile_jit(
-            prefill, avals, fingerprint=self._fingerprint,
+            prefill, avals, cached_only=cached_only,
+            fingerprint=self._fingerprint,
             cache=self._cache, tag=f"decode-prefill-p{pbucket}",
             in_shardings=in_sh, out_shardings=out_sh,
-            audit_ctx=self._audit_ctx(pv))
-        self._note_compile(source)
-        self._prefill_fns[pbucket] = compiled
-        return compiled
+            donate_argnums=(3,), audit_ctx=self._audit_ctx(pv))
+        return self._installed(self._prefill_fns, pbucket, compiled, source)
 
     def _prefill_tokens_sharding(self, pbucket, repl):
         """Sharding for the prefill token buffer [1, pbucket].
@@ -1695,7 +1763,7 @@ class DecodeEngine:
         repl = _shardlib.replicated(self.mesh)
         return (tuple([repl] * (3 + n_scalars)), (repl, repl))
 
-    def _verify_fn(self, bucket):
+    def _verify_fn(self, bucket, cached_only=False):
         """Target-side verification step for `bucket` sequences: scores
         K+1 positions per sequence — the last committed token plus the K
         draft proposals — as ONE chunk-shaped forward per sequence (the
@@ -1759,16 +1827,15 @@ class DecodeEngine:
             in_sh = (pv_sh, bv_sh, pool_sh, repl, repl, repl)
             out_sh = (pool_sh, repl)
         compiled, source = aot.compile_jit(
-            step, avals, fingerprint=self._fingerprint, cache=self._cache,
+            step, avals, cached_only=cached_only,
+            fingerprint=self._fingerprint, cache=self._cache,
             tag=f"decode-verify-b{bucket}",
             extra_key=("speculate_k", self._k),
             in_shardings=in_sh, out_shardings=out_sh,
-            audit_ctx=self._audit_ctx(pv))
-        self._note_compile(source)
-        self._verify_fns[bucket] = compiled
-        return compiled
+            donate_argnums=(2,), audit_ctx=self._audit_ctx(pv))
+        return self._installed(self._verify_fns, bucket, compiled, source)
 
-    def _propose_fn(self, bucket):
+    def _propose_fn(self, bucket, cached_only=False):
         """Draft-side proposal step for `bucket` sequences: K
         autoregressive draft decode steps fused into ONE dispatch — each
         iteration feeds its own argmax back in, writing the draft's KV
@@ -1830,16 +1897,15 @@ class DecodeEngine:
         # avals in the persistent cache
         in_sh, out_sh = self._draft_shardings(3)
         compiled, source = aot.compile_jit(
-            step, avals, fingerprint=self._draft_fingerprint,
+            step, avals, cached_only=cached_only,
+            fingerprint=self._draft_fingerprint,
             cache=self._cache, tag=f"decode-propose-b{bucket}",
             extra_key=("speculate_k", self._k),
-            in_shardings=in_sh, out_shardings=out_sh,
+            in_shardings=in_sh, out_shardings=out_sh, donate_argnums=(2,),
             audit_ctx=None if not _gc.enabled() else {"mesh": self.mesh})
-        self._note_compile(source)
-        self._propose_fns[bucket] = compiled
-        return compiled
+        return self._installed(self._propose_fns, bucket, compiled, source)
 
-    def _draft_prefill_fn(self, pbucket):
+    def _draft_prefill_fn(self, pbucket, cached_only=False):
         """Draft catch-up prefill: the draft-model twin of `_prefill_fn`
         (chunk-aware, block-scattered, extended table) used to (re)build
         the draft's KV over already-COMMITTED tokens — at first
@@ -1863,23 +1929,23 @@ class DecodeEngine:
                  jax.ShapeDtypeStruct((nb_table,), jnp.int32))
         in_sh, out_sh = self._draft_shardings(4)
         compiled, source = aot.compile_jit(
-            prefill, avals, fingerprint=self._draft_fingerprint,
+            prefill, avals, cached_only=cached_only,
+            fingerprint=self._draft_fingerprint,
             cache=self._cache, tag=f"decode-prefill-p{pbucket}",
-            in_shardings=in_sh, out_shardings=out_sh,
+            in_shardings=in_sh, out_shardings=out_sh, donate_argnums=(2,),
             audit_ctx=None if not _gc.enabled() else {"mesh": self.mesh})
-        self._note_compile(source)
-        self._draft_prefill_fns[pbucket] = compiled
-        return compiled
+        return self._installed(self._draft_prefill_fns, pbucket, compiled,
+                               source)
 
-    def _cow_fn(self):
+    def _cow_fn(self, cached_only=False):
         """Compiled copy-on-write block copy: ONE donated dispatch that
         rewrites a single block's rows across every layer tensor. With
         the pool donated, XLA aliases input to output buffers, so the
         copy costs one block's traffic — an eager per-tensor `at[].set`
         would functionally re-materialize the ENTIRE pool per COW, a
         per-admission latency spike scaling with pool size."""
-        if self._cow_fn_c is not None:
-            return self._cow_fn_c
+        if "cow" in self._pool_fns:
+            return self._pool_fns["cow"]
         import jax
         import jax.numpy as jnp
         from ...jit import aot
@@ -1898,20 +1964,19 @@ class DecodeEngine:
             in_sh = (pool_sh, repl, repl)
             out_sh = pool_sh
         compiled, source = aot.compile_jit(
-            cow, avals, fingerprint=self._fingerprint, cache=self._cache,
+            cow, avals, cached_only=cached_only,
+            fingerprint=self._fingerprint, cache=self._cache,
             tag="decode-cow-copy", donate_argnums=(0,),
             in_shardings=in_sh, out_shardings=out_sh,
             audit_ctx=None if not _gc.enabled() else {"mesh": self.mesh})
-        self._note_compile(source)
-        self._cow_fn_c = compiled
-        return compiled
+        return self._installed(self._pool_fns, "cow", compiled, source)
 
-    def _zero_fn(self):
+    def _zero_fn(self, cached_only=False):
         """Compiled zeroing of one state slot across the recurrent layers:
         ONE donated dispatch over those layers' tensors alone (the blocks
         are not passed), aliased in place like `_cow_fn`'s copy."""
-        if self._zero_fn_c is not None:
-            return self._zero_fn_c
+        if "zero" in self._pool_fns:
+            return self._pool_fns["zero"]
         import jax
         import jax.numpy as jnp
         from ...jit import aot
@@ -1923,12 +1988,11 @@ class DecodeEngine:
         avals = (self._avals(self._state_tensors()),
                  jax.ShapeDtypeStruct((), jnp.int32))
         compiled, source = aot.compile_jit(
-            zero, avals, fingerprint=self._fingerprint, cache=self._cache,
+            zero, avals, cached_only=cached_only,
+            fingerprint=self._fingerprint, cache=self._cache,
             tag="decode-zero-slot", donate_argnums=(0,),
             audit_ctx=None if not _gc.enabled() else {"mesh": self.mesh})
-        self._note_compile(source)
-        self._zero_fn_c = compiled
-        return compiled
+        return self._installed(self._pool_fns, "zero", compiled, source)
 
     def _state_tensors(self):
         return [layer for i, layer in enumerate(self.pool.tensors)
@@ -1948,28 +2012,55 @@ class DecodeEngine:
         (plus the COW block-copy when prefix sharing is on) up front, so
         traffic never stalls on XLA — and so the tpu-san retrace
         sentinel can treat any later compile as a finding. Returns
-        ``{"decode": [...], "prefill": [...]}``."""
-        for b in self.decode_buckets:
-            # one step executable a bucket either way
-            (self._decode_fn if self._bd is None else self._bd_fn)(b)
-        for p in self.prefill_buckets:
-            self._prefill_fn(p)
+        ``{"decode": [...], "prefill": [...]}``.
+
+        The warm set is what the scheduler can dispatch. With chunked
+        prefill on that leaves out the target's prefill buckets above the
+        chunk: a dispatch holds at most a chunk of a prompt, so no prompt
+        ever reaches them (`_prefill_chunk`), and the largest are the
+        costliest programs to build and to load. The draft's catch-up does
+        use them. `"prefill"` lists the buckets that were brought up.
+
+        What the persistent cache holds is loaded first, one program after
+        the other on the calling thread: measured on a TPU v5e, a load made
+        from a pool's thread takes ten times as long as the same load here,
+        whether or not others run beside it (PERF.md section 6, PR 35).
+        What it lacks is built on a thread pool, joined before this
+        returns: no program depends on another, the Python traces take
+        turns (`jit/aot._trace_lock`) and the XLA compilations, which are
+        most of a cold start, run side by side. Under the
+        collective-schedule auditor the walk is serial: its cross-host
+        verifier hashes the ORDER in which programs are built."""
+        # one step executable a bucket either way
+        step_fn = self._decode_fn if self._bd is None else self._bd_fn
+        programs = [(step_fn, b) for b in self.decode_buckets]
+        chunks = [p for p in self.prefill_buckets
+                  if not self._chunk or p <= self._chunk]
+        programs += [(self._prefill_fn, p) for p in chunks]
         if self._prefix_on:
-            self._cow_fn()
+            programs.append((self._cow_fn,))
         if self._recurrent:
-            self._zero_fn()
-        out = {"decode": list(self.decode_buckets),
-               "prefill": list(self.prefill_buckets)}
+            programs.append((self._zero_fn,))
+        out = {"decode": list(self.decode_buckets), "prefill": chunks}
         if self._spec_on:
             # speculation executables are part of the warm set too: a
             # propose/verify/catch-up dispatch after mark_warm() that
             # compiles is a retrace finding exactly like a decode one
             for b in self.decode_buckets:
-                self._propose_fn(b)
-                self._verify_fn(b)
-            for p in self.prefill_buckets:
-                self._draft_prefill_fn(p)
+                programs += [(self._propose_fn, b), (self._verify_fn, b)]
+            programs += [(self._draft_prefill_fn, p)
+                         for p in self.prefill_buckets]
             out["speculate_k"] = self._k
+        to_build = [(fn, *args) for fn, *args in programs
+                    if fn(*args, cached_only=True) is None]
+        if to_build:
+            width = 1 if _cc.enabled() else \
+                min(len(to_build), os.cpu_count() or 1)
+            with concurrent.futures.ThreadPoolExecutor(
+                    width, thread_name_prefix="DecodeEngine-warmup") as ex:
+                for done in [ex.submit(fn, *args)
+                             for fn, *args in to_build]:
+                    done.result()   # a failed build raises here, as ever
         return out
 
     def _san_sweep(self, pool_ts):
@@ -2073,39 +2164,165 @@ class DecodeEngine:
         table[: len(seq.blocks)] = seq.blocks
         return table
 
-    def _submit_step(self, run, names):
+    def _submit_step(self, run, names, seqs):
         """Dispatch a step closure on the supervised step pool. A wedged
         dispatch (pool hang detection fired: worker retired, capacity
-        restored) is re-submitted — the closure is a pure function of the
-        last COMMITTED state, so a re-run is safe and batchmates lose
-        nothing. `RequestFailed` / `PoolClosed` propagate to the caller
-        for classification.
+        restored) is re-submitted: the closure takes the pool as it
+        stands when it reaches its compiled call (`_consume`) and all
+        else from the last COMMITTED state, so a re-run is safe and
+        batchmates lose nothing; the attempt given up is cancelled first,
+        so its worker, should it wake, dispatches nothing. An attempt that
+        timed out INSIDE its compiled call is waited for instead (it holds
+        the pool, and what it brings back is the step). `RequestFailed` /
+        `PoolClosed` propagate to the caller for classification, unless
+        the failure cost the pool: then the cache is rebuilt for every
+        resident sequence (`_rebuild_pools`; `seqs` are the dispatch's
+        members) and `_PoolRebuilt` ends the round.
 
         Each attempt is one `.handoff` span of the round's phase
-        (`names`): `run(member, ctx)` opens its `.enqueue` / `.fetch`
-        under `ctx` on the worker, so the span's self time is the way to
-        the worker and back. The step pool itself is entered detached:
-        its admission must not add spans of its own to the round."""
-        last = None
-        for _ in range(self._step_retries + 1):
-            with _otrace.span(names["handoff"], profile=True) as hand:
-                def call(member, ctx=hand.ctx):
-                    _otrace.reserve_ring()   # the worker keeps a window
-                    return run(member, ctx)
+        (`names`): `run(member, ctx, attempt)` opens its `.enqueue` /
+        `.fetch` under `ctx` on the worker, so the span's self time is the
+        way to the worker and back. The step pool itself is entered
+        detached: its admission must not add spans of its own to the
+        round."""
+        try:
+            last = attempt = None
+            for _ in range(self._step_retries + 1):
+                with _otrace.span(names["handoff"], profile=True) as hand:
+                    if attempt is not None and attempt.claimed:
+                        # the attempt that timed out is inside its compiled
+                        # call, with the pool: there is nothing to dispatch
+                        # from until it comes back, and what it brings
+                        # back is the step
+                        if attempt.done.wait(self.step_timeout):
+                            if attempt.error is not None:
+                                raise RequestFailed(
+                                    f"decode step failed past its "
+                                    f"deadline: {attempt.error}",
+                                    cause=attempt.error)
+                            return attempt.result
+                        with self._lock:
+                            self._wedged_steps += 1
+                        continue
+                    attempt = _Attempt()
 
-                with _otrace.detached():
-                    req = self._steps.submit(call,
-                                             timeout=self.step_timeout)
-                try:
-                    return req.result()
-                except DeadlineExceeded as e:
-                    with self._lock:
-                        self._wedged_steps += 1
-                    last = e
-        raise RequestFailed(
-            f"decode step wedged {self._step_retries + 1} time(s) — "
-            f"giving up", cause=last,
-            attempts=self._step_retries + 1)
+                    def call(member, ctx=hand.ctx, attempt=attempt):
+                        _otrace.reserve_ring()   # the worker keeps a window
+                        attempt.error = None     # the step pool's own retry
+                        try:
+                            attempt.result = run(member, ctx, attempt)
+                        except BaseException as e:
+                            attempt.error = e
+                            raise
+                        finally:
+                            attempt.done.set()
+                        return attempt.result
+
+                    with _otrace.detached():
+                        req = self._steps.submit(call,
+                                                 timeout=self.step_timeout)
+                    try:
+                        return req.result()
+                    except DeadlineExceeded as e:
+                        with self._lock:
+                            attempt.cancelled = not attempt.claimed
+                            self._wedged_steps += 1
+                        last = e
+            raise RequestFailed(
+                f"decode step wedged {self._step_retries + 1} time(s) — "
+                f"giving up", cause=last,
+                attempts=self._step_retries + 1)
+        except PoolClosed:
+            raise
+        except Exception as e:  # noqa: BLE001 — whatever failed: did it
+            if not self._pool_lost():       # take the pool with it?
+                raise
+            self._rebuild_pools(seqs, e)
+            raise _PoolRebuilt(str(e)) from e
+
+    def _consume(self, attempt, pool, call):
+        """`call(pool_tensors)`, the compiled program that takes `pool`
+        donated, on the step-pool worker. The tensors are read here and
+        not when the closure was made: a re-submitted closure then starts
+        from the pool as the engine holds it now. Taking them and finding
+        the attempt live is one step under the engine's lock, where
+        `_submit_step` cancels an attempt, so a retired worker can never
+        take a pool that a later attempt made."""
+        with self._lock:
+            if attempt.cancelled:
+                raise _AttemptRetired(
+                    "this attempt was given up as wedged; the step was "
+                    "submitted again")
+            attempt.claimed = True
+            pool_ts = pool.tensors
+        out = call(pool_ts)
+        if _san.enabled():
+            # whoever still holds the old list holds deleted arrays: a
+            # later use names this site, not jax's "Array has been deleted"
+            _san.note_donation("decode.dispatch", pool_ts, tag=pool.name)
+        with self._lock:
+            self._donated_dispatches += 1
+        return out
+
+    def _pool_lost(self):
+        """Whether a buffer of the cache is gone: a compiled call consumed
+        it and did not come back with its successor."""
+        pools = [self.pool] + ([self.draft_pool] if self._spec_on else [])
+        return any(t.is_deleted() for pool in pools
+                   for layer in pool.tensors for t in layer)
+
+    def _rebuild_pools(self, seqs, cause):
+        """Fault contract (c), on the scheduler thread: a fresh cache, and
+        every resident sequence back at the head of the waiting queue with
+        no block to its name, to be admitted and prefilled again from its
+        committed tokens as a resumed request is (`submit`'s
+        `resume_committed`): the final chunk samples the NEXT token, under
+        the counter the decode step would have used, so a greedy sequence
+        goes on bit for bit and a sampled one redraws its own stream. A
+        block model keeps its open block, which lives on the host. The
+        prefix cache's entries go with the rows they named. `seqs`, the
+        members of the dispatch that failed, are suspects: each decodes
+        alone until a step of its own has come back, and a suspect that
+        fails alone is failed."""
+        if len(seqs) == 1 and seqs[0].suspect:
+            self._finish(seqs[0], "failed", RequestFailed(
+                f"sequence {seqs[0].id}: its dispatch failed alone, twice, "
+                f"after consuming the pool: {type(cause).__name__}: "
+                f"{cause}", cause=cause))
+        with self._cv:
+            self._pool_rebuilds += 1
+            resident = sorted(self._active + self._prefill_q,
+                              key=lambda s: s.id)
+            self._clear_prefix_cache_locked()
+            for seq in resident:
+                self.pool.free_owned(seq.id)
+                seq.blocks = []
+                seq.outstanding = 0
+                seq.prefill_pos = seq.matched_tokens = 0
+                if seq.state == _ACTIVE:
+                    ids = self._committed_tokens(seq)
+                    seq.prefill_ids = ids if self._bd is None \
+                        else ids[:seq.pos]
+                if self._spec_on:
+                    self.draft_pool.free_owned(seq.id)
+                    seq.draft_blocks = []
+                    seq.draft_pos = seq.draft_outstanding = 0
+                seq.state = _WAITING
+            self._active = []
+            self._prefill_q = []
+            self._waiting = resident + self._waiting
+            self.pool.reset_tensors()
+            if self._spec_on:
+                self.draft_pool.reset_tensors()
+                self._place_draft_pool()
+        for seq in seqs:
+            seq.suspect = True
+        _log.warning(
+            "decode engine %s: a dispatch of sequences %s failed after it "
+            "consumed the pool (%s: %s); cache rebuilt, %d resident "
+            "sequence(s) prefill again from their committed tokens",
+            self.name, [s.id for s in seqs], type(cause).__name__, cause,
+            len(resident))
 
     def _loop(self):
         """The scheduler thread. Its time is tiled by `decode.round`
@@ -2182,6 +2399,8 @@ class DecodeEngine:
                     with _otrace.span(_DECODE["round"],
                                       profile=True) as phase:
                         self._decode_round(phase)
+        except _PoolRebuilt:
+            pass    # every sequence waits again; the next round admits them
         except Exception as exc:  # noqa: BLE001 — scheduler must
             # survive anything: fail the implicated sequences with a
             # typed error instead of silently dying with them stuck
@@ -2305,6 +2524,7 @@ class DecodeEngine:
         it: a full-prompt hit joins the running batch immediately — zero
         prompt compute — anything else enters the chunked-prefill queue."""
         plen = len(seq.prefill_ids)
+        again = seq.round_admit is not None     # the cache was rebuilt
         seq.t_admit = time.perf_counter()
         seq.round_admit = self._round_no
         if self._recurrent:
@@ -2312,7 +2532,8 @@ class DecodeEngine:
             # just found one of those), zeroed of its last owner's state
             seq.slot = self.pool.alloc_slot(seq.id)
             self._zero_slot(seq.slot)
-        if self._h_queue_wait is not None and seq.submitted_at is not None:
+        if self._h_queue_wait is not None and seq.submitted_at is not None \
+                and not again:
             self._h_queue_wait.observe(self._clock() - seq.submitted_at,
                                        ctx=seq.span.ctx)
         if entry is not None:
@@ -2340,8 +2561,10 @@ class DecodeEngine:
                     len(self._active) + len(self._prefill_q))
             if self._bd is not None:
                 # nothing to deliver yet: the first block opens (a prompt
-                # shorter than a block has no prefill at all)
-                self._bd_open_block(seq, seq.prompt[plen:])
+                # shorter than a block has no prefill at all); a sequence
+                # whose cache was rebuilt has its open block still
+                if seq.bd is None:
+                    self._bd_open_block(seq, seq.prompt[plen:])
             else:
                 self._deliver(seq, int(entry["next_token"]))
             return
@@ -2378,6 +2601,8 @@ class DecodeEngine:
             self._finish(seq, "cancelled", e)
         except RequestFailed as e:
             self._finish(seq, "failed", e)
+        except _PoolRebuilt:
+            raise
         except Exception as exc:  # noqa: BLE001 — e.g. an XLA compile
             # failure: fail THIS sequence, not the scheduler
             self._finish(seq, "failed", RequestFailed(
@@ -2396,6 +2621,14 @@ class DecodeEngine:
         this_len = self._chunk if (self._chunk
                                    and remaining > self._chunk) \
             else remaining
+        if this_len > self.prefill_buckets[-1]:
+            # only a sequence whose cache is being rebuilt from its
+            # committed tokens (`_rebuild_pools`) can have outgrown the
+            # largest bucket, and only with chunking off
+            raise RequestFailed(
+                f"sequence {seq.id}: {this_len} committed tokens to "
+                f"prefill again, over the largest prefill bucket "
+                f"{self.prefill_buckets[-1]}, and chunked prefill is off")
         pbucket = next(p for p in self.prefill_buckets if p >= this_len)
         with _otrace.span(names["pack"], profile=True):
             # fresh blocks to hold positions
@@ -2420,7 +2653,6 @@ class DecodeEngine:
             tokens = np.full((1, pbucket), self.pad_token_id, np.int32)
             tokens[0, :this_len] = seq.prefill_ids[start:start + this_len]
             table = self._padded_table(seq, self._nb + self._prefill_tail)
-            pool_ts = self.pool.tensors
             extra = (np.asarray(seq.slot, np.int32),) \
                 if self._recurrent else ()
         hook = self._fault_hook
@@ -2430,7 +2662,7 @@ class DecodeEngine:
         lin = {"recurrent_layers": self._recurrent} \
             if self._recurrent else {}
 
-        def run(_member, hctx):
+        def run(_member, hctx, attempt):
             if hook is not None:
                 hook("prefill", [seq.id], {"bucket": pbucket,
                                            "start": start,
@@ -2455,17 +2687,19 @@ class DecodeEngine:
                 with _san.hot_region("decode.step_dispatch"), \
                         _otrace.span_in(names["enqueue"], hctx,
                                         profile=True):
-                    new_pool, nxt = fn(pv, bv, ats, pool_ts, tokens,
-                                       np.asarray(start, np.int32),
-                                       np.asarray(this_len, np.int32),
-                                       table, aid, hist, samp, *extra)
+                    new_pool, nxt = self._consume(
+                        attempt, self.pool, lambda pool_ts: fn(
+                            pv, bv, ats, pool_ts, tokens,
+                            np.asarray(start, np.int32),
+                            np.asarray(this_len, np.int32),
+                            table, aid, hist, samp, *extra))
                 self._san_sweep(new_pool)
                 with _san.allow_host_sync("decode.token_fetch"), \
                         _otrace.span_in(names["fetch"], hctx,
                                         profile=True):
                     return new_pool, int(np.asarray(nxt))
 
-        new_pool, tok = self._submit_step(run, names)
+        new_pool, tok = self._submit_step(run, names, [seq])
         if self._recurrent:
             with self._lock:
                 if pbucket > 1:
@@ -2523,8 +2757,10 @@ class DecodeEngine:
             self._active.append(seq)
         if self._bd is not None:
             # the prefill's own next token is no token of a block model:
-            # the prompt's remainder opens the first block instead
-            self._bd_open_block(seq, seq.prompt[plen:])
+            # the prompt's remainder opens the first block instead (a
+            # sequence whose cache was rebuilt has its open block still)
+            if seq.bd is None:
+                self._bd_open_block(seq, seq.prompt[plen:])
         else:
             self._deliver(seq, tok)
 
@@ -2742,7 +2978,8 @@ class DecodeEngine:
             spec = [s for s in active
                     if s.max_new - s.generated > 1
                     and s.pos + self._k + 1 <= limit
-                    and s.sampling is None and s.adapter is None]
+                    and s.sampling is None and s.adapter is None
+                    and not s.suspect]
             active = [s for s in active if s not in spec]
         if spec:
             # sequences whose draft is still catching up (one chunk per
@@ -2783,6 +3020,14 @@ class DecodeEngine:
         names = _DECODE
         with _otrace.span(names["grow"], profile=True):
             self._grow_for_write(active)
+        suspects = [s for s in active if s.suspect]
+        if suspects:
+            # members of a dispatch that failed after it consumed the pool
+            # (`_rebuild_pools`): each alone, until a step of its own has
+            # come back
+            self._run_isolated(suspects)
+            active = [s for s in active if not s.suspect
+                      and s.state == _ACTIVE]
         if not active:
             return
         try:
@@ -2804,7 +3049,8 @@ class DecodeEngine:
                 self._deliver(seq, int(tok))
 
     def _run_linked_step(self, name, event_name, seqs, hook_tag, info,
-                         dispatch, sweep=False, member_attrs=None):
+                         dispatch, sweep=False, member_attrs=None,
+                         pool=None):
         """Shared scaffolding for every gathered multi-sequence dispatch
         (plain decode step, speculative propose, speculative verify):
         fault hook, one step-trace root span LINKING every member
@@ -2813,10 +3059,11 @@ class DecodeEngine:
         it), lockcheck blocking region + tpu-san hot region around the
         XLA call, optional non-finite sweep over the freshly written
         pool, and the sanctioned host fetch — one implementation, three
-        steps. `dispatch()` runs the compiled program and returns
-        `(new_pool_tensors, host_array)` (the block step: a tuple of
-        arrays); `member_attrs` (one dict a sequence) joins that member's
-        back-link event."""
+        steps. `dispatch(pool_tensors)` runs the compiled program, which
+        consumes them (`_consume`; `pool`: the target's unless given),
+        and returns `(new_pool_tensors, host_array)` (the block step: a
+        tuple of arrays); `member_attrs` (one dict a sequence) joins that
+        member's back-link event."""
         hook = self._fault_hook
         ids = [s.id for s in seqs]
         traced = ([s for s in seqs
@@ -2827,8 +3074,9 @@ class DecodeEngine:
         names = _DECODE
         rnd = self._round_no
         self._last_step = (info.get("bucket"), ids)
+        pool = self.pool if pool is None else pool
 
-        def run(_member, hctx):
+        def run(_member, hctx, attempt):
             if hook is not None:
                 hook(hook_tag, ids, info)
             # `round` joins the step's own trace to the scheduler round
@@ -2845,7 +3093,7 @@ class DecodeEngine:
                 with _san.hot_region("decode.step_dispatch"), \
                         _otrace.span_in(names["enqueue"], hctx,
                                         profile=True):
-                    new_pool, host = dispatch()
+                    new_pool, host = self._consume(attempt, pool, dispatch)
                 if sweep:
                     self._san_sweep(new_pool)
                 with _san.allow_host_sync("decode.token_fetch"), \
@@ -2862,7 +3110,7 @@ class DecodeEngine:
                            "step_trace": step_span.trace_id_hex})
             return out
 
-        return self._submit_step(run, names)
+        return self._submit_step(run, names, seqs)
 
     def _dispatch_decode(self, active):
         n = len(active)
@@ -2884,14 +3132,13 @@ class DecodeEngine:
                 slots[i] = seq.slot
             hist = self._hist_pack(active, bucket)
             samp = self._samp_pack(active, bucket)
-            pool_ts = self.pool.tensors
             extra = (slots,) if self._recurrent else ()
         new_pool, nxt = self._run_linked_step(
             "decode.step", "decode.step_join", active, "decode",
             {"bucket": bucket, **({"recurrent_layers": self._recurrent}
                                   if self._recurrent else {})},
-            lambda: fn(pv, bv, ats, pool_ts, tokens, positions, tables,
-                       aids, hist, samp, *extra),
+            lambda pool_ts: fn(pv, bv, ats, pool_ts, tokens, positions,
+                               tables, aids, hist, samp, *extra),
             sweep=True)
         self.pool.tensors = new_pool
         for seq in active:
@@ -2937,6 +3184,13 @@ class DecodeEngine:
         if phase is not None:
             phase.set_attr("denoise", len(active) - commits)
             phase.set_attr("commit", commits)
+        suspects = [s for s in active if s.suspect]
+        if suspects:                    # as `_plain_round`'s
+            self._bd_isolated(suspects)
+            active = [s for s in active if not s.suspect
+                      and s.state == _ACTIVE]
+            if not active:
+                return
         try:
             out = self._bd_dispatch(active)
         except PoolClosed:
@@ -2947,21 +3201,26 @@ class DecodeEngine:
                 return
             with self._lock:
                 self._isolations += 1
-            for seq in list(active):
-                if seq.state != _ACTIVE:
-                    continue
-                try:
-                    out = self._bd_dispatch([seq])
-                except PoolClosed:
-                    return
-                except RequestFailed as e1:
-                    self._finish(seq, "failed", e1)
-                    continue
-                self._bd_advance(seq, *(row[0] for row in out))
+            self._bd_isolated(active)
             return
         with _otrace.span(names["deliver"], profile=True):
             for i, seq in enumerate(active):
                 self._bd_advance(seq, *(row[i] for row in out))
+
+    def _bd_isolated(self, seqs):
+        """`_run_isolated` for block steps: one dispatch a sequence."""
+        for seq in list(seqs):
+            if seq.state != _ACTIVE:
+                continue
+            try:
+                out = self._bd_dispatch([seq])
+            except PoolClosed:
+                return
+            except RequestFailed as e:
+                self._finish(seq, "failed", e)
+                continue
+            seq.suspect = False
+            self._bd_advance(seq, *(row[0] for row in out))
 
     def _bd_dispatch(self, active):
         """One block forward a sequence. Returns per sequence (whether it
@@ -2986,7 +3245,6 @@ class DecodeEngine:
                 positions[i] = seq.pos
                 tables[i] = self._padded_table(seq)
                 commit[i] = not seq.bd["masked"].any()
-            pool_ts = self.pool.tensors
         ncommit = int(commit.sum())
         member = [{"block": s.bd["index"], "pass": 0 if c else s.bd["t"] + 1}
                   for s, c in zip(active, commit)]
@@ -2995,8 +3253,8 @@ class DecodeEngine:
             {"bucket": bucket, "denoise": n - ncommit, "commit": ncommit,
              "block": [m["block"] for m in member],
              "pass": [m["pass"] for m in member]},
-            lambda: fn(pv, bv, pool_ts, tokens, positions, tables, commit,
-                       valid),
+            lambda pool_ts: fn(pv, bv, pool_ts, tokens, positions, tables,
+                               commit, valid),
             sweep=True, member_attrs=member)
         self.pool.tensors = new_pool
         with self._lock:
@@ -3063,6 +3321,7 @@ class DecodeEngine:
             except RequestFailed as e:
                 self._finish(seq, "failed", e)
                 continue
+            seq.suspect = False
             self._deliver(seq, int(nxt[0]))
 
     # -- speculative decoding round ----------------------------------------
@@ -3155,13 +3414,12 @@ class DecodeEngine:
         tokens[0, :this_len] = committed[start:start + this_len]
         table = np.zeros(self._nb + self._prefill_tail, np.int32)
         table[: len(seq.draft_blocks)] = seq.draft_blocks
-        pool_ts = self.draft_pool.tensors
         hook = self._fault_hook
         sctx = seq.span.ctx
 
         names = _DECODE
 
-        def run(_member, hctx):
+        def run(_member, hctx, attempt):
             if hook is not None:
                 hook("draft_prefill", [seq.id],
                      {"bucket": pbucket, "start": start,
@@ -3175,10 +3433,11 @@ class DecodeEngine:
                 with _san.hot_region("decode.step_dispatch"), \
                         _otrace.span_in(names["enqueue"], hctx,
                                         profile=True):
-                    new_pool, nxt = fn(pv, bv, pool_ts, tokens,
-                                       np.asarray(start, np.int32),
-                                       np.asarray(this_len, np.int32),
-                                       table)
+                    new_pool, nxt = self._consume(
+                        attempt, self.draft_pool, lambda pool_ts: fn(
+                            pv, bv, pool_ts, tokens,
+                            np.asarray(start, np.int32),
+                            np.asarray(this_len, np.int32), table))
                 # the argmax is discarded (the propose dispatch
                 # starts from last_token) — fetched only to fence
                 # the dispatch for the pool's hang detection
@@ -3188,7 +3447,7 @@ class DecodeEngine:
                     int(np.asarray(nxt))
                 return new_pool
 
-        self.draft_pool.tensors = self._submit_step(run, names)
+        self.draft_pool.tensors = self._submit_step(run, names, [seq])
         seq.draft_pos = start + this_len
         with self._lock:
             self._spec_catchup_chunks += 1
@@ -3235,11 +3494,11 @@ class DecodeEngine:
             tokens[i] = seq.last_token
             positions[i] = seq.pos
             tables[i, : len(seq.draft_blocks)] = seq.draft_blocks
-        pool_ts = self.draft_pool.tensors
         new_pool, props = self._run_linked_step(
             "decode.speculate", "decode.speculate", seqs, "speculate",
             {"bucket": bucket, "k": self._k},
-            lambda: fn(pv, bv, pool_ts, tokens, positions, tables))
+            lambda pool_ts: fn(pv, bv, pool_ts, tokens, positions, tables),
+            pool=self.draft_pool)
         self.draft_pool.tensors = new_pool
         with self._lock:
             self._spec_draft_dispatches += 1
@@ -3258,11 +3517,10 @@ class DecodeEngine:
             tokens[i, 1:] = props[i]
             positions[i] = seq.pos
             tables[i] = self._padded_table(seq)
-        pool_ts = self.pool.tensors
         new_pool, preds = self._run_linked_step(
             "decode.verify", "decode.verify", seqs, "verify",
             {"bucket": bucket, "k": self._k},
-            lambda: fn(pv, bv, pool_ts, tokens, positions, tables),
+            lambda pool_ts: fn(pv, bv, pool_ts, tokens, positions, tables),
             sweep=True)
         self.pool.tensors = new_pool
         with self._lock:
@@ -3282,6 +3540,8 @@ class DecodeEngine:
             except RequestFailed as e:
                 self._finish(seq, "failed", e)
                 continue
+            except _PoolRebuilt:
+                raise
             except Exception as exc:  # noqa: BLE001 — e.g. an XLA
                 # compile failure: fail THIS sequence, not the scheduler
                 self._finish(seq, "failed", RequestFailed(
@@ -3528,6 +3788,13 @@ class DecodeEngine:
                 "tokens_out": self._tokens_out,
                 "wedged_steps": self._wedged_steps,
                 "isolation_rounds": self._isolations,
+                # compiled calls that consumed a pool (steps, chunks,
+                # verify, propose, draft catch-up) and came back: steps +
+                # prefill_chunks in a sound run without speculation
+                "donated_dispatches": self._donated_dispatches,
+                # fault contract (c): the cache built anew after a
+                # dispatch that consumed it failed
+                "pool_rebuilds": self._pool_rebuilds,
                 "occupancy": (self._step_active / self._step_slots)
                 if self._step_slots else 0.0,
                 # the two cumulative counters behind `occupancy`: a

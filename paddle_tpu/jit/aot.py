@@ -216,6 +216,12 @@ class CompileCache:
 
 _default_cache = None
 _default_lock = _locks.new_lock("aot.default_cache")
+# Tracing a functionalized layer swaps the traced values into the LIVE
+# layer objects (distributed/functional.py), so two traces of one model may
+# not overlap. What follows a trace (XLA compilation, the load of a cached
+# executable) touches no layer and runs side by side: the decode engine's
+# `warmup()` builds what its cache lacks on a thread pool.
+_trace_lock = _locks.new_lock("aot.trace")
 
 
 def default_cache():
@@ -331,7 +337,7 @@ def _store_executable(cache, key, compiled):
 
 def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
                 in_shardings=None, out_shardings=None, audit_ctx=None,
-                donate_argnums=None, extra_key=None):
+                donate_argnums=None, extra_key=None, cached_only=False):
     """AOT-compile (or cache-load) `fn` over an aval pytree, persisting the
     executable like `compile_batched` does for bucket executables.
 
@@ -341,13 +347,19 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
     of NamedShardings matching `avals`) compiles the program partitioned
     over those placements — the decode engine's tensor-parallel path; it
     joins the cache key, so a TP executable never collides with the
-    single-device one. `extra_key` (any str()-able value) joins both the
+    single-device one. `donate_argnums` joins it too (and the retrace
+    sentinel's signature): a program that consumes an argument and one that
+    copies it are different binaries under one tag, and neither may be
+    served the other's. `extra_key` (any str()-able value) joins both the
     persistent-cache key and the retrace-sentinel signature: callers whose
     traced program depends on configuration `fn` CLOSES OVER — the decode
     engine's speculative propose/verify steps close over `speculate_k`,
     and two K values can share identical input avals — must pass it, or a
     stale executable for a different configuration could be resurrected
-    from disk. Returns `(compiled, source)` where
+    from disk. `cached_only` brings up what the persistent cache holds
+    and builds nothing: `(None, None)` where it holds no such executable
+    (the decode engine loads its warm set on one thread and builds the
+    rest on several). Returns `(compiled, source)` where
     `compiled(*args)` runs the executable and `source` is "compiled"
     (built here, persisted when a fingerprint was given) or "disk"
     (loaded from the persistent cache, zero XLA compilation).
@@ -364,6 +376,7 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
         sig = (_sharding_sig(in_shardings), _sharding_sig(out_shardings))
         key = CompileCache.key(tag, fingerprint, _aval_signature(avals),
                                *_versions(),
+                               "donate", tuple(donate_argnums or ()),
                                *(("shardings", sig) if sig != (None, None)
                                  else ()),
                                *(("extra", extra_key)
@@ -371,6 +384,8 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
         loaded = _load_executable(cache, key, in_shardings)
         if loaded is not None:
             return loaded, "disk"
+    if cached_only:
+        return None, None
 
     if _san.enabled():
         # retrace sentinel (tpu-san): this is a REAL XLA compile — a
@@ -386,15 +401,13 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
             # retrace blame as a sharding-signature change
             (_san.aval_signature(avals),
              "sharding:" + str(_sharding_sig(in_shardings)),
+             "donate:" + str(tuple(donate_argnums or ())),
              # closed-over configuration (e.g. speculate_k): two programs
              # with identical avals must not look like a duplicate compile
              "extra:" + str(extra_key)))
     with _locks.blocking_region("aot.compile"):
         kw = {}
         if donate_argnums is not None:
-            # donation is TAG-scoped (callers donating must use a tag no
-            # non-donating executable shares), so the persistent-cache
-            # key needs no extra component
             kw["donate_argnums"] = donate_argnums
         if in_shardings is not None:
             kw["in_shardings"] = in_shardings
@@ -403,16 +416,19 @@ def compile_jit(fn, avals, *, fingerprint=None, cache=None, tag="jit-v1",
             # engine's KV pool) on the placement the NEXT dispatch's
             # in_shardings demand — AOT executables accept exact matches
             kw["out_shardings"] = out_shardings
-        lowered = jax.jit(fn, **kw).lower(*avals)
+        with _trace_lock:
+            lowered = jax.jit(fn, **kw).lower(*avals)
         compiled = lowered.compile()
     if _gc.enabled():
         # graph auditor: every REAL compile is audited (disk loads were
         # audited when first built); `audit_ctx` carries the caller's
-        # placement context (decode engine, sharded layers)
-        _gc.audit_executable(f"aot.{tag}", fn=fn, args=avals,
-                             lowered=lowered, compiled=compiled,
-                             in_shardings=in_shardings,
-                             **(audit_ctx or {}))
+        # placement context (decode engine, sharded layers). It traces
+        # `fn` again
+        with _trace_lock:
+            _gc.audit_executable(f"aot.{tag}", fn=fn, args=avals,
+                                 lowered=lowered, compiled=compiled,
+                                 in_shardings=in_shardings,
+                                 **(audit_ctx or {}))
     if _cc.enabled():
         # collective-schedule auditor: the lowered/compiled objects are
         # already in hand, so recording+verifying here is (extra

@@ -92,11 +92,18 @@ def _bucket_inputs(eng, live, bucket, seed):
 
 
 def _dispatch(eng, pool_ts, tokens, positions, tables, aids, samp=None):
+    """The step consumes the pool it is given (donated): it gets a copy, so
+    the caller can dispatch from `pool_ts` again and hold it beside the
+    result."""
+    import jax
+    import jax.numpy as jnp
+
     bucket = len(tokens)
     pv, bv = eng._weights()
     new_pool, nxt = eng._decode_fn(bucket)(
-        pv, bv, eng._adapter_stacks(), pool_ts, tokens, positions, tables,
-        aids, eng._hist_pack([], bucket),
+        pv, bv, eng._adapter_stacks(),
+        jax.tree_util.tree_map(jnp.copy, pool_ts), tokens, positions,
+        tables, aids, eng._hist_pack([], bucket),
         eng._samp_pack([], bucket) if samp is None else samp)
     return new_pool, np.asarray(nxt)
 

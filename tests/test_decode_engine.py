@@ -14,6 +14,7 @@ a degenerate repeated-token model would vacuously pass sequencing bugs.
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -343,6 +344,339 @@ def test_unexpected_prefill_error_fails_sequence_typed(eng):
     st = eng.stats()
     assert st["failed"] - base == 1 and _leaked(st) == 0
     assert eng.generate(_prompt(21), 4)   # engine still serves
+
+
+# ---------------------------------------------------------------------------
+# the pool donated to every step and chunk (ISSUE 35): one copy of the
+# cache, the fault contract, the warm set brought up on threads
+# ---------------------------------------------------------------------------
+
+def _leaves(pool):
+    return [t for layer in pool.tensors for t in layer]
+
+
+def _sound(st):
+    """A run without speculation in which no dispatch was lost: every
+    step and chunk consumed a pool and brought its successor back."""
+    return (st["pool_rebuilds"] == 0 and st["donated_dispatches"]
+            == st["steps"] + st["prefill_chunks"])
+
+
+def test_a_dispatch_consumes_the_pool_it_was_given(model):
+    """After traffic the tensors the engine started from are gone (each
+    dispatch's output pool IS its input's buffers), the pool it holds is
+    whole, and the tokens are the un-donated reference's."""
+    with _engine(model) as eng:
+        first = _leaves(eng.pool)
+        got = eng.generate(_prompt(3), 10)
+        mid = _leaves(eng.pool)
+        assert all(t.is_deleted() for t in first)
+        assert not any(t.is_deleted() for t in mid)
+        a, b = eng.submit(_prompt(1), 12), eng.submit(_prompt(2), 4)
+        assert a.result() and b.result()
+        assert all(t.is_deleted() for t in mid)
+        assert not any(t.is_deleted() for t in _leaves(eng.pool))
+        st = eng.stats()
+        # both counters reach /metrics through the engine's collector
+        from paddle_tpu.obs.metrics import registry
+        text = registry().prometheus_text()
+        want = f"decode_{eng.name}_donated_dispatches {st['donated_dispatches']}"
+        assert want in text.replace(".0\n", "\n"), text[-600:]
+        assert f"decode_{eng.name}_pool_rebuilds 0" in text
+    assert got == _ref_tokens(model, _prompt(3), 10)
+    assert st["steps"] > 0 and st["prefill_chunks"] > 0 and _sound(st)
+
+
+def test_compile_cache_key_tells_a_donated_program_from_a_copying_one(
+        tmp_path):
+    """One tag, one fingerprint, the same avals: `donate_argnums` alone
+    makes two cache entries, and each caller is served its own binary."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.jit import aot
+
+    cache = aot.CompileCache(root=str(tmp_path))
+    avals = (jax.ShapeDtypeStruct((8, 4), jnp.float32),
+             jax.ShapeDtypeStruct((), jnp.int32))
+
+    def fn(x, i):
+        return x.at[i].set(1.0), x[i].sum()
+
+    def get(donate):
+        return aot.compile_jit(fn, avals, fingerprint="fp", cache=cache,
+                               tag="one-tag", donate_argnums=donate)
+
+    assert [get(None)[1], get((0,))[1]] == ["compiled", "compiled"]
+    assert len(cache.entries()) == 2
+    for donate, consumed in ((None, False), ((0,), True)):
+        compiled, source = get(donate)
+        x = jnp.zeros((8, 4), jnp.float32)
+        compiled(x, jnp.int32(3))
+        assert source == "disk" and x.is_deleted() is consumed
+
+
+class _FailsAfterTheCall:
+    """Stands in the engine's non-finite sweep, which runs on the step-pool
+    worker right after the compiled call has come back: the pool is
+    consumed by then, and the dispatch fails."""
+
+    def __init__(self, eng, when):
+        self.eng, self.when, self.fired = eng, when, []
+        self.sound = eng._san_sweep
+        eng._san_sweep = self
+
+    def __call__(self, new_pool):
+        members = (self.eng._last_step or (None, []))[1]
+        if self.when(members, len(self.fired)):
+            self.fired.append(list(members))
+            raise RuntimeError("injected: failed after consuming the pool")
+        return self.sound(new_pool)
+
+
+def _conserved(eng):
+    b = eng.stats()["blocks"]
+    return (b["allocated"] == 0 and b["allocs"] == b["frees"]
+            and b["free"] + b["reserved"] == b["total"]
+            and b["state_slots"] == 0
+            and b["state_slot_allocs"] == b["state_slot_frees"])
+
+
+JOBS = ((31, 7, 14), (32, 13, 9), (33, 5, 12))    # seed, prompt, max_new
+CHUNKED = dict(prefill_buckets=(8, 16), prefill_chunk=8)
+
+
+def test_a_step_that_fails_after_consuming_the_pool_costs_the_cache_only(
+        model):
+    """Fault contract (c): one shared step fails after its compiled call.
+    The cache is rebuilt once, every resident sequence prefills again from
+    its committed tokens and ends with the tokens of an unfaulted run, no
+    block leaks, and the members decode alone until a step of theirs has
+    come back."""
+    with _engine(model, **CHUNKED) as eng:
+        refs = [eng.generate(_prompt(s, n), new) for s, n, new in JOBS]
+        base = eng.stats()
+        fault = _FailsAfterTheCall(
+            eng, lambda members, fired: len(members) == 3 and not fired)
+        streams = [eng.submit(_prompt(s, n), new) for s, n, new in JOBS]
+        outs = [s.result() for s in streams]
+        st = eng.stats()
+        assert len(fault.fired) == 1 and outs == refs
+        assert st["pool_rebuilds"] - base["pool_rebuilds"] == 1
+        assert st["failed"] == 0 and st["completed"] == 6
+        assert not any(t.is_deleted() for t in _leaves(eng.pool))
+        # the lost dispatch's compiled call did come back: one consumed
+        # pool that no step or chunk counts
+        assert st["donated_dispatches"] \
+            == st["steps"] + st["prefill_chunks"] + 1
+        assert eng.generate(_prompt(34), 6)          # and it serves on
+    assert _conserved(eng)
+
+
+def test_a_sequence_that_fails_alone_twice_is_the_one_that_fails(model):
+    """A dispatch that fails after consuming the pool whenever one
+    sequence is in it: the shared step costs a rebuild, then the suspect
+    fails alone and is failed; its batchmates end with their own
+    tokens."""
+    from paddle_tpu.inference import RequestFailed
+
+    with _engine(model, **CHUNKED) as eng:
+        refs = [eng.generate(_prompt(s, n), new) for s, n, new in JOBS]
+        victim = []
+        fault = _FailsAfterTheCall(
+            eng, lambda members, fired: bool(victim)
+            and victim[0] in members and eng._round_no > 0
+            and any(s.state == "active" and s.id == victim[0]
+                    for s in eng._active))
+        streams = [eng.submit(_prompt(s, n), new) for s, n, new in JOBS]
+        victim.append(streams[1].id)
+        outs = []
+        for s in streams:
+            try:
+                outs.append(s.result())
+            except RequestFailed:
+                outs.append(None)
+        st = eng.stats()
+        assert outs[1] is None and outs[0] == refs[0] and outs[2] == refs[2]
+        assert st["failed"] == 1 and 1 <= st["pool_rebuilds"] <= 2
+        assert eng.generate(_prompt(35), 5)
+    assert _conserved(eng)
+
+
+def test_a_wedged_steps_retired_worker_does_not_dispatch(model):
+    """Fault contract (b): the step that hangs in its hook past the
+    deadline is submitted again from the pool as it stands; the retired
+    worker wakes afterwards, finds its attempt cancelled and takes no
+    pool, so every dispatch that consumed one is a committed one."""
+    from paddle_tpu.inference.decode import engine as engine_mod
+
+    hung, woke, retired = [], threading.Event(), []
+
+    def hook(tag, ids, info):
+        if tag == "decode" and len(ids) > 1 and not hung:
+            hung.append(ids)
+            time.sleep(1.0)
+            woke.set()
+
+    with _engine(model, fault_hook=hook, step_timeout=0.3,
+                 step_retries=2, **CHUNKED) as eng:
+        consume = eng._consume
+
+        def watched(attempt, pool, call):
+            try:
+                return consume(attempt, pool, call)
+            except engine_mod._AttemptRetired:
+                retired.append(attempt)
+                raise
+
+        eng._consume = watched
+        refs = [eng.generate(_prompt(s, n), new) for s, n, new in JOBS]
+        streams = [eng.submit(_prompt(s, n), new) for s, n, new in JOBS]
+        assert [s.result() for s in streams] == refs
+        assert woke.wait(10.0)
+        for _ in range(200):
+            if retired:
+                break
+            time.sleep(0.01)
+        st = eng.stats()
+    assert hung and st["wedged_steps"] >= 1 and len(retired) == 1
+    assert retired[0].cancelled and _sound(st)
+    assert _conserved(eng)
+
+
+def test_a_step_that_outlasts_its_deadline_inside_the_call_is_waited_for(
+        model):
+    """An attempt that times out INSIDE its compiled call holds the only
+    copy of the cache: it is not given up and nothing is dispatched beside
+    it; when it comes back, late, what it brings is the step. No rebuild,
+    no token differs, every consumed pool is a committed one."""
+    slow = []
+
+    with _engine(model, step_timeout=0.3, step_retries=2, **CHUNKED) as eng:
+        refs = [eng.generate(_prompt(s, n), new) for s, n, new in JOBS]
+        sweep = eng._san_sweep
+
+        def slow_after_the_call(new_pool):
+            members = (eng._last_step or (None, []))[1]
+            if len(members) > 1 and not slow:
+                slow.append(members)
+                time.sleep(0.5)         # past the deadline, pool consumed
+            return sweep(new_pool)
+
+        eng._san_sweep = slow_after_the_call
+        streams = [eng.submit(_prompt(s, n), new) for s, n, new in JOBS]
+        assert [s.result() for s in streams] == refs
+        st = eng.stats()
+    assert slow and st["wedged_steps"] >= 1 and _sound(st)
+    assert _conserved(eng)
+
+
+def test_a_dispatch_that_raises_before_the_call_leaves_the_pool_whole(
+        model):
+    """Fault contract (a): a hook that raises in a shared step (before the
+    compiled call) costs an isolation round and no cache."""
+    armed = [3]     # the step pool runs a failing closure three times
+
+    def hook(tag, ids, info):
+        if tag == "decode" and len(ids) > 1 and armed[0]:
+            armed[0] -= 1
+            raise RuntimeError("injected before the call")
+
+    with _engine(model, fault_hook=hook, **CHUNKED) as eng:
+        refs = [eng.generate(_prompt(s, n), new) for s, n, new in JOBS]
+        streams = [eng.submit(_prompt(s, n), new) for s, n, new in JOBS]
+        assert [s.result() for s in streams] == refs
+        st = eng.stats()
+    assert not armed[0] and st["isolation_rounds"] >= 1 and _sound(st)
+
+
+def _warm_engine(model, width, monkeypatch, **kw):
+    """An engine, its `warmup()`'s return, and the (tag, key, source) of
+    every program it brought up, sorted; `width` 1 walks them one by
+    one."""
+    from paddle_tpu.jit import aot
+
+    rows, real_load, real_jit = [], aot._load_executable, aot.compile_jit
+    here = threading.local()
+
+    def load(cache, key, in_shardings):
+        here.key = key
+        return real_load(cache, key, in_shardings)
+
+    def jit(fn, avals, **k):
+        out = real_jit(fn, avals, **k)
+        if out[0] is not None:          # not: a look into the cache alone
+            rows.append((k["tag"], here.key, out[1]))
+        return out
+
+    monkeypatch.setattr(aot, "_load_executable", load)
+    monkeypatch.setattr(aot, "compile_jit", jit)
+    if width == 1:
+        monkeypatch.setattr(os, "cpu_count", lambda: 1)
+    eng = _engine(model, **kw)
+    warmed = eng.warmup()
+    monkeypatch.undo()
+    return eng, warmed, sorted(rows)
+
+
+def test_warmup_on_threads_brings_up_what_a_serial_walk_does(
+        model, tmp_path, monkeypatch):
+    """Same return, same cache keys, same built / disk counts: cold (every
+    program built) and warm (a second engine in the process loads them
+    all), and tokens from the programs either walk brought up."""
+    kw = dict(decode_buckets=(1, 2, 4), prefill_buckets=(8, 16, 24),
+              prefill_chunk=16)
+    seen = {}
+    for width in (1, 0):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / f"w{width}"))
+        cold, cold_ret, cold_rows = _warm_engine(model, width, monkeypatch,
+                                                 **kw)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / f"w{width}"))
+        warm, warm_ret, warm_rows = _warm_engine(model, width, monkeypatch,
+                                                 **kw)
+        with cold, warm:
+            n = len(cold_rows)
+            assert n == 3 + 2 + 1                   # steps, chunks, COW
+            assert "decode-prefill-p24" not in [t for t, _, _ in cold_rows]
+            assert cold.stats()["compiles"] == {"built": n, "disk": 0}
+            assert warm.stats()["compiles"] == {"built": 0, "disk": n}
+            assert [(t, k) for t, k, _ in cold_rows] \
+                == [(t, k) for t, k, _ in warm_rows]
+            # the bucket above the chunk is no part of the warm set: no
+            # dispatch can reach it, and a prompt longer than it compiles
+            # nothing
+            assert cold_ret == warm_ret == {"decode": [1, 2, 4],
+                                            "prefill": [8, 16]}
+            tokens = [e.generate(_prompt(41, 29), 7) for e in (cold, warm)]
+            assert warm.stats()["compiles"] == {"built": 0, "disk": n}
+        seen[width] = ([(t, k) for t, k, _ in cold_rows], cold_ret, tokens)
+    assert seen[0] == seen[1]
+
+
+def test_warmup_on_threads_under_the_lock_checker(model, tmp_path,
+                                                  monkeypatch, checker):
+    """The engine built after `enable()`: its locks, the step pool's and
+    the compile cache's are instrumented. A cold `warmup()` on threads,
+    traffic with a wedge in it, and shutdown leave no ordering cycle and
+    no lock held across a blocking region."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    hung = []
+
+    def hook(tag, ids, info):
+        if tag == "decode" and not hung:
+            hung.append(ids)
+            time.sleep(0.6)
+
+    with _engine(model, fault_hook=hook, step_timeout=0.2,
+                 step_retries=2, **CHUNKED) as eng:
+        eng.warmup()
+        assert eng.stats()["compiles"]["built"] == 5
+        streams = [eng.submit(_prompt(s, n), new) for s, n, new in JOBS]
+        assert all(s.result() for s in streams)
+        assert eng.stats()["wedged_steps"] >= 1
+    rep = checker.assert_clean()
+    assert {"decode.engine", "aot.compile_cache"} <= set(rep["locks"])
 
 
 # ---------------------------------------------------------------------------

@@ -113,6 +113,22 @@ def _pool_reading(compiled, tensors):
     return reading, compiled.memory_analysis().temp_size_in_bytes
 
 
+def _aliased(compiled):
+    """How many of the compiled program's outputs are one of its inputs'
+    buffers: the entries of the module's `input_output_alias`."""
+    head = compiled.as_text().split("\n", 1)[0]
+    m = re.search(r"input_output_alias=\{(.*?)\}, \w+=", head)
+    return 0 if m is None else len(re.findall(r"-alias\)", m.group(1)))
+
+
+def _donated(compiled):
+    """How many array leaves the program was told it may consume."""
+    import jax
+
+    return sum(bool(a.donated)
+               for a in jax.tree_util.tree_leaves(compiled.args_info))
+
+
 @contextlib.contextmanager
 def _engine_compiled_for_chip(quant, one_chip, monkeypatch):
     """An engine over a `WIDE` bf16 model whose step programs compile for
@@ -178,10 +194,20 @@ def test_step_programs_hold_no_relayout_of_the_pool(
             # one layout wherever a pool tensor appears (argument, result,
             # loop carry, every fusion between them), so no copy has a
             # pool tensor in one layout as operand and in another as
-            # result: what the entry computation still copies is the
-            # un-donated pool, plainly, and the loop copies none
+            # result, and the loop copies none. Since the pool is donated
+            # (ISSUE 35) every leaf's output IS its input, updated in
+            # place: the program holds ONE pool, and what it returns
+            # beside it is the tokens. (`entry_copies` stays recorded, not
+            # judged: at this size the compiler stages a 2 MB tensor's
+            # scatter in fast memory and copies it back, which a 68 MB
+            # tensor of the served size does not fit.)
             assert len(got["layouts"]) == 1, (tag, got)
             assert got["inner_copies"] == 0, (tag, got)
+            assert _aliased(built[tag]) == len(layer) * len(eng.pool.tensors)
+            mem = built[tag].memory_analysis()
+            assert mem.alias_size_in_bytes >= pool_bytes, (tag, mem)
+            assert mem.output_size_in_bytes - mem.alias_size_in_bytes \
+                < pool_bytes // 100, (tag, mem)
             assert temp < pool_bytes, (tag, temp, pool_bytes)
 
 
@@ -244,6 +270,138 @@ def test_decode_step_reads_every_weight_once_outside_any_loop(
             readers = [line for line in comps[entry]
                        if used.search(line.split(" = ", 1)[-1])]
             assert readers, (shape, name)
+
+
+# ---------------------------------------------------------------------------
+# CPU: every program that writes the cache takes it donated (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _rehearsal_model(name, **more):
+    """A benchmark configuration at its rehearsal sizes."""
+    import json
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    with open(os.path.join(ROOT, "benchmarks", "configs", name)) as f:
+        conf = json.load(f)
+    net = GPTForCausalLM(GPTConfig(**{**conf["model"], **conf["rehearsal"],
+                                      **more}))
+    net.eval()
+    return net
+
+
+def _kinds():
+    """name -> (engine factory, tags of the programs that take the target's
+    pool, tags of those that take the draft's)."""
+    geo = dict(max_length=48, block_size=8, decode_buckets=(1, 2),
+               prefill_buckets=(8, 16), prefill_chunk=16,
+               default_timeout=60.0)
+    steps = ["decode-step-b1", "decode-step-b2"]
+    chunks = ["decode-prefill-p8", "decode-prefill-p16"]
+
+    def tiny():
+        paddle.seed(7)
+        m = gpt("gpt_tiny", **TINY)
+        m.eval()
+        return m
+
+    def dense(**kw):
+        return DecodeEngine(tiny(), **geo, **kw)
+
+    def spec():
+        paddle.seed(3)
+        draft = gpt("gpt_tiny", **{**TINY, "num_layers": 1})
+        draft.eval()
+        return DecodeEngine(tiny(), **geo, draft_model=draft, speculate_k=2)
+
+    def mesh():
+        from paddle_tpu.sharding import MeshConfig
+
+        return DecodeEngine(tiny(), **geo, mesh=MeshConfig(tp=2, dp=4).build())
+
+    def recurrent():
+        return DecodeEngine(
+            _rehearsal_model("olmo_hybrid_7b.json"),
+            **{**geo, "max_length": 64, "block_size": 16,
+               "prefill_buckets": (16, 32), "prefill_chunk": 32})
+
+    def diffusion():
+        return DecodeEngine(
+            _rehearsal_model("sdar_30b_a3b.json"),
+            block_diffusion={"block_length": 4, "denoising_steps": 2,
+                             "mask_token_id": 255},
+            **{**geo, "max_length": 64, "block_size": 16,
+               "prefill_buckets": (16, 32), "prefill_chunk": 32})
+
+    cow = ["decode-cow-copy"]
+    return {
+        "dense": (dense, steps + chunks + cow, []),
+        "int8": (lambda: dense(quant="int8"), steps + chunks + cow, []),
+        "tp_mesh": (mesh, steps + chunks + cow, []),
+        "speculation": (spec, steps + chunks + cow
+                        + ["decode-verify-b1", "decode-verify-b2"],
+                        ["decode-propose-b1", "decode-propose-b2",
+                         "decode-prefill-p8", "decode-prefill-p16"]),
+        "recurrent": (recurrent, steps + ["decode-prefill-p16",
+                                          "decode-prefill-p32"], []),
+        "block_diffusion": (diffusion,
+                            ["decode-step-bd-b1", "decode-step-bd-b2",
+                             "decode-prefill-p16", "decode-prefill-p32"]
+                            + cow, []),
+    }
+
+
+@pytest.mark.parametrize("kind", ["dense", "int8", "tp_mesh", "speculation",
+                                  "recurrent", "block_diffusion"])
+def test_every_program_that_writes_the_cache_aliases_every_leaf_of_it(
+        kind, tmp_path, monkeypatch):
+    """Compile-level, so it holds whatever a backend does with a donation
+    at run time: each step, chunk, block-diffusion, verify, propose and
+    draft catch-up executable is told it may consume every leaf of its
+    pool (rows, int8 scales, recurrent state slots) and nothing else, and
+    its module aliases each of those leaves to an output."""
+    import jax
+    from paddle_tpu.jit import aot
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    built, real = [], aot.compile_jit
+
+    def keeping(fn, avals, **kw):
+        out = real(fn, avals, **kw)
+        if out[0] is not None:          # not: a look into the cache alone
+            built.append((kw["tag"], kw["donate_argnums"], avals, out[0]))
+        return out
+
+    monkeypatch.setattr(aot, "compile_jit", keeping)
+    make, target_tags, draft_tags = _kinds()[kind]
+    eng = make()
+    try:
+        eng.warmup()
+        n_target = len(jax.tree_util.tree_leaves(eng.pool.tensors))
+        n_draft = len(jax.tree_util.tree_leaves(eng.draft_pool.tensors)) \
+            if draft_tags else 0
+        if kind == "recurrent":
+            n_state = len(jax.tree_util.tree_leaves(eng._state_tensors()))
+            assert 0 < n_state < n_target
+            (zero,) = [b for b in built if b[0] == "decode-zero-slot"]
+            assert _donated(zero[3]) == _aliased(zero[3]) == n_state
+        seen = sorted(tag for tag, *_ in built
+                      if tag != "decode-zero-slot")
+        assert seen == sorted(target_tags + draft_tags)
+        for tag, donate, avals, compiled in built:
+            if tag == "decode-zero-slot":
+                continue
+            (arg,) = donate
+            n_pool = len(jax.tree_util.tree_leaves(avals[arg]))
+            # the draft's catch-up shares its tag with the target's chunk:
+            # told apart by the pool they take
+            assert n_pool in {n_target, n_draft} - {0}, (tag, n_pool)
+            assert _donated(compiled) == n_pool, (tag, _donated(compiled))
+            assert _aliased(compiled) == n_pool, (tag, _aliased(compiled))
+    finally:
+        eng.shutdown(drain_timeout=10.0)
 
 
 # ---------------------------------------------------------------------------

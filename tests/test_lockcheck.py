@@ -350,6 +350,38 @@ def test_report_shape(reg):
     assert rep["cycles"] == [] and rep["violations"] == []
 
 
+def test_a_gc_callback_that_locks_inside_the_recorder_does_not_hang(reg):
+    """A garbage collection can start at any allocation, the recorder's own
+    critical sections included, and its callbacks (the tracer's `host.gc`
+    span) take instrumented locks on that very thread: the recorder's
+    guard has to let its own thread back in. Stood in for by a `_held`
+    that takes another instrumented lock, as such a callback would."""
+    inner = InstrumentedLock("obs.trace", reg)
+    outer = InstrumentedLock("decode.engine", reg)
+    plain_held, busy = reg._held, []
+
+    def held_with_a_collection():
+        if not busy:
+            busy.append(True)
+            with inner:             # the callback's span takes its lock
+                pass
+            busy.pop()
+        return plain_held()
+
+    reg._held = held_with_a_collection
+    done = threading.Event()
+
+    def run():
+        with outer:
+            pass
+        done.set()
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    assert done.wait(5), "the recorder's guard deadlocked on its own thread"
+    assert reg.cycles() == [] and reg.violations == []
+
+
 # ---------------------------------------------------------------------------
 # the serving pool under the enabled checker (fake layer: no XLA compile)
 # ---------------------------------------------------------------------------

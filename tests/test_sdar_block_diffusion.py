@@ -583,9 +583,16 @@ def _bd_inputs(live, bucket, seed, commits=lambda i: i % 3 == 0):
 
 
 def _bd_step(eng, pool, *args, rows=slice(None)):
+    """The step consumes the pool it is given (donated): it gets a copy, so
+    the caller can dispatch from `pool` again and hold it beside the
+    result."""
+    import jax
+    import jax.numpy as jnp
+
     pv, bv = eng._weights()
     args = [a[rows] for a in args]
-    new_pool, out = eng._bd_fn(len(args[0]))(pv, bv, pool, *args)
+    new_pool, out = eng._bd_fn(len(args[0]))(
+        pv, bv, jax.tree_util.tree_map(jnp.copy, pool), *args)
     return new_pool, [np.asarray(o) for o in out]
 
 
