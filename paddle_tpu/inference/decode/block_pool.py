@@ -60,6 +60,14 @@ what the engine cannot do with one (prefix reuse, copy-on-write,
 speculation's rollback) it refuses at construction. `slots + free slots +
 reserved == total` holds beside the blocks' law.
 
+A LATENT-ATTENTION layer (`models/latent_attention.py`) keeps rows like any
+attention layer, but one tensor of them and not a pair: its entry spec is a
+1-tuple `((kv_lora_rank + qk_rope_head_dim,), dtype, 1)`, a block of it is
+`[block_size, 576]` at the published sizes, and it is allocated, shared,
+copied and freed by block table with the other layers' blocks. Nothing here
+tells the three kinds apart but `slot_layers`: a layer's tuple is walked,
+whatever its length.
+
 Invariant (asserted by the decode fault-injection harness):
 ``allocated + free + reserved == total`` at all times (a block is
 "allocated" while it has >= 1 reference, however many holders share it),

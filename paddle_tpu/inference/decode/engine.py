@@ -506,6 +506,12 @@ class DecodeEngine:
             prefix_cache=prefix_cache, block_diffusion=block_diffusion)
         if prefix_cache is None:
             prefix_cache = not self._recurrent
+        # layers whose cache row is one compressed latent a token, and
+        # layers of sparse experts off the block-diffusion path (their
+        # counts ride back with the step's tokens); 0: none
+        self._latent = getattr(model, "latent_layers", lambda: 0)()
+        self._moe_layers = 0 if self._bd is not None else \
+            getattr(model, "expert_layers", lambda: 0)()
 
         if prefill_buckets is None:
             p, buckets = min(8, self.max_length - 1), []
@@ -562,6 +568,13 @@ class DecodeEngine:
             num_blocks, self.block_size, quant=quant, name="target",
             # one state slot a resident sequence
             **({"num_slots": self.max_active} if self._recurrent else {}))
+        # one token's rows over the latent-attention layers
+        self._latent_row_bytes = sum(
+            t.shape[-1] * t.dtype.itemsize
+            for kind, layer in zip(model.cfg.layer_kinds(),
+                                   self.pool.tensors)
+            if kind == "latent_attention" for t in layer) \
+            if self._latent else 0
         if self._bd is not None and (
                 self.block_size % self._bd["block_length"]
                 or (self._chunk and self._chunk % self._bd["block_length"])):
@@ -673,8 +686,8 @@ class DecodeEngine:
 
             cts = [tuple(Tensor(a) for a in entry) for entry in cache_vals]
             if valid is not None:
-                # a prompt chunk of a model with recurrent layers: how many
-                # of the bucket's positions are real
+                # a prompt chunk of a model with recurrent layers or sparse
+                # experts: how many of the bucket's positions are real
                 logits, new_caches = model.decode_step(
                     Tensor(tokens), cts, Tensor(pos), Tensor(valid))
             elif ats:
@@ -820,9 +833,11 @@ class DecodeEngine:
         self._bd_blocks_committed = 0
         self._bd_head_dispatches = 0   # dispatches with a denoising pass
         self._bd_context_tokens = 0    # keys the forwards attended to
+        self._moe_choices = 0          # experts chosen, held here or not
         self._moe_tokens = None        # [layers, experts] positions routed
         self._moe_distinct = 0         # experts touched, summed over
         #                                layers and dispatches
+        self._moe_chunk_distinct = 0   # ... the prompt chunks' part of it
         self._moe_reads = 0            # experts the schedule read, same sum
         self._moe_load_sum = 0.0       # fullest expert over the mean, summed
         self._moe_load_n = 0           # ... over this many (layer, dispatch)
@@ -1479,7 +1494,8 @@ class DecodeEngine:
                         tables, aids, slots=None):
         """ONE forward for a bucket of one-token steps (traced): float32
         logits `[B, vocab]` and the cache rows the step made (`_new_rows`,
-        stacked `[B, ...]`), the pool itself untouched.
+        stacked `[B, ...]`), the pool itself untouched; with expert layers
+        also their position counts `[B, layers, experts held]`.
 
         The per-sequence program (the model's one definition of a cached
         step, `decode_step`) is traced once and batched by `vmap` with
@@ -1492,13 +1508,20 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        from ...models.moe import expert_counts
+
         def one(tok, pos, table, aid, *slot):
             caches = self._gather(pool_ts, table,
                                   slot=slot[0] if slot else None)
-            (logits, new_caches), _ = self._apply(
-                pv, bv, tok.reshape(1, 1), caches, pos, ats, aid)
-            return (logits[0, -1].astype(jnp.float32),
-                    self._new_rows(new_caches, pos))
+            with expert_counts() as counts:
+                (logits, new_caches), _ = self._apply(
+                    pv, bv, tok.reshape(1, 1), caches, pos, ats, aid)
+            out = (logits[0, -1].astype(jnp.float32),
+                   self._new_rows(new_caches, pos))
+            # a padded slot (block table all 0, as nothing live has) is
+            # routed like any token and counted nowhere
+            return out + ((jnp.stack(counts) * (table[0] != 0),)
+                          if self._moe_layers else ())
 
         # a model with recurrent layers reads each row's state by its slot
         return jax.vmap(one)(tokens, positions, tables, aids,
@@ -1515,7 +1538,7 @@ class DecodeEngine:
 
         def step(pv, bv, ats, pool_ts, tokens, positions, tables,
                  aids, hist, samp, slots=None):
-            logits, rows = self._forward_bucket(
+            logits, rows, *counts = self._forward_bucket(
                 pv, bv, ats, pool_ts, tokens, positions, tables, aids,
                 slots)
             # greedy rows (`samp["greedy"] == 1`) select the raw-logits
@@ -1528,6 +1551,11 @@ class DecodeEngine:
             # executable.
             nxt = jax.lax.map(lambda row: sample_token(*row),
                               (logits, samp, hist))
+            if counts:
+                # the expert layers' counts ride back behind the tokens:
+                # one array, one readback (`_take_expert_counts`)
+                nxt = jnp.concatenate(
+                    [nxt, jnp.sum(counts[0], axis=0).reshape(-1)])
             return self._scatter_new_rows(
                 pool_ts, rows, tables, positions, slots), nxt
 
@@ -1618,6 +1646,7 @@ class DecodeEngine:
             return out
 
         if multiplex:
+            from ...models.moe import expert_counts
             from ..sampling import sample_token
 
             def prefill(pv, bv, ats, pool_ts, tokens, start, valid_len,
@@ -1631,12 +1660,17 @@ class DecodeEngine:
                 # state as the last real position left it.
                 caches = self._gather(pool_ts, table, nb=nb_table,
                                       slot=slot)
-                (logits, new_caches), _ = apply(
-                    pv, bv, tokens, caches, start, ats, aid,
-                    *((valid_len,) if self._recurrent else ()))
+                with expert_counts() as counts:
+                    (logits, new_caches), _ = apply(
+                        pv, bv, tokens, caches, start, ats, aid,
+                        *((valid_len,) if self._recurrent
+                          or self._moe_layers else ()))
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], valid_len - 1, axis=0, keepdims=False)
                 nxt = sample_token(last.astype(jnp.float32), samp, hist)
+                if self._moe_layers:
+                    nxt = jnp.concatenate(
+                        [nxt[None], jnp.stack(counts).reshape(-1)])
                 return scatter(pool_ts, new_caches, table, start,
                                slot), nxt
         else:
@@ -2659,8 +2693,7 @@ class DecodeEngine:
         sctx = seq.span.ctx
         chunked = this_len < remaining or start > 0
         rnd = self._round_no
-        lin = {"recurrent_layers": self._recurrent} \
-            if self._recurrent else {}
+        lin = self._layer_attrs()
 
         def run(_member, hctx, attempt):
             if hook is not None:
@@ -2697,9 +2730,11 @@ class DecodeEngine:
                 with _san.allow_host_sync("decode.token_fetch"), \
                         _otrace.span_in(names["fetch"], hctx,
                                         profile=True):
-                    return new_pool, int(np.asarray(nxt))
+                    return new_pool, np.asarray(nxt).reshape(-1)
 
-        new_pool, tok = self._submit_step(run, names, [seq])
+        new_pool, out = self._submit_step(run, names, [seq])
+        tok = int(self._take_expert_counts(out, 1, this_len, pbucket,
+                                           chunk=True)[0])
         if self._recurrent:
             with self._lock:
                 if pbucket > 1:
@@ -3112,6 +3147,51 @@ class DecodeEngine:
 
         return self._submit_step(run, names, seqs)
 
+    def _layer_attrs(self):
+        """What a step's or a chunk's span says of the model's layers
+        beyond full attention."""
+        return {k: v for k, v in (("recurrent_layers", self._recurrent),
+                                  ("latent_layers", self._latent)) if v}
+
+    def _count_experts(self, counts, chosen, positions, chunk=False):
+        """Take one dispatch's expert counts `[layers, experts held]` into
+        the counters (caller holds the lock): `chosen` experts were chosen
+        in all (live positions x experts a token x layers), the counts hold
+        those that fell on experts held here, and `positions` (padding
+        included) is what the layer's schedule chose its pass from; `chunk`
+        marks a prompt chunk, whose distinct experts are also kept apart
+        from the decode steps'."""
+        from ...models.moe import experts_read
+
+        if self._moe_tokens is None:
+            self._moe_tokens = np.zeros(counts.shape, np.int64)
+        self._moe_tokens += counts
+        self._moe_choices += chosen
+        distinct = int((counts > 0).sum())
+        self._moe_distinct += distinct
+        self._moe_chunk_distinct += distinct if chunk else 0
+        self._moe_reads += counts.shape[0] * experts_read(
+            positions, self.model.cfg.num_experts_per_tok, counts.shape[1])
+        mean = counts.sum(axis=1) / counts.shape[1]
+        self._moe_load_sum += float(
+            (counts.max(axis=1) / np.maximum(mean, 1e-30)).sum())
+        self._moe_load_n += counts.shape[0]
+
+    def _take_expert_counts(self, out, tokens, live, positions,
+                            chunk=False):
+        """Split what a step or a prompt `chunk` of a model with expert
+        layers brought back, `tokens` token ids and then the layers'
+        counts, and count; the token ids. `live` of the dispatch's
+        `positions` were real."""
+        if not self._moe_layers:
+            return out
+        counts = out[tokens:].reshape(self._moe_layers, -1)
+        with self._lock:
+            self._count_experts(
+                counts, live * self._moe_layers
+                * self.model.cfg.num_experts_per_tok, positions, chunk)
+        return out[:tokens]
+
     def _dispatch_decode(self, active):
         n = len(active)
         bucket = next(b for b in self.decode_buckets if b >= n)
@@ -3135,11 +3215,11 @@ class DecodeEngine:
             extra = (slots,) if self._recurrent else ()
         new_pool, nxt = self._run_linked_step(
             "decode.step", "decode.step_join", active, "decode",
-            {"bucket": bucket, **({"recurrent_layers": self._recurrent}
-                                  if self._recurrent else {})},
+            {"bucket": bucket, **self._layer_attrs()},
             lambda pool_ts: fn(pv, bv, ats, pool_ts, tokens, positions,
                                tables, aids, hist, samp, *extra),
             sweep=True)
+        nxt = self._take_expert_counts(nxt, bucket, n, bucket)
         self.pool.tensors = new_pool
         for seq in active:
             seq.pos += 1
@@ -3226,8 +3306,6 @@ class DecodeEngine:
         """One block forward a sequence. Returns per sequence (whether it
         was a commit pass, the arg-max tokens [B], their confidences
         [B])."""
-        from ...models.moe import experts_read
-
         n = len(active)
         bl = self._bd["block_length"]
         bucket = next(b for b in self.decode_buckets if b >= n)
@@ -3266,17 +3344,9 @@ class DecodeEngine:
             self._bd_head_dispatches += ncommit < n
             self._bd_context_tokens += int(positions[:n].sum()) + n * bl
             if counts.size:
-                if self._moe_tokens is None:
-                    self._moe_tokens = np.zeros(counts.shape, np.int64)
-                self._moe_tokens += counts
-                self._moe_distinct += int((counts > 0).sum())
-                self._moe_reads += counts.shape[0] * experts_read(
-                    bucket * bl, self.model.cfg.num_experts_per_tok,
-                    counts.shape[1])
-                mean = counts.sum(axis=1) / counts.shape[1]
-                self._moe_load_sum += float(
-                    (counts.max(axis=1) / np.maximum(mean, 1e-30)).sum())
-                self._moe_load_n += counts.shape[0]
+                self._count_experts(
+                    counts, n * bl * counts.shape[0]
+                    * self.model.cfg.num_experts_per_tok, bucket * bl)
         return commit[:n].astype(bool), best[:n], conf[:n]
 
     def _bd_advance(self, seq, committed, best, conf):
@@ -3867,13 +3937,19 @@ class DecodeEngine:
                     bd_blocks_committed=self._bd_blocks_committed,
                     bd_head_dispatches=self._bd_head_dispatches,
                     bd_context_tokens=self._bd_context_tokens)
-                if self._moe_tokens is not None:
-                    snap.update(
-                        moe_expert_tokens=self._moe_tokens.tolist(),
-                        moe_distinct_experts=self._moe_distinct,
-                        moe_expert_reads=self._moe_reads,
-                        moe_load_max_over_mean_sum=self._moe_load_sum,
-                        moe_layer_dispatches=self._moe_load_n)
+            if self._moe_tokens is not None:
+                # the expert layers, under block diffusion or not: what was
+                # chosen in all, and what of it fell on the experts held
+                snap.update(
+                    moe_experts_held=self._moe_tokens.shape[1],
+                    moe_choices_total=self._moe_choices,
+                    moe_choices_local=int(self._moe_tokens.sum()),
+                    moe_expert_tokens=self._moe_tokens.tolist(),
+                    moe_distinct_experts=self._moe_distinct,
+                    moe_chunk_distinct_experts=self._moe_chunk_distinct,
+                    moe_expert_reads=self._moe_reads,
+                    moe_load_max_over_mean_sum=self._moe_load_sum,
+                    moe_layer_dispatches=self._moe_load_n)
             if self._recurrent:
                 snap.update(
                     lin_layers=self._recurrent,
@@ -3892,6 +3968,12 @@ class DecodeEngine:
                 lin_state_bytes=blocks["state_slots"]
                 * blocks["state_slot_bytes"],
                 kv_blocks_in_use=blocks["allocated"])
+        if self._latent:
+            # token rows the allocated blocks hold, and what one of them
+            # weighs over all latent-attention layers
+            snap.update(
+                mla_rows_in_use=blocks["allocated"] * self.block_size,
+                mla_row_bytes=self._latent_row_bytes)
         if self._adapters is not None:
             snap["adapters"] = self._adapters.stats()
         if self._spec_on:

@@ -17,8 +17,11 @@ TPU-native design:
   GPT-3-1.3B and LLaMA-2 configs of BASELINE.md (configs 4, 5).
 - Layers of more than one kind (`GPTConfig.layer_pattern`): full attention
   beside the gated delta rule of `linear_attention.py`, whose cache entry
-  is a recurrent state and not rows; the norm before a sublayer or after
-  it (`norm_after`); no positions at all (`learned_positions=False`).
+  is a recurrent state and not rows, and the latent attention of
+  `latent_attention.py`, whose cache entry is one compressed row a token;
+  the norm before a sublayer or after it (`norm_after`); no positions at
+  all (`learned_positions=False`); leading layers whose feed-forward is
+  dense before the expert layers begin (`first_k_dense`).
 """
 from __future__ import annotations
 
@@ -34,8 +37,9 @@ from ..core.dispatch import apply
 from ..nn import functional as F
 
 
-FULL, LINEAR = "full_attention", "linear_attention"
-LAYER_KINDS = (FULL, LINEAR)
+FULL, LINEAR, LATENT = ("full_attention", "linear_attention",
+                        "latent_attention")
+LAYER_KINDS = (FULL, LINEAR, LATENT)
 
 
 @dataclass
@@ -80,9 +84,35 @@ class GPTConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False   # beta in (0, 2), not (0, 1)
+    linear_gate_channels: bool = False  # a log-decay a KEY CHANNEL, not one
+    #                                     a head (Kimi Delta Attention)
+    linear_gate_lower_bound: float = 0.0    # b < 0: log-decay = b *
+    #                                     sigmoid(A (a + dt_bias)) in (b, 0);
+    #                                     0: -A softplus(a + dt_bias)
+    linear_output_gate: str = "silu"    # | "sigmoid": the output norm's gate
+    # -- latent attention (models/latent_attention.py) ---------------------
+    kv_lora_rank: int = 0            # the latent a token leaves in the cache
+    qk_nope_head_dim: int = 0        # a head's query/key part w/o positions
+    qk_rope_head_dim: int = 0        # ... and its rotated part (one key
+    #                                  shared by the heads)
+    v_head_dim: int = 0
+    attn_head_gate: bool = False     # o_h *= sigmoid(w_h . x), one a head
+    # -- the expert layers beyond softmax top-k (models/moe.py) ------------
+    first_k_dense: int = 0           # leading layers keep the dense MLP
+    experts_held: tuple = ()         # (first, count): the experts THIS chip
+    #                                  holds of the router's num_experts; ()
+    #                                  -> all of them
+    moe_score_function: str = "softmax"     # | "sigmoid"
+    moe_router_bias: bool = False    # a selection bias an expert: chosen by
+    #                                  score + bias, weighed by the score
+    moe_n_group: int = 0             # experts in n groups, of which the
+    moe_topk_group: int = 0          # ... best k may be chosen from
+    routed_scaling_factor: float = 1.0
+    moe_shared_expert_intermediate_size: int = 0   # >0: one shared expert
 
     def __post_init__(self):
         self.layer_pattern = tuple(self.layer_pattern)
+        self.experts_held = tuple(self.experts_held)
         unknown = set(self.layer_pattern) - set(LAYER_KINDS)
         if unknown or (self.layer_pattern
                        and self.num_layers % len(self.layer_pattern)):
@@ -96,6 +126,29 @@ class GPTConfig:
             raise ValueError(
                 "a linear_attention layer needs linear_num_heads, "
                 "linear_key_head_dim and linear_value_head_dim")
+        if LATENT in self.layer_pattern and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim
+                and self.rope):
+            raise ValueError(
+                "a latent_attention layer needs kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim, v_head_dim and rope")
+        if self.experts_held and not (
+                len(self.experts_held) == 2 and 0 <= self.experts_held[0]
+                and self.experts_held[1] >= 1
+                and sum(self.experts_held) <= self.num_experts):
+            raise ValueError(
+                f"experts_held {self.experts_held} is (first, count) inside "
+                f"the router's num_experts ({self.num_experts})")
+        if self.moe_n_group > 1 and (
+                self.num_experts % self.moe_n_group
+                or not 1 <= self.moe_topk_group <= self.moe_n_group
+                or self.num_experts // self.moe_n_group < 2):
+            raise ValueError(
+                f"moe_n_group {self.moe_n_group} has to divide num_experts "
+                f"({self.num_experts}) into groups of two experts or more, "
+                f"and moe_topk_group ({self.moe_topk_group}) lie in "
+                f"1..moe_n_group")
         if self.num_kv_heads == 0:
             self.num_kv_heads = self.num_heads
         if self.head_dim == 0:
@@ -353,9 +406,9 @@ def _cached_attn_int8_impl(q, k_new, v_new, kq_c, ks_c, vq_c, vs_c, pos, *,
 
 
 class GPTMLP(nn.Layer):
-    def __init__(self, cfg: GPTConfig):
+    def __init__(self, cfg: GPTConfig, width: int = 0):
         super().__init__()
-        h, m = cfg.hidden_size, cfg.intermediate_size
+        h, m = cfg.hidden_size, width or cfg.intermediate_size
         std = cfg.initializer_range
         bias = not cfg.rms_norm
         self.swiglu = cfg.swiglu
@@ -384,11 +437,12 @@ class GPTMLP(nn.Layer):
 
 
 class GPTBlock(nn.Layer):
-    """One decoder layer of `kind`: full attention (`attn`) or the gated
-    delta rule (`lin`, whose cache is a state and not rows), the norms
-    before the sublayers or, with `norm_after`, after them."""
+    """One decoder layer of `kind`: full attention or latent attention
+    (`attn`) or the gated delta rule (`lin`, whose cache is a state and not
+    rows), the norms before the sublayers or, with `norm_after`, after
+    them; the feed-forward dense, or with `experts` the sparse experts."""
 
-    def __init__(self, cfg: GPTConfig, kind: str = FULL):
+    def __init__(self, cfg: GPTConfig, kind: str = FULL, experts=None):
         super().__init__()
         self.kind = kind
         self.norm_after = cfg.norm_after
@@ -397,10 +451,15 @@ class GPTBlock(nn.Layer):
             from .linear_attention import GatedDeltaNet
 
             self.lin = GatedDeltaNet(cfg)
+        elif kind == LATENT:
+            from .latent_attention import LatentAttention
+
+            self.attn = LatentAttention(cfg)
         else:
             self.attn = GPTAttention(cfg)
         self.ln_2 = _make_norm(cfg)
-        if cfg.num_experts:
+        self.experts = bool(cfg.num_experts) if experts is None else experts
+        if self.experts:
             from .moe import SparseExperts
 
             self.mlp = SparseExperts(cfg)
@@ -427,12 +486,15 @@ class GPTBlock(nn.Layer):
         new_cache = None
         if cache is not None:
             mixed, new_cache = mixed
+        # the expert layer is told which positions are a bucket's padding,
+        # for its counts alone
+        told = (valid_len,) if self.experts and valid_len is not None else ()
         if self.norm_after:
             x = x + self.ln_1(mixed)
-            x = x + self.ln_2(self.mlp(x))
+            x = x + self.ln_2(self.mlp(x, *told))
         else:
             x = x + mixed
-            x = x + self.mlp(self.ln_2(x))
+            x = x + self.mlp(self.ln_2(x), *told)
         return x if cache is None else (x, new_cache)
 
 
@@ -451,8 +513,10 @@ class GPTModel(nn.Layer):
                                     cfg.hidden_size,
                                     weight_attr=_normal_attr(std))
         self.drop = nn.Dropout(cfg.dropout)
-        self.layers = nn.LayerList([GPTBlock(cfg, kind)
-                                    for kind in cfg.layer_kinds()])
+        self.layers = nn.LayerList([
+            GPTBlock(cfg, kind, bool(cfg.num_experts)
+                     and i >= cfg.first_k_dense)
+            for i, kind in enumerate(cfg.layer_kinds())])
         self.ln_f = _make_norm(cfg)
 
     def forward(self, input_ids, position_ids=None):
@@ -546,18 +610,60 @@ class GPTForCausalLM(nn.Layer):
                       f"c{cfg.linear_conv_kernel_dim}"
                       f"neg{int(cfg.linear_allow_neg_eigval)}:"
                       f"eps{cfg.layer_norm_epsilon}")
+            if LINEAR in cfg.layer_pattern:
+                # the delta rule's decay a key channel and its chunked
+                # form by tiles: a program of its own since then, which an
+                # executable cached before must not serve
+                hybrid += ":rule-tiles16"
+            if cfg.linear_gate_channels or cfg.linear_gate_lower_bound \
+                    or cfg.linear_output_gate != "silu":
+                hybrid += (f":gate{int(cfg.linear_gate_channels)}"
+                           f"b{cfg.linear_gate_lower_bound}"
+                           f"{cfg.linear_output_gate}")
+            if LATENT in cfg.layer_pattern:
+                # "linear" and "latent" share their first letter above
+                hybrid += (f":mla@{','.join(str(i) for i, k in enumerate(cfg.layer_kinds()) if k == LATENT)}"
+                           f":{cfg.kv_lora_rank}+{cfg.qk_rope_head_dim}"
+                           f"n{cfg.qk_nope_head_dim}v{cfg.v_head_dim}"
+                           f"g{int(cfg.attn_head_gate)}")
         if not (cfg.block_attention > 1 or cfg.num_experts or cfg.qk_norm):
             return ":".join(x for x in (gqa, hybrid) if x)
+        experts = ""
+        if cfg.first_k_dense or cfg.experts_held or cfg.moe_router_bias \
+                or cfg.moe_score_function != "softmax" or cfg.moe_n_group \
+                or cfg.routed_scaling_factor != 1.0 \
+                or cfg.moe_shared_expert_intermediate_size:
+            # the router's rule and the share held are no parameter's shape
+            experts = (f":dense{cfg.first_k_dense}:held{cfg.experts_held}:"
+                       f"{cfg.moe_score_function}"
+                       f"b{int(cfg.moe_router_bias)}"
+                       f"g{cfg.moe_n_group}/{cfg.moe_topk_group}"
+                       f"x{cfg.routed_scaling_factor}"
+                       f"s{cfg.moe_shared_expert_intermediate_size}")
         return (f"block{cfg.block_attention}:top{cfg.num_experts_per_tok}:"
                 f"norm{int(cfg.norm_topk_prob)}:theta{cfg.rope_theta}:"
                 f"eps{cfg.layer_norm_epsilon}" + (":" + gqa if gqa else "")
-                + (":" + hybrid if hybrid else ""))
+                + (":" + hybrid if hybrid else "") + experts)
 
     def recurrent_layers(self):
         """How many layers keep a recurrent state and no rows (the decode
         engine gives each resident sequence one state slot for them, and
         refuses what needs a cache made of rows)."""
         return self.cfg.layer_kinds().count(LINEAR)
+
+    def expert_layers(self):
+        """How many layers' feed-forward is the sparse experts."""
+        cfg = self.cfg
+        return max(0, cfg.num_layers - cfg.first_k_dense) \
+            if cfg.num_experts else 0
+
+    def latent_layers(self):
+        """How many layers cache one compressed latent row a token."""
+        return self.cfg.layer_kinds().count(LATENT)
+
+    def _latent_row(self):
+        cfg = self.cfg
+        return cfg.kv_lora_rank + cfg.qk_rope_head_dim
 
     def _resolve_cache_quant(self, quant):
         """Resolve the KV-cache quantization mode with a documented
@@ -613,6 +719,11 @@ class GPTForCausalLM(nn.Layer):
                 # state, whatever the length (and never quantized)
                 return tuple(Tensor(jnp.zeros((batch_size,) + suffix, dt))
                              for suffix, dt in self._state_spec(dtype))
+            if kind == LATENT:
+                # a latent-attention layer: one row a token, [c ; rot(k_r)]
+                self._no_latent_quant(quant)
+                return (Tensor(jnp.zeros(shape[:2] + (self._latent_row(),),
+                                         dtype)),)
             if quant == "int8":
                 sshape = shape[:2] + (cfg.num_kv_heads,)
                 return (Tensor(jnp.zeros(shape, jnp.int8)),
@@ -623,6 +734,13 @@ class GPTForCausalLM(nn.Layer):
                     Tensor(jnp.zeros(shape, dtype)))
 
         return [entry(kind) for kind in cfg.layer_kinds()]
+
+    @staticmethod
+    def _no_latent_quant(quant):
+        if quant is not None:
+            raise CacheQuantError(
+                f"cache quant {quant!r} is for rows of keys and values: a "
+                f"latent-attention layer's row has no int8 layout")
 
     def _state_spec(self, dtype):
         """(suffix shape, dtype) of a delta-rule layer's cache tensors,
@@ -672,15 +790,21 @@ class GPTForCausalLM(nn.Layer):
         else:
             layer = ((rows, dtype, hkv), (rows, dtype, hkv))
         kinds = cfg.layer_kinds()
+        # a latent-attention layer's entry is one tensor of rows, a block
+        # of them like any other's; one "head" as far as sharding goes
+        by_kind = {FULL: layer}
+        if LATENT in kinds:
+            self._no_latent_quant(quant)
+            by_kind[LATENT] = (((self._latent_row(),), dtype, 1),)
         if LINEAR not in kinds:
             return BlockKVCache(num_blocks, block_size,
-                                [layer] * cfg.num_layers, quant=quant,
+                                [by_kind[k] for k in kinds], quant=quant,
                                 name=name)
         # a delta-rule layer's entry is one slot a sequence, not blocks of
         # rows: the pool holds `num_slots` of them beside the blocks
-        state = tuple(self._state_spec(dtype))
+        by_kind[LINEAR] = tuple(self._state_spec(dtype))
         return BlockKVCache(num_blocks, block_size,
-                            [state if k == LINEAR else layer for k in kinds],
+                            [by_kind[k] for k in kinds],
                             quant=quant, name=name,
                             slot_layers=[k == LINEAR for k in kinds],
                             num_slots=num_slots)

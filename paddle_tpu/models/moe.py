@@ -60,19 +60,60 @@ def expert_counts():
         _TLS.counts = prev
 
 
-def route(h, router_w, top_k, norm_topk):
-    """(expert ids [T,k], their weights [T,k] float32, counts [E] int32).
-    The softmax runs in float32 over all experts; `lax.top_k` takes the
-    lower index of two equal scores."""
+def route(h, router_w, top_k, norm_topk, *, bias=None, score="softmax",
+          n_group=0, topk_group=0, scale=1.0, held=None, valid=None):
+    """(expert ids [T,k], their weights [T,k] float32, counts int32).
+
+    The scores run in float32 over ALL the router's experts: a softmax, or
+    with `score="sigmoid"` each expert's own sigmoid. A selection `bias`
+    [E] is added to the scores for the choice only; the weights are the
+    scores themselves. With `n_group` groups of which `topk_group` are
+    kept, a group's score is the sum of its two largest (biased) scores and
+    the choice is made inside the best groups. `lax.top_k` takes the lower
+    index of two equal scores. The chosen weights are divided by their sum
+    (`norm_topk`) and multiplied by `scale`.
+
+    `held = (first, count)`: this layer holds experts `first ..
+    first + count - 1` of the router's E and no other. The ids come back
+    relative to `first`, a choice that fell on an absent expert as `count`
+    (past the stack: `apply_experts` adds nothing for it) with weight 0,
+    and `counts` is over the held experts, `[count]`; else `[E]`.
+    `valid` [T] bool leaves padding positions out of the counts."""
     logits = jnp.einsum("th,he->te", h.astype(jnp.float32),
                         router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, top_k)
+    n = router_w.shape[-1]
+    if score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    chosen_by = probs if bias is None else probs + bias.astype(jnp.float32)
+    if n_group > 1 and topk_group < n_group:
+        grouped = chosen_by.reshape(-1, n_group, n // n_group)
+        best2, _ = jax.lax.top_k(grouped, 2)
+        _, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+        kept = jnp.zeros(grouped.shape[:2], bool).at[
+            jnp.arange(grouped.shape[0])[:, None], keep].set(True)
+        chosen_by = jnp.where(kept[..., None], grouped,
+                              -jnp.inf).reshape(-1, n)
+    if chosen_by is probs:
+        w, idx = jax.lax.top_k(probs, top_k)
+    else:
+        _, idx = jax.lax.top_k(chosen_by, top_k)
+        w = jnp.take_along_axis(probs, idx, axis=-1)
     if norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
-    counts = jnp.zeros(router_w.shape[-1], jnp.int32).at[
-        idx.reshape(-1)].add(1)
+    if scale != 1.0:
+        w = w * scale
+    counted = n
+    if held is not None:
+        first, counted = held
+        here = (idx >= first) & (idx < first + counted)
+        idx = jnp.where(here, idx - first, counted)
+        w = jnp.where(here, w, 0.0)
+    ones = 1 if valid is None else jnp.broadcast_to(
+        valid[:, None], idx.shape).reshape(-1).astype(jnp.int32)
+    counts = jnp.zeros(counted, jnp.int32).at[idx.reshape(-1)].add(ones)
     return idx, w, counts
 
 
@@ -150,17 +191,39 @@ def _fold_batch(axis_size, in_batched, h, idx, w, gate_up, down):
     return y.reshape(h.shape), True
 
 
-def _sparse_experts_impl(x, router_w, gate_up, down, *, top_k, norm_topk):
+def _sparse_experts_impl(x, router_w, gate_up, down, *extra, top_k,
+                         norm_topk, has_bias=False, valid=False, **routing):
+    """`extra`: the router's selection bias [E] (`has_bias`), then how many
+    of x's positions (its axis -2) are real (`valid`)."""
+    extra = list(extra)
+    bias = extra.pop(0) if has_bias else None
     shape = x.shape
     h = x.reshape(-1, shape[-1])
-    idx, w, counts = route(h, router_w, top_k, norm_topk)
+    live = None
+    if valid:
+        live = jnp.broadcast_to(jnp.arange(shape[-2]) < extra.pop(0),
+                                shape[:-1]).reshape(-1)
+    idx, w, counts = route(h, router_w, top_k, norm_topk, bias=bias,
+                           valid=live, **routing)
     y = apply_experts(h, idx, w, gate_up, down)
     return y.astype(x.dtype).reshape(shape), counts
 
 
 class SparseExperts(nn.Layer):
-    """`num_experts` SwiGLU experts of width `moe_intermediate_size`,
-    `num_experts_per_tok` a position, no shared expert, no bias."""
+    """SwiGLU experts of width `moe_intermediate_size`,
+    `num_experts_per_tok` a position out of the router's `num_experts`, no
+    bias in any projection. The router is a softmax over the experts or,
+    by `cfg.moe_score_function`, their sigmoids, with a selection bias
+    (`moe_router_bias`), a limit to the best `moe_topk_group` of
+    `moe_n_group` groups and a `routed_scaling_factor`; one shared expert
+    of `moe_shared_expert_intermediate_size` is added to every position.
+
+    `cfg.experts_held = (first, count)` makes the layer one chip's share of
+    an expert-parallel deployment: it routes over all `num_experts`, holds
+    the weights of its own `count` and returns their part of the result
+    (plus the shared expert, which every chip computes alike). What the
+    absent experts would add is added by nobody here: no exchange is run
+    and none is stood in for."""
 
     def __init__(self, cfg):
         super().__init__()
@@ -173,21 +236,54 @@ class SparseExperts(nn.Layer):
         std = cfg.initializer_range
         self.top_k = int(cfg.num_experts_per_tok)
         self.norm_topk = bool(cfg.norm_topk_prob)
+        # what `route` is told beyond the softmax top-k: empty for a layer
+        # that uses none of it, whose traced program stays as it was
+        self.routing = {}
+        if cfg.moe_score_function != "softmax":
+            self.routing["score"] = cfg.moe_score_function
+        if cfg.moe_n_group > 1:
+            self.routing.update(n_group=int(cfg.moe_n_group),
+                                topk_group=int(cfg.moe_topk_group))
+        if cfg.routed_scaling_factor != 1.0:
+            self.routing["scale"] = float(cfg.routed_scaling_factor)
+        self.held = tuple(cfg.experts_held) or None
+        if self.held:
+            self.routing["held"] = self.held
+        held = self.held[1] if self.held else n
         normal = nn.initializer.Normal
         self.router = nn.Linear(h, n, bias_attr=False, weight_attr=nn.ParamAttr(
             initializer=normal(0.0, std)))
+        self.router_bias = self.create_parameter(
+            [n], default_initializer=nn.initializer.Constant(0.0)) \
+            if cfg.moe_router_bias else None
         self.experts_gate_up = self.create_parameter(
-            [n, h, 2 * m], default_initializer=normal(0.0, std))
+            [held, h, 2 * m], default_initializer=normal(0.0, std))
         self.experts_down = self.create_parameter(
-            [n, m, h], default_initializer=normal(
+            [held, m, h], default_initializer=normal(
                 0.0, std / math.sqrt(2 * cfg.num_layers)))
+        self.shared = None
+        if cfg.moe_shared_expert_intermediate_size:
+            from .gpt import GPTMLP
 
-    def forward(self, x):
-        y, counts = apply(
-            "sparse_experts", _sparse_experts_impl,
-            [x, self.router.weight, self.experts_gate_up, self.experts_down],
-            {"top_k": self.top_k, "norm_topk": self.norm_topk})
+            self.shared = GPTMLP(cfg, cfg.moe_shared_expert_intermediate_size)
+
+    def forward(self, x, valid_len=None):
+        """`valid_len`: how many of x's positions are real (a prompt
+        chunk's bucket padding is routed like any position and counted
+        nowhere)."""
+        args = [x, self.router.weight, self.experts_gate_up,
+                self.experts_down]
+        statics = {"top_k": self.top_k, "norm_topk": self.norm_topk,
+                   **self.routing}
+        if self.router_bias is not None:
+            args.append(self.router_bias)
+            statics["has_bias"] = True
+        if valid_len is not None:
+            args.append(valid_len)
+            statics["valid"] = True
+        y, counts = apply("sparse_experts", _sparse_experts_impl, args,
+                          statics)
         sink = getattr(_TLS, "counts", None)
         if sink is not None:
             sink.append(counts._value)
-        return y
+        return y if self.shared is None else y + self.shared(x)

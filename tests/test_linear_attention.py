@@ -2,7 +2,10 @@
 of the rule in `models/linear_attention.py` against the plain reference's
 recurrence (`benchmarks/reference/olmo_hybrid_ref.py`, the file the
 benchmark uses), the hybrid block of `models/gpt.py` against the reference's
-forward, and the cache entries the model hands a cached step.
+forward, and the cache entries the model hands a cached step. Since ISSUE 37
+the log-decay is one a key channel and one a head is its broadcast: every
+case of the forms runs under both gates, the per-channel one against
+`benchmarks/reference/ling_ref.py`'s recurrence.
 """
 import importlib
 import json
@@ -20,6 +23,7 @@ if ROOT not in sys.path:
 
 import paddle_tpu as paddle  # noqa: E402
 from benchmarks import weights_olmo_hybrid  # noqa: E402
+from benchmarks.reference import ling_ref  # noqa: E402
 from benchmarks.reference import olmo_hybrid_ref as ref  # noqa: E402
 from paddle_tpu.models import linear_attention as la  # noqa: E402
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM  # noqa: E402
@@ -39,22 +43,34 @@ def highest():
         yield
 
 
-def operands(s, seed=0):
-    """q, k (unit length), v, g = log alpha, beta in (0, 2), a start state."""
+GATES = ("head", "channel")
+
+
+def operands(s, seed=0, gate="head"):
+    """q, k (unit length), v, the log-decay g (one a head, or with
+    `gate="channel"` one a key channel, in (-5, 0) and spread over four
+    orders of magnitude), beta in (0, 2)."""
     rng = np.random.default_rng(seed)
     q, k = (rng.normal(size=(s, H, DK)).astype(np.float32) for _ in "qk")
     q /= np.linalg.norm(q, axis=-1, keepdims=True)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
     v = rng.normal(size=(s, H, DV)).astype(np.float32)
-    g = -0.3 * np.abs(rng.normal(size=(s, H))).astype(np.float32)
+    if gate == "channel":
+        g = -5.0 * rng.uniform(0, 1, size=(s, H, DK)).astype(np.float32) ** 4
+    else:
+        g = -0.3 * np.abs(rng.normal(size=(s, H))).astype(np.float32)
     beta = rng.uniform(0, 2, size=(s, H)).astype(np.float32)
     return q, k, v, g, beta
 
 
 def reference_rule(q, k, v, g, beta):
-    """The reference's position-by-position recurrence, from a zero state."""
-    return np.asarray(ref.delta_rule(*map(jnp.asarray, (q, k, v)),
-                                     jnp.exp(g), jnp.asarray(beta)))
+    """The reference's position-by-position recurrence, from a zero state:
+    the hybrid cell's for one decay a head, the Ling cell's for one a key
+    channel."""
+    q, k, v, beta = map(jnp.asarray, (q, k, v, beta))
+    if g.ndim == 3:
+        return np.asarray(ling_ref.delta_rule(q, k, v, jnp.asarray(g), beta))
+    return np.asarray(ref.delta_rule(q, k, v, jnp.exp(g), beta))
 
 
 def run_in_pieces(form, ops, cuts):
@@ -69,6 +85,7 @@ def run_in_pieces(form, ops, cuts):
     return np.concatenate(outs), np.asarray(state)
 
 
+@pytest.mark.parametrize("gate", GATES)
 @pytest.mark.parametrize("form,cuts", [
     ("recurrent", ()),                    # the whole sequence
     ("chunked", ()),                      # whole, blocks of 64 + a tail
@@ -76,8 +93,9 @@ def run_in_pieces(form, ops, cuts):
     ("chunked", (64, 128)),               # whole blocks exactly
     ("steps", None),                      # one position at a time
 ])
-def test_the_three_forms_agree_with_the_references_recurrence(form, cuts):
-    ops = operands(150)
+def test_the_three_forms_agree_with_the_references_recurrence(form, cuts,
+                                                              gate):
+    ops = operands(150, gate=gate)
     want = reference_rule(*ops)
     if form == "steps":
         state = jnp.zeros((H, DV, DK), jnp.float32)
@@ -93,11 +111,57 @@ def test_the_three_forms_agree_with_the_references_recurrence(form, cuts):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_the_forms_leave_the_same_state():
-    ops = operands(130, seed=1)
+@pytest.mark.parametrize("gate", GATES)
+def test_the_forms_leave_the_same_state(gate):
+    ops = operands(130, seed=1, gate=gate)
     _, s_rec = run_in_pieces(la.delta_rule_recurrent, ops, ())
     _, s_chunk = run_in_pieces(la.delta_rule_chunked, ops, (33, 97))
     np.testing.assert_allclose(s_chunk, s_rec, atol=2e-5)
+
+
+def test_one_decay_a_head_is_the_broadcast_over_the_key_channels():
+    """A gate constant over d_k IS the scalar rule: bit for bit in the
+    recurrent form (outputs and state), whichever way it is handed in."""
+    q, k, v, g, beta = operands(90, seed=2)
+    zero = jnp.zeros((H, DV, DK), jnp.float32)
+    o_head, s_head = la.delta_rule_recurrent(q, k, v, g, beta, zero)
+    spread = np.ascontiguousarray(np.broadcast_to(g[..., None], k.shape))
+    o_chan, s_chan = la.delta_rule_recurrent(q, k, v, spread, beta, zero)
+    assert np.array_equal(np.asarray(o_head), np.asarray(o_chan))
+    assert np.array_equal(np.asarray(s_head), np.asarray(s_chan))
+    # and what the scalar rule was before the gate had channels
+
+    def before(state, x):
+        qt, kt, vt, gt, bt = x
+        state = state * jnp.exp(gt)[:, None, None]
+        sk = jnp.sum(state * kt[:, None, :], axis=-1)
+        state = state + (bt[:, None] * (vt - sk))[:, :, None] \
+            * kt[:, None, :]
+        return state, jnp.sum(state * qt[:, None, :], axis=-1)
+
+    s_was, o_was = jax.lax.scan(before, zero, (q, k, v, g, beta))
+    assert np.array_equal(np.asarray(o_head), np.asarray(o_was))
+    assert np.array_equal(np.asarray(s_head), np.asarray(s_was))
+
+
+@pytest.mark.parametrize("s", [64, 150])
+def test_a_chunk_at_the_gates_lower_bound_stays_finite(s):
+    """g = -5 in every channel of every position: the running sum passes
+    float32's exp(-88) after 18 positions, which is what a factored
+    exp(G_i) exp(-G_j) over a block of 64 would overflow on."""
+    q, k, v, _, beta = operands(s, seed=3)
+    g = np.full((s, H, DK), -5.0, np.float32)
+    zero = jnp.zeros((H, DV, DK), jnp.float32)
+    o_chunk, s_chunk = la.delta_rule_chunked(q, k, v, g, beta, zero)
+    o_rec, s_rec = la.delta_rule_recurrent(q, k, v, g, beta, zero)
+    assert np.isfinite(np.asarray(o_chunk)).all()
+    np.testing.assert_allclose(o_chunk, o_rec, atol=2e-5)
+    np.testing.assert_allclose(s_chunk, s_rec, atol=2e-5)
+    # an unbounded gate too: the tiles never exponentiate a positive sum
+    g = np.full((s, H), -40.0, np.float32)
+    o_chunk, _ = la.delta_rule_chunked(q, k, v, g, beta, zero)
+    o_rec, _ = la.delta_rule_recurrent(q, k, v, g, beta, zero)
+    np.testing.assert_allclose(o_chunk, o_rec, atol=2e-5)
 
 
 @pytest.mark.parametrize("n", [16, 32, 64])
